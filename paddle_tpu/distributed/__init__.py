@@ -49,6 +49,10 @@ def init_parallel_env():
     # the launcher can tell a hang from a crash (no-op otherwise)
     from .launch.heartbeat import start_heartbeat
     start_heartbeat()
+    # a process entry point of a job: JAX's compile cache goes where the
+    # environment (or the checkout) says, the same for every rank
+    from ..jit.compile_cache import place_jax_cache
+    place_jax_cache()
     if os.environ.get("PT_COORDINATOR"):
         jax.distributed.initialize(
             coordinator_address=os.environ["PT_COORDINATOR"],

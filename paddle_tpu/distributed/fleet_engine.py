@@ -535,7 +535,12 @@ class DistributedTrainStep:
             bufs = [leaf_dict["buf::" + n] for n in buf_leaf_names]
             with FB._swapped(template, leaf_names, arrs,
                              buf_leaf_names, bufs) as (_, tbufs):
-                with _random.key_context(key):
+                # no tape: the schedule differentiates this function with
+                # jax.vjp, also from the hand-written backward, which runs
+                # outside compute_loss's no_grad — a taped op would be a
+                # vjp inside that vjp, and the flash kernel's forward rule
+                # has no JVP ("pallas_call ... AssertionError")
+                with _random.key_context(key), _engine.no_grad():
                     out = template(Tensor._from_array(h))
                 # capture BEFORE _swapped restores arrays
                 new_bufs = {"buf::" + n: tbufs[n]._array
@@ -746,12 +751,11 @@ class DistributedTrainStep:
         self._plain_jit = lambda: jax.jit(step_fn, in_shardings=in_sh,
                                           out_shardings=out_sh)
 
-    def memory_stats(self, *batch):
-        """AOT-compile the fused step for `batch` and return XLA's
-        CompiledMemoryStats (argument/output/temp bytes) WITHOUT running
-        it — the peak-memory evidence for pipeline schedule choices
-        (tools/pp_memory.py; reference analog: 1F1B's activation-memory
-        motivation in fleet pipeline_parallel.py)."""
+    def lower(self, *batch):
+        """`jax.stages.Lowered` of the fused step for `batch` — the
+        program `__call__` compiles, WITHOUT running it: for reading
+        its compiled text (kernels, collectives) and memory analysis.
+        Consumes no step count and no randomness."""
         model, optimizer = self.model, self.optimizer
         if not self._placed:
             self._place_state()
@@ -770,16 +774,16 @@ class DistributedTrainStep:
         ba = [b._array for _, b in model.named_buffers()]
         lr = jnp.asarray(optimizer.get_lr(), jnp.float32)
         step = jnp.asarray(self._step + 1, jnp.float32)
-        # observational: a throwaway key with the right aval, NOT a draw
-        # from the shared stream (would perturb later training randomness)
-        st = _random.get_rng_state()
-        try:
-            rng = _random.next_key()
-        finally:
-            _random.set_rng_state(st)
         return self._jitted.lower(
-            param_tree, ba, self._opt_state, lr, step, rng,
-            batch_arrays).compile().memory_analysis()
+            param_tree, ba, self._opt_state, lr, step, _random.peek_key(),
+            batch_arrays)
+
+    def memory_stats(self, *batch):
+        """XLA's CompiledMemoryStats (argument/output/temp bytes) of the
+        fused step for `batch` — the peak-memory evidence for pipeline
+        schedule choices (tools/pp_memory.py; reference analog: 1F1B's
+        activation-memory motivation in fleet pipeline_parallel.py)."""
+        return self.lower(*batch).compile().memory_analysis()
 
     def __call__(self, *batch):
         model, optimizer = self.model, self.optimizer

@@ -4,8 +4,8 @@ Reference: python/paddle/distributed/launch/ (`python -m
 paddle.distributed.launch --nnodes ... train.py`), which sets up
 per-rank env, starts workers, watches them, and supports elastic
 restart.  TPU-native shape: ONE controller process per host (XLA drives
-every local chip), so `--nproc_per_node` exists mainly for CPU-mesh
-testing and per-process-per-chip setups; ranks coordinate through
+every local chip), so `--nproc_per_node` > 1 is for CPU-mesh testing
+and is refused on a TPU host; ranks coordinate through
 jax.distributed.initialize (gRPC coordinator at `--master`), which
 `paddle_tpu.distributed.init_parallel_env()` reads from the PT_*
 variables this launcher exports.
@@ -87,6 +87,13 @@ def _parse_args(argv):
         # handing jax.distributed.initialize conflicting world specs
         p.error("--elastic requires --nnodes=1: supervisors do not "
                 "coordinate a downsize across hosts")
+    from ...device import tpu_chips_visible
+    if args.nproc_per_node > 1 and tpu_chips_visible():
+        # every worker claims the host's TPU at start-up and a chip
+        # belongs to one process: the second rank would fail or hang
+        p.error("--nproc_per_node > 1 cannot run on a TPU host: one "
+                "controller process drives every local chip (workers are "
+                "not placed one per chip)")
     return args
 
 

@@ -1,100 +1,39 @@
-"""jax version compatibility shims.
+"""The one place this repo touches jax surfaces that have moved before.
 
-`shard_map` moved and changed surface across jax versions:
-
-  * old (<= 0.4.x): `jax.experimental.shard_map.shard_map` with
-    `check_rep=` and `auto=` (the set of axes left AUTOMATIC — the
-    complement of the manual set).
-  * new: top-level `jax.shard_map` with `check_vma=` (renamed from
-    check_rep) and `axis_names=` (the set of axes made MANUAL).
-
-Call sites in this repo use the NEW spelling; `compat.shard_map`
-translates to whatever the installed jax provides, so the pipeline and
-ring-attention paths work on both.  Resolution happens once at import.
+`shard_map`, `axis_size` and `cost_analysis()` changed shape across jax
+releases; call sites go through here so that the next move is one edit.
+The code is for the jax that is installed (0.9): no branch for another.
 """
 from __future__ import annotations
-
-import inspect
 
 import jax
 from jax import lax
 
-_IMPL = getattr(jax, "shard_map", None)
-if _IMPL is None:
-    from jax.experimental.shard_map import shard_map as _IMPL  # type: ignore
-
-_PARAMS = frozenset(inspect.signature(_IMPL).parameters)
-
-# Partial-manual shard_map (manual over SOME mesh axes, GSPMD over the
-# rest) needs the new-style `axis_names` implementation: on old jax the
-# `auto=` spelling lowers manual-axis collectives (ppermute/psum) into a
-# program the bundled XLA rejects with a fatal CHECK (spmd_partitioner
-# "IsManualSubgroup" mismatch) — a process abort, not an exception.
-HAS_PARTIAL_MANUAL = "axis_names" in _PARAMS
 
 def axis_index(axis_name):
-    """`lax.axis_index` — one indirection point so future jax surface
-    moves (as with shard_map/axis_size) stay contained to this module."""
     return lax.axis_index(axis_name)
+
+
+def axis_size(axis_name):
+    """Inside a mapped body this resolves to a concrete Python int."""
+    return lax.axis_size(axis_name)
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma=None,
               axis_names=None):
-    """`jax.shard_map` with new-style kwargs on any supported jax.
-
-    `axis_names` — mesh axes to run in MANUAL mode (partial-manual
-    shard_map); omitted means all axes manual.  On old jax this is
-    translated to the complementary `auto=` set.
-    `check_vma` — value-and-mesh-agreement check (old name: check_rep).
-    """
+    """`jax.shard_map`.  `axis_names` — mesh axes to run in MANUAL mode
+    (partial-manual: GSPMD keeps the rest); omitted means all axes.
+    `check_vma` — the value-and-mesh-agreement check."""
     kw = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
     if check_vma is not None:
-        if "check_vma" in _PARAMS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _PARAMS:
-            kw["check_rep"] = check_vma
+        kw["check_vma"] = check_vma
     if axis_names is not None:
-        manual = set(axis_names)
-        if "axis_names" in _PARAMS:
-            kw["axis_names"] = manual
-        elif "auto" in _PARAMS:
-            auto = frozenset(mesh.axis_names) - manual
-            kw["auto"] = auto
-            wide = sorted(a for a in auto if mesh.shape[a] > 1)
-            if wide:
-                # size-1 auto axes are degenerate (nothing for GSPMD to
-                # shard) and compile fine; >1 is the broken case
-                raise NotImplementedError(
-                    "partial-manual shard_map (manual over "
-                    f"{sorted(manual)}, GSPMD over {wide}) is not "
-                    "supported on this jax version: the old-style "
-                    "`auto=` lowering sends manual-axis collectives "
-                    "into a fatal XLA CHECK (spmd_partitioner "
-                    "IsManualSubgroup).  Upgrade jax, or use the "
-                    "full-manual pipeline (pipeline_apply) / a mesh "
-                    "whose non-pipeline axes have degree 1.")
-    return _IMPL(f, **kw)
-
-
-def axis_size(axis_name):
-    """`lax.axis_size` (newer jax) with a psum(1) fallback — inside a
-    mapped body both resolve to a concrete Python int."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)
 
 
 def normalize_cost_analysis(cost):
-    """`Compiled.cost_analysis()` as ONE dict on every jax version.
-
-    The return shape moved across versions: older jax returns a
-    per-computation list ``[{...}]``, newer returns the dict directly,
-    and a backend that implements no cost model returns None/empty.
-    Callers (paddle.flops, profiler.program_stats, the sparse-conv FLOP
-    assertions) read keys like ``"flops"`` — route every read through
-    this helper instead of guessing the container."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost) if isinstance(cost, dict) else {}
+    """`Compiled.cost_analysis()` as a dict; a backend with no cost model
+    returns None.  Callers (paddle.flops, profiler.program_stats, the
+    sparse-conv FLOP assertions) read keys like ``"flops"``."""
+    return dict(cost) if cost else {}

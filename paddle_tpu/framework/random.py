@@ -40,6 +40,13 @@ def next_key():
     return sub
 
 
+def peek_key():
+    """A key with the aval `next_key()` returns, WITHOUT advancing the
+    stream — for lowering a program that takes a key (a draw would
+    perturb later training randomness)."""
+    return jax.random.split(default_key())[1]
+
+
 @contextlib.contextmanager
 def key_context(key):
     """Route next_key() to splits of `key` (used by jit/functional paths)."""

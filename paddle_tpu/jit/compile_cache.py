@@ -30,9 +30,8 @@ Robustness-first storage contract:
   * the store is size-budgeted (``PADDLE_TPU_CACHE_MAX_BYTES``):
     oldest-first GC after each put, never collecting the entry just
     published; a reader losing the race to GC sees a plain miss;
-  * an unwritable/full directory or a jax build without executable
-    serialization degrades to in-memory-only with ONE warning — the
-    training loop never aborts because of the cache.
+  * an unwritable/full directory degrades to in-memory-only with ONE
+    warning — the training loop never aborts because of the cache.
 
 Fault sites (resilience/chaos.py): ``cache.corrupt`` flips bytes in the
 just-published entry, ``cache.race`` publishes a competing write first,
@@ -41,18 +40,22 @@ just-published entry, ``cache.race`` publishes a competing write first,
 zero recompiles with bit-exact loss continuity and corrupt entries are
 quarantined transparently.
 
-Donated executables are never serialized directly: on this jaxlib
-(0.4.36/CPU) a deserialized executable whose program bakes input/output
-buffer aliases (``donate_argnums``) corrupts memory at run or teardown
-time — a nondeterministic segfault, measured at ~40% of warm restarts.
-Entries that donate (TrainStep, DistributedTrainStep) therefore publish
-an alias-free TWIN compilation (`plain_jit` in `FunctionCache.lookup`):
-donation never changes the math, only buffer reuse, so a restarted
-process loads a bit-exact, crash-free executable, while the compiling
-process keeps its donating one.  The twin doubles compile cost on the
-publishing miss only; set ``PADDLE_TPU_CACHE_DONATED=1`` to serialize
-the donating executable directly on stacks where the round-trip is
-known safe.
+Donated executables are not serialized directly.  On jaxlib 0.4.36/CPU
+a deserialized executable whose program bakes input/output buffer
+aliases (``donate_argnums``) corrupted memory at run or teardown time —
+a nondeterministic segfault in ~40% of warm restarts.  Entries that
+donate (TrainStep, DistributedTrainStep) therefore publish an alias-free
+TWIN compilation (`plain_jit` in `FunctionCache.lookup`): donation never
+changes the math, only buffer reuse, so a restarted process loads a
+bit-exact executable while the compiling process keeps its donating
+one.  The twin doubles compile cost on the publishing miss only.
+Re-tested on jax/jaxlib 0.9.0, CPU backend (PR 21): 31 warm restarts in
+fresh subprocesses loading the DONATING executable
+(``PADDLE_TPU_CACHE_DONATED=1``), 0 crashes, losses bit-exact — the
+hazard no longer reproduces there.  It has not been re-tested on the TPU
+backend, where a crash costs a machine, so the twin stays until it is
+(ROADMAP D-queue); ``PADDLE_TPU_CACHE_DONATED=1`` serializes the
+donating executable directly.
 
 Env knobs: ``PADDLE_TPU_CACHE_DIR`` (unset = disabled),
 ``PADDLE_TPU_CACHE_MAX_BYTES`` (default 2 GiB),
@@ -70,6 +73,7 @@ import time
 import warnings
 
 import jax
+from jax.experimental import serialize_executable as _se
 
 _ENV_DIR = "PADDLE_TPU_CACHE_DIR"
 _ENV_MAX = "PADDLE_TPU_CACHE_MAX_BYTES"
@@ -81,7 +85,7 @@ _DEFAULT_MAX_BYTES = 2 << 30
 
 class CacheUnavailableWarning(UserWarning):
     """The persistent cache degraded to in-memory-only (unwritable/full
-    directory, or this jax build cannot serialize executables)."""
+    directory)."""
 
 
 def _reg():
@@ -89,14 +93,63 @@ def _reg():
     return metrics.registry()
 
 
-def _serializer():
-    """The (serialize, deserialize_and_load) pair, or None when this jax
-    build cannot round-trip compiled executables."""
+def serialize_compiled(compiled):
+    """Pickle-ready form of a compiled executable: jax's serialized
+    payload plus the ids of the devices it was compiled for."""
+    blob, in_tree, out_tree = _se.serialize(compiled)
+    ids = [d.id for d in compiled.runtime_executable().local_devices()]
+    return blob, in_tree, out_tree, ids
+
+
+def load_compiled(payload):
+    """The inverse of `serialize_compiled`.  The executable is loaded
+    onto the devices it was compiled for: left to its default,
+    `deserialize_and_load` loads onto EVERY device of the backend, and
+    a one-device program then refuses its arguments on a multi-device
+    host ("expected ... to have N shards, got: [1]")."""
+    blob, in_tree, out_tree, ids = payload
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        blob, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in ids])
+
+
+# ===================================================================
+# JAX's own persistent compilation cache — placed from outside
+# ===================================================================
+_JAX_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def place_jax_cache():
+    """Switch on JAX's persistent compilation cache for this process and
+    return its directory.  Called by process entry points (chip_smoke.py,
+    tools/serve.py, the serving worker, bench.py, init_parallel_env) —
+    never at import.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
+    cache there and nothing here sets another.  Where it is not, the
+    cache lives at ``<checkout>/.jax_cache``: a fixed path, because the
+    next process must find it again — a temporary name, a pid or a time
+    in it would never hit.  The variable is exported so that child
+    processes land in the same directory.
+    This is independent of the executable store above
+    (``PADDLE_TPU_CACHE_DIR``)."""
+    d = os.environ.get(_JAX_CACHE_ENV)
+    if not d:
+        d = os.path.join(_CHECKOUT, ".jax_cache")
+        os.environ[_JAX_CACHE_ENV] = d
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
+
+
+def jax_cache_entries(d):
+    """Number of entries in a JAX compilation cache directory."""
     try:
-        from jax.experimental import serialize_executable as se
-        return se.serialize, se.deserialize_and_load
-    except Exception:  # pragma: no cover - depends on jax build
-        return None
+        return sum(1 for n in os.listdir(d) if n.endswith("-cache"))
+    except OSError:
+        return 0
 
 
 # ===================================================================
@@ -496,9 +549,6 @@ def configure(cache_dir=None, max_bytes=None):
             _CACHE = CompileCache(cache_dir, max_bytes=max_bytes)
             if not probe_ok:
                 _CACHE._degrade("directory is not writable")
-            if _serializer() is None:
-                _CACHE._degrade("this jax build cannot serialize "
-                                "executables (version mismatch)")
         _CONFIGURED = True
     return _CACHE
 
@@ -547,9 +597,9 @@ def _drop_memo_unsafe():
 # dedup (a TrainStep re-created after an in-process rollback reuses the
 # executable instead of re-reading disk), this is a CRASH GUARD: on
 # jaxlib 0.4.36/CPU, deserializing a second live instance of an
-# executable this process already compiled segfaults nondeterministically
+# executable this process already compiled segfaulted nondeterministically
 # (double-instance buffer-alias corruption; a fresh process loading the
-# same entry is stable).  The memo guarantees one live instance per
+# same entry is stable; not re-tested on 0.9).  The memo guarantees one live instance per
 # program per process, so the persistent path only ever deserializes in
 # a process that never compiled that program — exactly the restart case
 # it exists for.
@@ -639,16 +689,12 @@ class FunctionCache:
             hit = _MEMO.get(digest)
         if hit is not None:
             return hit[0], "mem", hit[1]
-        ser = _serializer()
-        if ser is None:
-            return jitted, "bypass", None
-        serialize, deserialize = ser
         blob = c.get(digest)
         if blob is not None:
             t0 = time.perf_counter()
             try:
                 exe, extra = pickle.loads(blob)
-                compiled = deserialize(*exe)
+                compiled = load_compiled(exe)
             except Exception as e:
                 # payload passed the checksum but won't load (e.g. an
                 # XLA-internal format change): quarantine + recompile
@@ -692,7 +738,7 @@ class FunctionCache:
                 _reg().histogram("compile_cache_twin_compile_seconds",
                                  fn=self.label).observe(
                                      time.perf_counter() - tw0)
-            payload = pickle.dumps((serialize(to_publish), extra))
+            payload = pickle.dumps((serialize_compiled(to_publish), extra))
             c.put(digest, payload,
                   meta={"label": self.label, "jax": jax.__version__,
                         "mesh": mesh_fingerprint()})

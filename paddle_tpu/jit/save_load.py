@@ -174,14 +174,8 @@ def _env_stamp():
 
 
 def _write_aot(jitted, path, example_structs):
-    ser = _cc._serializer()
-    if ser is None:
-        raise AOTIncompatible(
-            "this jax build cannot serialize executables "
-            "(jax.experimental.serialize_executable unavailable)")
-    serialize, _ = ser
     compiled = jitted.lower(*example_structs).compile()
-    payload = pickle.dumps(serialize(compiled))
+    payload = pickle.dumps(_cc.serialize_compiled(compiled))
     with open(os.path.join(path, _AOT), "wb") as f:
         f.write(payload)
     stamp = _env_stamp()
@@ -262,16 +256,12 @@ def _load_aot(path, meta):
     ok, reason = _aot_compatible(stamp)
     if not ok:
         return None, reason
-    ser = _cc._serializer()
-    if ser is None:
-        return None, ("this jax build cannot deserialize executables "
-                      "(serialize_executable unavailable)")
     try:
         with open(aot_path, "rb") as f:
             payload = f.read()
         if hashlib.sha256(payload).hexdigest() != stamp.get("sha256"):
             return None, "artifact checksum mismatch (damaged file)"
-        return ser[1](*pickle.loads(payload)), ""
+        return _cc.load_compiled(pickle.loads(payload)), ""
     except Exception as e:  # damaged/foreign payload: fall back
         return None, f"artifact failed to load: {e}"
 

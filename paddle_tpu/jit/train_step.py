@@ -135,7 +135,24 @@ class TrainStep:
         # executables segfault — see compile_cache module docstring)
         self._plain_jit = ((lambda: jax.jit(step_fn)) if donate else None)
 
-    def __call__(self, *batch):
+    def lower(self, *batch):
+        """`jax.stages.Lowered` of the fused step for `batch` — the
+        program `__call__` compiles, WITHOUT running it: for reading
+        its compiled text (is the flash kernel in it?) and memory
+        analysis.  Consumes no step count and no randomness."""
+        _, pa, _, ba = self._state()
+        batch_arrays = tuple(
+            b._array if isinstance(b, Tensor) else jnp.asarray(b)
+            for b in batch)
+        return self._jitted.lower(
+            pa, ba, self._opt_state,
+            jnp.asarray(self.optimizer.get_lr(), jnp.float32),
+            jnp.asarray(self._step + 1, jnp.float32),
+            _random.peek_key(), batch_arrays)
+
+    def _state(self):
+        """(param names, arrays, buffer names, arrays) of the model,
+        with the optimizer state adopted and the step built."""
         model, optimizer = self.model, self.optimizer
         sync = getattr(model, "_pp_sync", None)
         if sync is not None:  # flush a prior pp engine's stacked weights
@@ -155,6 +172,11 @@ class TrainStep:
             # on retry (self._jitted stays None, so the next call rebuilds)
             _chaos.crash("compile.fail_once")
             self._build()
+        return pn, pa, bn, ba
+
+    def __call__(self, *batch):
+        model, optimizer = self.model, self.optimizer
+        pn, pa, bn, ba = self._state()
         self._step += 1
         lr = jnp.asarray(optimizer.get_lr(), jnp.float32)
         step = jnp.asarray(self._step, jnp.float32)

@@ -56,12 +56,6 @@ def export_serving_artifacts(engine, path, prompt_lens=()):
     lengths this replica expects (chunks it would cut); the decode
     program and the base chunk bucket are always included.  Returns the
     manifest dict."""
-    ser = _cc._serializer()
-    if ser is None:
-        raise AOTIncompatible(
-            "this jax build cannot serialize executables "
-            "(jax.experimental.serialize_executable unavailable)")
-    serialize, _ = ser
     path = os.path.abspath(path)
     os.makedirs(os.path.join(path, _PROGRAMS), exist_ok=True)
     manifest = {"stamp": _env_stamp(), "programs": {}}
@@ -73,7 +67,7 @@ def export_serving_artifacts(engine, path, prompt_lens=()):
         # program
         builder, structs = engine.program_structs(key)
         compiled = builder().lower(*structs).compile()
-        payload = pickle.dumps(serialize(compiled))
+        payload = pickle.dumps(_cc.serialize_compiled(compiled))
         name = _key_name(key)
         fn = os.path.join(_PROGRAMS, f"{name}.aotexec")
         with open(os.path.join(path, fn), "wb") as f:
@@ -111,18 +105,6 @@ def load_serving_artifacts(engine, path, strict=False):
             f"instead (cold compile)", UserWarning, stacklevel=2)
         _metrics.registry().counter("serving_aot_refused_total").inc()
         return []
-    ser = _cc._serializer()
-    if ser is None:
-        if strict:
-            raise AOTIncompatible(
-                "this jax build cannot deserialize executables")
-        warnings.warn(
-            "serving AOT artifacts refused: this jax build cannot "
-            "deserialize executables (serialize_executable unavailable); "
-            "live jit serves instead (cold compile)", UserWarning,
-            stacklevel=2)
-        _metrics.registry().counter("serving_aot_refused_total").inc()
-        return []
     loaded = []
     for name, entry in manifest.get("programs", {}).items():
         try:
@@ -130,7 +112,7 @@ def load_serving_artifacts(engine, path, strict=False):
                 payload = f.read()
             if hashlib.sha256(payload).hexdigest() != entry.get("sha256"):
                 raise ValueError("artifact checksum mismatch")
-            exec_ = ser[1](*pickle.loads(payload))
+            exec_ = _cc.load_compiled(pickle.loads(payload))
         except Exception as e:
             if strict:
                 raise AOTIncompatible(f"program {name}: {e}")

@@ -153,6 +153,21 @@ def _raise_remote(err):
                       f"{err.get('message', err)!r}")
 
 
+def check_proc_replicas(replicas):
+    """A chip belongs to one process at a time, and every worker claims
+    the host's TPU when it starts: a second worker process fails or
+    hangs.  Say so before anything is spawned."""
+    from ..device import tpu_chips_visible
+    chips = tpu_chips_visible()
+    if chips and replicas > 1:
+        raise SystemExit(
+            f"--proc with {replicas} replicas cannot run on this TPU host "
+            f"({chips} chip(s)): each worker process claims the TPU at "
+            f"start-up and a chip belongs to one process. Placing one "
+            f"worker per chip is not implemented (ROADMAP, Speed queue); "
+            f"run --proc --replicas 1, or in-process replicas.")
+
+
 class ProcReplica(ReplicaHandle):
     """ReplicaHandle over a spawned worker process (see module doc).
 
@@ -235,16 +250,25 @@ class ProcReplica(ReplicaHandle):
                 self._gauges = (int(g[0]), int(g[1]), int(g[2]))
         # unknown events are ignored (forward compatibility)
 
-    def _pump(self):
+    def _pump(self, until_reply=False):
         """Dispatch every frame the kernel already buffered.  Frame
         damage is counted, then surfaces to the caller — whose job is
-        to escalate it into an eviction."""
+        to escalate it into an eviction.
+
+        `until_reply` (the RPC wait) stops at the reply and leaves what
+        follows it buffered: a fast worker streams the first token of a
+        request right behind its `add_request` reply, and the router
+        only learns which request that stream belongs to once the call
+        has returned — dispatched earlier, the token would be dropped
+        as a stale stream."""
         try:
             while True:
                 msg = self.ch.poll()
                 if msg is None:
                     return
                 self._dispatch(msg)
+                if until_reply and self._pending_reply is not None:
+                    return
         except FrameError:
             _metrics.registry().counter(
                 "router_transport_frame_errors_total").inc()
@@ -279,7 +303,7 @@ class ProcReplica(ReplicaHandle):
                 # reply honored — EOF alone is not "no answer"
                 closed = False
                 try:
-                    self._pump()
+                    self._pump(until_reply=True)
                 except ChannelClosed:
                     closed = True
                 if self._pending_reply is not None:
@@ -714,6 +738,10 @@ def main(argv=None):
     if not init or init.get("cmd") != "init":
         print(f"worker {args.name}: no init frame", file=sys.stderr)
         return 2
+    # a process entry point: JAX's compile cache goes where the parent's
+    # environment (or the checkout) says, the same for every worker
+    from ..jit import compile_cache as _cc
+    _cc.place_jax_cache()
     eng, heartbeat, aot_loaded = _build(init.get("spec") or {})
     loop = _WorkerLoop(ch, eng, heartbeat, aot_loaded=aot_loaded,
                        step_delay_s=(init.get("spec") or {}).get(

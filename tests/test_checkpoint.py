@@ -152,7 +152,6 @@ def test_generic_pickle_save_load(tmp_path):
     np.testing.assert_allclose(m2(x).numpy(), m(x).numpy(), rtol=1e-6)
 
 
-@pytest.mark.needs_partial_manual
 def test_fleet_engine_resume_matches_uninterrupted(tmp_path):
     """Checkpoint/resume THROUGH the fleet engine (pp + dp + Adam state):
     save after 2 steps, rebuild everything, load, continue — losses must
@@ -199,7 +198,6 @@ def test_fleet_engine_resume_matches_uninterrupted(tmp_path):
         mesh_mod._state.update(prev)
 
 
-@pytest.mark.needs_partial_manual
 def test_fleet_resume_topology_guards(tmp_path):
     """Wrong-topology or eager-format checkpoints must fail loudly, and a
     save-after-load-before-step round-trip must not drop the moments."""

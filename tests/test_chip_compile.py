@@ -1,0 +1,142 @@
+"""The main path's Pallas kernels, compiled for a DESCRIBED TPU v5e.
+
+Interpret mode runs a kernel as jnp ops and cannot see what the chip's
+compiler refuses (block shapes off the (8, 128) tiling, kernels that GSPMD
+cannot partition).  libtpu can compile for a topology that is described and
+not attached, so these tests catch that on the CPU, about two seconds each.
+
+The topology is described inside a module-scoped fixture — never at import:
+only one process may load libtpu at a time, and every xdist worker imports
+every test file.  All such tests live in THIS file, so one worker owns the
+library.  Nothing here runs a kernel: a compile that passes is not a chip run.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, *structs):
+    return jax.jit(fn).lower(*structs).compile().as_text()
+
+
+def _flash_loss(q, k, v):
+    o = fa.flash_attention(q, k, v, is_causal=True)
+    return jnp.sum(o.astype(jnp.float32) ** 2)
+
+
+@pytest.mark.parametrize("H,Hkv", [(16, 16), (12, 2)],
+                         ids=["mha16x128", "gqa12over2x128"])
+def test_flash_fwd_bwd_compiles(one_chip, H, Hkv):
+    s = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.bfloat16,
+                          sharding=one_chip)
+    q, kv = s((4, 1024, H, 128)), s((4, 1024, Hkv, 128))
+    assert fa.supports(q.shape, kv.shape, None, q.dtype, v_shape=kv.shape,
+                       is_causal=True)
+    text = _compiled_text(jax.grad(_flash_loss, argnums=(0, 1, 2)),
+                          q, kv, kv)
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("dtype,H,Hkv", [
+    (jnp.bfloat16, 16, 16), (jnp.float32, 16, 16), (jnp.bfloat16, 12, 2)],
+    ids=["gpt1.3B-bf16", "gpt1.3B-f32", "gqa12over2-bf16"])
+def test_paged_decode_compiles(one_chip, dtype, H, Hkv):
+    """gpt3-1.3B serving shape: 8 slots, 16 heads x 128, 16-token blocks."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = s((8, 1, H, 128), dtype)
+    pool = s((280, 16, Hkv, 128), dtype)
+    assert pa.supports(q.shape, pool.shape, dtype)
+    text = _compiled_text(pa.paged_decode_attention, q, pool, pool,
+                          s((8, 34), jnp.int32), s((8,), jnp.int32))
+    assert KERNEL in text
+
+
+def test_supports_refuses_what_the_compiler_refuses(one_chip):
+    """float16: Mosaic has no f16 vector load on this chip — supports()
+    must say so, for both kernels, and the compiler must agree."""
+    s = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float16,
+                          sharding=one_chip)
+    q = s((4, 1024, 16, 128))
+    assert not fa.supports(q.shape, q.shape, None, jnp.float16)
+    assert not pa.supports((8, 1, 16, 128), (280, 16, 16, 128), jnp.float16)
+    with pytest.raises(Exception, match="Invalid vector type"):
+        _compiled_text(lambda q, k, v: fa.flash_attention(
+            q, k, v, is_causal=True), q, q, q)
+
+
+def test_flash_through_dispatch_under_2x2_mesh(topo, monkeypatch):
+    """`sdpa_with_flash` under a dp2 x mp2 fleet mesh: bare, the compiler
+    says "Mosaic kernels cannot be automatically partitioned"; the
+    dispatch must wrap the kernel in a shard_map."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.ops import pallas as plo
+    # code that asks jax.devices() sees the CPU here: steer it in the test
+    monkeypatch.setattr(plo, "_mode", lambda: "tpu")
+    # the meshes set below live in a copy that monkeypatch throws away
+    monkeypatch.setattr(mesh_mod, "_state", dict(mesh_mod._state))
+    mesh_mod.set_mesh(Mesh(np.asarray(topo.devices).reshape(2, 1, 2),
+                           mesh_mod.AXES))
+    sh = NamedSharding(mesh_mod.get_mesh(), P("dp", None, "mp", None))
+    q = jax.ShapeDtypeStruct((8, 1024, 16, 128), jnp.bfloat16,
+                             sharding=sh)
+
+    def loss(q, k, v):
+        o = plo.sdpa_with_flash(q, k, v, is_causal=True)
+        return jnp.sum(o.astype(jnp.float32) ** 2)
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
+    assert KERNEL in text
+    # the paged kernel with an mp-sharded pool (BlockPool.shard_)
+    mesh_mod.set_mesh(Mesh(np.asarray(topo.devices).reshape(1, 1, 4),
+                           mesh_mod.AXES))
+    hs = NamedSharding(mesh_mod.get_mesh(), P(None, None, "mp", None))
+    rep = NamedSharding(mesh_mod.get_mesh(), P())
+    s = jax.ShapeDtypeStruct
+    text = _compiled_text(
+        plo.paged_attention_with_pallas,
+        s((8, 1, 16, 128), jnp.bfloat16, sharding=hs),
+        s((280, 16, 16, 128), jnp.bfloat16, sharding=hs),
+        s((280, 16, 16, 128), jnp.bfloat16, sharding=hs),
+        s((8, 34), jnp.int32, sharding=rep),
+        s((8,), jnp.int32, sharding=rep))
+    assert KERNEL in text
+
+
+def test_ring_block_fwd_bwd_compiles(one_chip):
+    """One KV-ring step's kernels (distributed/ring_attention.py)."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    x = s((2, 512, 16, 128), jnp.bfloat16)
+    fwd = _compiled_text(
+        lambda q, k, v: fa.flash_block_fwd(q, k, v, True), x, x, x)
+    bwd = _compiled_text(
+        lambda q, k, v, o, lse, do: fa.flash_block_bwd(
+            q, k, v, o, lse, do, True),
+        x, x, x, x, s((2, 16, 512), jnp.float32), x)
+    assert KERNEL in fwd and KERNEL in bwd

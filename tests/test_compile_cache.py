@@ -18,6 +18,7 @@ import sys
 import textwrap
 import warnings
 
+import jax
 import numpy as np
 import pytest
 
@@ -180,10 +181,9 @@ def test_lookup_miss_mem_hit_flow(tmp_path):
     cc._drop_memo_unsafe()
     runner4, outcome4, extra = FunctionCache(
         "flow", fingerprint=("flow-src",)).lookup(jitted, args)
-    if outcome4 != "bypass":            # jax build can serialize
-        assert outcome4 == "hit"
-        np.testing.assert_allclose(np.asarray(runner4(*args)),
-                                   np.full(4, 3.0))
+    assert outcome4 == "hit"
+    np.testing.assert_allclose(np.asarray(runner4(*args)),
+                               np.full(4, 3.0))
     s = cc.stats()
     assert s["misses"] == 1 and s["puts"] == 1
 
@@ -278,11 +278,6 @@ def test_bucketed_generate_respects_eos(_clean_cache_state):
 # round-trip in-process — see the _MEMO comment for why donated
 # executables are not)
 # ===================================================================
-_AOT_OK = cc._serializer() is not None
-aot_only = pytest.mark.skipif(
-    not _AOT_OK, reason="this jax build cannot serialize executables")
-
-
 class _TinyNet(pt.nn.Layer):
     def __init__(self):
         super().__init__()
@@ -304,7 +299,6 @@ def _export_aot(tmp_path):
     return path, x, m(x).numpy()
 
 
-@aot_only
 def test_aot_roundtrip_serves_without_compilation(tmp_path):
     from paddle_tpu.jit.save_load import load_inference
     path, x, ref = _export_aot(tmp_path)
@@ -314,7 +308,6 @@ def test_aot_roundtrip_serves_without_compilation(tmp_path):
     np.testing.assert_allclose(tl(x).numpy(), ref, atol=1e-6)
 
 
-@aot_only
 def test_aot_refused_with_reason_on_stamp_mismatch(tmp_path,
                                                    _clean_cache_state):
     import json as _json
@@ -339,7 +332,6 @@ def test_aot_refused_with_reason_on_stamp_mismatch(tmp_path,
         load_inference(path, strict_aot=True)
 
 
-@aot_only
 def test_aot_damaged_artifact_falls_back(tmp_path):
     from paddle_tpu.jit.save_load import load_inference
     path, x, ref = _export_aot(tmp_path)
@@ -378,8 +370,11 @@ def test_config_fingerprint_keys_hyperparams_not_runtime_state():
 # satellites riding along
 # ===================================================================
 def test_normalize_cost_analysis_shapes():
+    # the installed jax returns the dict itself; a backend without a
+    # cost model returns None
     assert normalize_cost_analysis(None) == {}
-    assert normalize_cost_analysis([]) == {}
+    assert normalize_cost_analysis({}) == {}
     assert normalize_cost_analysis({"flops": 2.0}) == {"flops": 2.0}
-    assert normalize_cost_analysis([{"flops": 4.0}]) == {"flops": 4.0}
-    assert normalize_cost_analysis(42) == {}
+    cost = jax.jit(lambda x: x @ x).lower(
+        jax.ShapeDtypeStruct((8, 8), "float32")).compile().cost_analysis()
+    assert normalize_cost_analysis(cost)["flops"] > 0

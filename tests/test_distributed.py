@@ -182,7 +182,6 @@ def _tiny_gpt(tp, seed=13, layers=4, recompute=False):
     {"dp_degree": 2, "mp_degree": 1, "pp_degree": 2,
      "accumulate_steps": 2, "virtual_pp_degree": 2},
 ])
-@pytest.mark.needs_partial_manual
 def test_fleet_gpt_pipeline_matches_serial(hybrid):
     """pp>1 fleet step == serial eager training (loss + params)."""
     from paddle_tpu.text import gpt_loss_fn
@@ -219,7 +218,6 @@ def test_fleet_gpt_pipeline_matches_serial(hybrid):
     mesh_mod._state.update(prev)
 
 
-@pytest.mark.needs_partial_manual
 def test_fleet_gpt_pipeline_with_remat_and_zero():
     """pp + recompute + ZeRO-1 still matches serial losses."""
     from paddle_tpu.text import gpt_loss_fn

@@ -251,3 +251,99 @@ def test_supports_gate_round3():
     assert fa.supports(s, s, mask, jnp.float32)
     assert not fa.supports(s, s, object(), jnp.float32)  # weird mask obj
     assert not fa.supports(s, s, None, jnp.int32)
+
+
+# -------------------------------------------- the kernels under a fleet mesh
+# Mosaic kernels cannot be partitioned by GSPMD: under a multi-device mesh
+# the dispatch wrappers run them per shard inside jax.shard_map (batch on
+# dp, heads on mp) or take the XLA path by a rule of supports().
+@pytest.fixture
+def fleet_mesh():
+    from paddle_tpu.distributed import mesh as mesh_mod
+    prev = dict(mesh_mod._state)
+    yield mesh_mod
+    mesh_mod._state.update(prev)
+
+
+def test_supports_gate_mesh_shards():
+    s, skv = (4, 128, 4, 64), (4, 128, 2, 64)
+    assert fa.supports(s, skv, None, jnp.float32, shards=(2, 2))
+    assert not fa.supports(s, skv, None, jnp.float32, shards=(1, 4))  # Hkv
+    assert not fa.supports(s, skv, None, jnp.float32, shards=(8, 1))  # B
+    assert not fa.supports(s, skv, None, jnp.float32, shards=None)
+    m3 = jnp.zeros((4, 128, 128), jnp.float32)
+    assert fa.supports(s, s, m3, jnp.float32)
+    assert not fa.supports(s, s, m3, jnp.float32, shards=(2, 1))
+    assert not fa.supports(s, s, None, jnp.float16)   # Mosaic refuses f16
+
+
+@pytest.mark.parametrize("degrees", [(2, 1, 2), (1, 1, 4), (4, 1, 1)])
+def test_sdpa_override_under_mesh_matches_xla(monkeypatch, fleet_mesh,
+                                              degrees):
+    from paddle_tpu.ops import pallas as plo
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    fleet_mesh.build_mesh(*degrees)
+    rng = np.random.default_rng(11)
+    B, L, H, Hkv, D = 4, 64, 8, 4, 32
+    q = jnp.asarray(rng.standard_normal((B, L, H, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((B, L, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((B, L, Hkv, D)), jnp.float32)
+    mask = jnp.asarray(rng.standard_normal((B, 1, L, L)), jnp.float32)
+    assert plo._shards(plo._mesh_split()) == (degrees[0], degrees[2])
+
+    def loss(fn, q, k, v):
+        return jnp.sum(fn(q, k, v, mask=mask, is_causal=True) ** 2)
+
+    sh = fleet_mesh.sharding("dp", None, "mp", None)
+    args = [jax.device_put(a, sh) for a in (q, k, v)]
+    got = jax.jit(jax.value_and_grad(
+        lambda *a: loss(plo.sdpa_with_flash, *a), argnums=(0, 1, 2)))(*args)
+    ref = jax.value_and_grad(
+        lambda *a: loss(sdpa_k, *a), argnums=(0, 1, 2))(q, k, v)
+    assert "shard_map" in str(jax.make_jaxpr(
+        lambda *a: plo.sdpa_with_flash(*a, is_causal=True))(q, k, v))
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(ref)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=2e-4, atol=2e-4)
+
+
+def test_sdpa_override_mesh_gates_to_xla(monkeypatch, fleet_mesh):
+    """Heads that do not divide mp, and a live axis the kernel cannot be
+    split over (pp outside a pipeline stage body), take the XLA path."""
+    from paddle_tpu.ops import pallas as plo
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    rng = np.random.default_rng(12)
+    q, k, v = _rand_qkv(rng, 2, 32, 6, 16)
+    for degrees in [(1, 1, 4), (1, 2, 2)]:
+        fleet_mesh.build_mesh(*degrees)
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a: plo.sdpa_with_flash(*a, is_causal=True))(q, k, v))
+        assert "shard_map" not in jaxpr and "pallas_call" not in jaxpr
+
+
+def test_paged_override_under_mp_mesh(monkeypatch, fleet_mesh):
+    from paddle_tpu.ops import pallas as plo
+    from paddle_tpu.ops.nn_kernels import paged_attention_k
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    fleet_mesh.build_mesh(1, 1, 2)
+    rng = np.random.RandomState(0)
+    B, H, Hkv, D, bs, N, M = 3, 8, 4, 128, 8, 12, 4
+    q = jnp.asarray(rng.randn(B, 1, H, D), jnp.float32)
+    kp = jnp.asarray(rng.randn(N, bs, Hkv, D), jnp.float32)
+    vp = jnp.asarray(rng.randn(N, bs, Hkv, D), jnp.float32)
+    tables = jnp.asarray(rng.permutation(N)[:B * M].reshape(B, M),
+                         jnp.int32)
+    pos = jnp.asarray([5, 17, 30], jnp.int32)
+    sh = fleet_mesh.sharding(None, None, "mp", None)
+    got = jax.jit(plo.paged_attention_with_pallas)(
+        jax.device_put(q, sh), jax.device_put(kp, sh),
+        jax.device_put(vp, sh), tables, pos)
+    ref = paged_attention_k(q, kp, vp, tables, pos)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                               rtol=2e-5, atol=2e-6)
+    # kv heads that do not divide mp: gated, never raised from a trace
+    fleet_mesh.build_mesh(1, 1, 8)
+    jaxpr = str(jax.make_jaxpr(plo.paged_attention_with_pallas)(
+        q, kp, vp, tables, pos))
+    assert "pallas_call" not in jaxpr

@@ -82,18 +82,12 @@ def test_1f1b_matches_gpipe_pp4(restore_mesh):
     _assert_parity(restore_mesh, pp=4, M=4, layers=8)
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing at seed: old-shard_map transpose (_SpecError) "
-           "under jax 0.4.37 via framework/compat.py; unblocks with the "
-           "ROADMAP item-3c migration off the compat shims")
 def test_1f1b_matches_gpipe_moe(restore_mesh):
     """Router aux losses (and their gradients) ride the custom bwd via the
     daux cotangent — parity must hold including the aux term."""
     _assert_parity(restore_mesh, pp=2, M=2, moe=True)
 
 
-@pytest.mark.needs_partial_manual
 def test_1f1b_matches_gpipe_dp_x_pp(restore_mesh):
     """dp stays a GSPMD annotation inside the partial-manual shard_map in
     both the forward AND the hand-written backward."""

@@ -66,12 +66,7 @@ def _bn_stats(model):
     ("1F1B", 1, 2),
     ("F-then-B", 1, 2),
     ("1F1B", 2, 4),
-    pytest.param("F-then-B", 2, 4, marks=pytest.mark.xfail(
-        strict=False,
-        reason="pre-existing at seed: interleaved-buffer numeric drift "
-               "under jax 0.4.37's old-shard_map compat path "
-               "(framework/compat.py); unblocks with the ROADMAP "
-               "item-3c migration off the compat shims")),
+    ("F-then-B", 2, 4),
 ])
 def test_pp_bn_running_stats_match_serial(restore_mesh, sched, vpp, M):
     B, width = 8, 16

@@ -282,7 +282,8 @@ def test_paged_attention_supports_gate():
     ok = ((3, 1, 4, 128), (12, 8, 2, 128))
     assert pa.supports(*ok, jnp.float32)
     assert not pa.supports((3, 2, 4, 128), ok[1], jnp.float32)  # prefill
-    assert not pa.supports(ok[0], (12, 6, 2, 128), jnp.float32)  # bs % 8
+    assert pa.supports(ok[0], (12, 6, 2, 128), jnp.float32)  # any bs
+    assert not pa.supports(ok[0], ok[1], jnp.float16)  # Mosaic refuses
     assert not pa.supports(ok[0], (12, 8, 3, 128), jnp.float32)  # H % Hkv
     assert not pa.supports((3, 1, 4, 64), (12, 8, 2, 64),
                            jnp.float32)                # unaligned head_dim

@@ -389,3 +389,83 @@ def test_chaos_check_router_proc_drill():
     # itself re-checks PROC_BUDGET_S internally)
     assert elapsed < mod.PROC_BUDGET_S, (
         f"proc drill took {elapsed:.0f}s — too slow for tier-1")
+
+
+# ===================================================================
+# one process per chip: the --proc parent leaves the device to workers
+# ===================================================================
+def test_serve_proc_parent_initialises_no_backend():
+    """`tools/serve.py --proc`: a chip belongs to one process at a time,
+    so the parent must not build a model, seed, or otherwise initialise
+    a jax backend before or while its workers live.  Asserted INSIDE
+    the spawned parent, at the moment the tier is closed (the workers
+    are still running then) and again after it returned."""
+    import subprocess
+    import sys
+    import textwrap
+    code = textwrap.dedent(f"""
+        import sys
+        sys.path.insert(0, {REPO!r})
+        sys.path.insert(0, {os.path.join(REPO, "tools")!r})
+        import serve
+        from jax._src import xla_bridge
+        from paddle_tpu import serving
+
+        seen = []
+        close = serving.Router.close
+
+        def spying_close(self):
+            alive = [s.handle.proc.poll() is None for s in self._slots
+                     if s.handle is not None]
+            seen.append((alive, sorted(xla_bridge._backends)))
+            return close(self)
+
+        serving.Router.close = spying_close
+        rc = serve.main(["--proc", "--replicas", "1", "--random", "4",
+                         "--max-new-tokens", "4", "-q"])
+        assert rc == 0, rc
+        assert seen == [([True], [])], seen
+        assert not xla_bridge._backends, sorted(xla_bridge._backends)
+        print("PARENT_HAS_NO_BACKEND")
+    """)
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=300,
+                       env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "PARENT_HAS_NO_BACKEND" in r.stdout
+    assert r.stdout.count("DONE (length)") == 4
+
+
+def test_check_proc_replicas_refuses_a_second_worker_on_a_chip(monkeypatch):
+    from paddle_tpu import device
+    monkeypatch.setattr(device, "tpu_chips_visible", lambda: 1)
+    sw.check_proc_replicas(1)
+    with pytest.raises(SystemExit, match="belongs to one process"):
+        sw.check_proc_replicas(2)
+    monkeypatch.setattr(device, "tpu_chips_visible", lambda: 0)
+    sw.check_proc_replicas(4)          # no TPU here: CPU tiers are free
+
+
+def test_rpc_wait_leaves_frames_behind_the_reply_buffered():
+    """A fast worker streams a request's first token right behind its
+    `add_request` reply.  The router binds the stream to the request
+    only after the call returns, so the RPC wait must stop at the reply
+    — dispatched inside the wait, that token was dropped as "stale"
+    (seen as a 15-of-16-token stream in the kill -9 drill under load)."""
+    frames = [{"reply": "add_request", "ok": True},
+              {"ev": "tok", "rid": 0, "tok": 42}]
+
+    class FakeChannel:
+        def poll(self):
+            return frames.pop(0) if frames else None
+
+    h = sw.ProcReplica.__new__(sw.ProcReplica)
+    h.ch, h._pending_reply = FakeChannel(), None
+    seen = []
+    h._reqs = {0: sw.RemoteRequest(
+        0, on_token=lambda rq, tok: seen.append(tok))}
+    h._pump(until_reply=True)
+    assert h._pending_reply == {"reply": "add_request", "ok": True}
+    assert seen == [] and len(frames) == 1      # the token still waits
+    h._pump()
+    assert seen == [42]

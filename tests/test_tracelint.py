@@ -397,12 +397,14 @@ def test_shard_map_compat_partial_manual_contract():
     devs = jax.devices()
     if len(devs) < 4:
         pytest.skip("needs >=4 devices")
+    import jax.numpy as jnp
     mesh = Mesh(np.array(devs[:4]).reshape(2, 2), ("pp", "dp"))
-    if compat.HAS_PARTIAL_MANUAL:
-        pytest.skip("native partial-manual support — no shim contract")
-    with pytest.raises(NotImplementedError, match="partial-manual"):
-        compat.shard_map(lambda a: a, mesh, in_specs=P("pp"),
-                         out_specs=P("pp"), axis_names={"pp"})
+    # manual over pp only, GSPMD keeps dp: each pp shard sees its half
+    f = compat.shard_map(lambda a: a + compat.axis_index("pp"), mesh,
+                         in_specs=P("pp"), out_specs=P("pp"),
+                         axis_names={"pp"})
+    out = jax.jit(f)(jnp.zeros((4, 2)))
+    np.testing.assert_allclose(np.asarray(out)[:, 0], [0, 0, 1, 1])
 
 
 # ===================================================================

@@ -1101,6 +1101,11 @@ def run_router_proc(out=None, verbose=False):
         if verbose:
             print(msg, file=out)
 
+    # two worker processes, and a parent that builds the reference model
+    # and the AOT artifacts itself: fine on the CPU, impossible on a TPU
+    # host, where a chip belongs to one process — refuse before spawning
+    sw.check_proc_replicas(2)
+
     t_start = time.monotonic()
     failures = []
     reg = metrics.registry()
@@ -1127,6 +1132,12 @@ def run_router_proc(out=None, verbose=False):
     aot_dir = tempfile.mkdtemp(prefix="chaos_proc_aot_")
     aot_ok = False
     pids = []
+    # a mesh leaked by an earlier in-process caller would be stamped
+    # into the AOT artifacts, and the mesh-less workers would refuse
+    # them ("mesh topology"): export from the state a worker starts in
+    from paddle_tpu.distributed import mesh as _mesh
+    prior_mesh = _mesh._state["mesh"]
+    _mesh.clear_mesh()
     try:
         # AOT artifacts exported ONCE so every worker — and every
         # backoff respawn — warm-starts through the PR-8 path
@@ -1335,6 +1346,8 @@ def run_router_proc(out=None, verbose=False):
             except (ProcessLookupError, PermissionError):
                 pass
         shutil.rmtree(aot_dir, ignore_errors=True)
+        if prior_mesh is not None:
+            _mesh.set_mesh(prior_mesh)
 
     elapsed = time.monotonic() - t_start
     if elapsed > PROC_BUDGET_S:

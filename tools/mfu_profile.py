@@ -26,8 +26,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-PEAK_TFLOPS = 197.0
-
 
 def run_variant(preset, seq, batch, steps, trace=False, cpu=False):
     import jax
@@ -66,7 +64,8 @@ def run_variant(preset, seq, batch, steps, trace=False, cpu=False):
 
     n_params = int(sum(p.size for p in model.parameters()))
     tps = batch * seq / dt
-    mfu = 6.0 * n_params * tps / (PEAK_TFLOPS * 1e12)
+    from paddle_tpu.device import peak_bf16_tflops
+    mfu = 6.0 * n_params * tps / (peak_bf16_tflops() * 1e12)
 
     # donation audit: live HBM peak vs the param+state footprint.  With
     # donation working, peak ~= params(bf16) + opt state + activations;

@@ -122,10 +122,11 @@ def main(argv=None):
     import numpy as np
     import paddle_tpu as pt
     from paddle_tpu import serving
+    from paddle_tpu.jit import compile_cache
     from paddle_tpu.observability import metrics
     from paddle_tpu.text import GPTConfig, GPTForCausalLM
 
-    pt.seed(0)
+    compile_cache.place_jax_cache()     # workers inherit the directory
     tiny_kw = dict(vocab_size=256, hidden_size=64, num_layers=2,
                    num_heads=4, max_position_embeddings=256,
                    hidden_dropout=0.0, attention_dropout=0.0,
@@ -152,10 +153,13 @@ def main(argv=None):
 
     router = None
     if args.proc:
-        # process-per-replica tier: no model in THIS process — each
-        # worker re-derives it from the spec (seed 0 + the config) and
-        # warm-starts itself from --load-aot; respawns do the same
+        # process-per-replica tier: no model, no seed, no backend in
+        # THIS process — a chip belongs to one process, and it is the
+        # worker's.  Each worker re-derives the model from the spec
+        # (seed 0 + the config) and warm-starts itself from --load-aot;
+        # respawns do the same
         from paddle_tpu.serving import worker as sw
+        sw.check_proc_replicas(args.replicas)
         spec = sw.gpt_spec(preset=args.preset or None,
                            overrides=preset_kw if args.preset else None,
                            config=None if args.preset else tiny_kw,
@@ -176,6 +180,7 @@ def main(argv=None):
         while stop["sig"] is None and not router.wait_ready(timeout=0.5):
             pass
     else:
+        pt.seed(0)
         with pt.LazyGuard():
             model = GPTForCausalLM(cfg)
 
