@@ -364,17 +364,19 @@ def run_serving(preset="gpt3-125M", n_requests=24, arrival_rate=8.0,
                     max_running=max_running, prefill_chunk=64)
     _warm_serving_buckets(eng, rs, cfg, prompts, max_len)
 
-    # engine latency fields (arrival_t/first_token_t) use time.monotonic,
-    # so the trace clock must too; TTFT is measured against the VIRTUAL
-    # Poisson arrival (t0 + arrivals[i]) — a request whose arrival lands
-    # mid-step is submitted late, and that wait belongs IN its TTFT
+    # engine latency fields (arrival_t/first_token_t) are on the span
+    # recorder's clock, so this loop's clock is too; TTFT is measured
+    # against the VIRTUAL Poisson arrival (t0 + arrivals[i]) — a request
+    # whose arrival lands mid-step is submitted late, and that wait
+    # belongs IN its TTFT
     # (excluding it would flatter exactly the loaded regime this bench
     # exists to characterize)
-    t0 = time.monotonic()
+    from paddle_tpu.serving.scheduler import clock
+    t0 = clock()
     submitted = 0
     reqs = []
     while submitted < n_requests or eng.has_work:
-        now = time.monotonic() - t0
+        now = clock() - t0
         while submitted < n_requests and arrivals[submitted] <= now:
             reqs.append(eng.add_request(prompts[submitted],
                                         max_new_tokens=new_tokens))
@@ -383,7 +385,7 @@ def run_serving(preset="gpt3-125M", n_requests=24, arrival_rate=8.0,
             eng.step()
         elif submitted < n_requests:
             time.sleep(min(0.001, arrivals[submitted] - now))
-    dt_engine = time.monotonic() - t0
+    dt_engine = clock() - t0
     gen_tokens = sum(len(r.generated) for r in reqs)
     ttft = sorted(r.first_token_t - (t0 + arrivals[i])
                   for i, r in enumerate(reqs))
@@ -428,13 +430,14 @@ def run_serving(preset="gpt3-125M", n_requests=24, arrival_rate=8.0,
 
 def _drive_trace(submit, backend, trace_arrivals, trace_prompts):
     """Feed the virtual-arrival trace; TTFT/TPOT measured per
-    request against its VIRTUAL arrival on one monotonic clock
+    request against its VIRTUAL arrival on the engine's clock
     (submit lag inside a step is part of the latency)."""
     from paddle_tpu.serving import ShedRequest
-    t0 = time.monotonic()
+    from paddle_tpu.serving.scheduler import clock
+    t0 = clock()
     submitted, reqs, shed = 0, [], 0
     while submitted < len(trace_prompts) or backend.has_work:
-        now = time.monotonic() - t0
+        now = clock() - t0
         while submitted < len(trace_prompts) and \
                 trace_arrivals[submitted] <= now:
             try:
@@ -448,7 +451,7 @@ def _drive_trace(submit, backend, trace_arrivals, trace_prompts):
         elif submitted < len(trace_prompts):
             time.sleep(min(0.001,
                            trace_arrivals[submitted] - now))
-    dt = time.monotonic() - t0
+    dt = clock() - t0
     ttft = [r.first_token_t - (t0 + trace_arrivals[i])
             for i, r in enumerate(reqs)
             if r is not None and r.first_token_t is not None]

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 from .. import observability as _obs
 from ..framework import random as _random
 from ..observability import compile_tracker as _ct
+from ..observability import trace as _trace
 from ..resilience import chaos as _chaos
 from ..resilience import guard as _guard
 from ..tensor import Tensor
@@ -175,6 +176,15 @@ class TrainStep:
         return pn, pa, bn, ba
 
     def __call__(self, *batch):
+        """One fused step.  The host's share of it is the span
+        `train.call`; its children `train.call.lookup` (state and
+        executable resolved) and `train.call.dispatch` (the runner call,
+        which returns before the device finishes) split it."""
+        with _trace.traced("train.call", cat="step") as root:
+            return self._call(batch, root.sid)
+
+    def _call(self, batch, parent):
+        t_lookup = _trace.now_ns()
         model, optimizer = self.model, self.optimizer
         pn, pa, bn, ba = self._state()
         self._step += 1
@@ -216,6 +226,9 @@ class TrainStep:
                     self._jitted, args, static=(self._bake_key,),
                     plain_jit=self._plain_jit)
                 self._cc_resolved = (bkey, runner)
+        t_dispatch = _trace.now_ns()
+        _trace.record("train.call.lookup", t_lookup, t_dispatch,
+                      parent=parent, cat="step")
         try:
             loss, new_params, new_buffers, self._opt_state, finite, ok = \
                 runner(*args)
@@ -223,6 +236,8 @@ class TrainStep:
             if tok is not None:
                 _ct.abort(tok)
             raise
+        _trace.record("train.call.dispatch", t_dispatch, _trace.now_ns(),
+                      parent=parent, cat="step")
         if tok is not None:
             # "mem" (process-global memo reuse) did not compile either —
             # reporting it as a compile would corrupt jit_compiles_total

@@ -23,12 +23,13 @@ execution — pair with the device xplane trace for on-device timing.
 from __future__ import annotations
 
 import collections
+import contextlib
 
 from . import metrics  # noqa: F401
 from . import trace  # noqa: F401
 from . import compile_tracker  # noqa: F401
 from .metrics import MetricsRegistry, registry  # noqa: F401
-from .trace import span, chrome_trace, export_chrome_trace  # noqa: F401
+from .trace import chrome_trace, export_chrome_trace  # noqa: F401
 from .compile_tracker import RecompileWarning  # noqa: F401
 
 __all__ = ["enable", "disable", "enabled", "reset", "dispatch_stats",
@@ -43,6 +44,16 @@ _comms_tel = None
 
 def enabled() -> bool:
     return _enabled
+
+
+def span(name, cat="host", args=None):
+    """Record the enclosed block as one span of the recorder
+    (`trace.traced`) while telemetry is enabled; a no-op otherwise.  The
+    serving engine and `TrainStep` call `trace.traced` themselves and
+    always record."""
+    if not _enabled:
+        return contextlib.nullcontext()
+    return trace.traced(name, cat=cat, counts=args)
 
 
 class _DispatchTelemetry:

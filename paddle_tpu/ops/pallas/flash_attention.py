@@ -222,6 +222,7 @@ def _fwd(q, k, v, mask, causal, scale, bq, bk, interpret, H, Hkv, mask_meta,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
         **kwargs,
     )(*args)
 
@@ -404,6 +405,7 @@ def _bwd(q, k, v, o, lse, do, mask, causal, scale, bq, bk, interpret, H, Hkv,
             pltpu.VMEM((bk, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
         **kw,
     )(*args)
 
@@ -436,6 +438,7 @@ def _bwd(q, k, v, o, lse, do, mask, causal, scale, bq, bk, interpret, H, Hkv,
             pltpu.VMEM((bq, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
         **kw,
     )(*args2)
     return dq, dk, dv

@@ -28,7 +28,6 @@ docs/serving.md for the full table.
 """
 from __future__ import annotations
 
-import time
 import warnings
 
 import numpy as np
@@ -38,11 +37,12 @@ import jax.numpy as jnp
 from ..autograd import engine as _autograd
 from ..jit import functional_bridge as FB
 from ..observability import metrics as _metrics
+from ..observability import trace as _trace
 from ..resilience import chaos
 from ..tensor import Tensor
 from ..text.generation import BucketPolicy
 from .block_pool import BlockPool, PoolExhausted
-from .scheduler import RUNNING, Request, Scheduler
+from .scheduler import RUNNING, Request, Scheduler, clock
 
 
 class ShedRequest(RuntimeError):
@@ -115,7 +115,9 @@ class LLMEngine:
         the survivor re-prefills prompt+resume and continues decoding at
         the next position — the preemption-resume path, so continuation
         is token-identical).  `arrival_t` preserves the original arrival
-        across a failover so `ttl_s` keeps meaning total lifetime.
+        across a failover so `ttl_s` keeps meaning total lifetime: seconds
+        on `scheduler.clock()` (Unix time, the span recorder's clock), NOT
+        `time.monotonic()`, against which a TTL would expire at once.
         `shed_exempt` bypasses the admission watermarks: a failed-over
         request already held capacity once — shedding it would tear a
         live stream to save queue slots it is owed.
@@ -215,46 +217,67 @@ class LLMEngine:
 
     # ----------------------------------------------------------------- step
     def step(self):
-        """One continuous-batching iteration.  Returns a summary dict."""
+        """One continuous-batching iteration.  Returns a summary dict.
+
+        Every step writes its phases to the span recorder (see
+        docs/serving.md): `serving.step` is the root, its children name
+        what the host does while the device waits or works."""
         sched = self.scheduler
-        now = time.monotonic()
-        self._expire(now)
-        admitted = sched.admit()
-        for req in admitted:
-            self._reg.counter("serving_requests_admitted_total").inc()
-            self._reg.histogram("serving_queue_wait_seconds").observe(
-                now - req.arrival_t)
+        with _trace.traced("serving.step", cat="serving") as root:
+            with _trace.traced("serving.schedule", parent=root.sid,
+                               cat="serving"):
+                now = clock()
+                self._expire(now)
+                admitted = sched.admit()
+                for req in admitted:
+                    self._reg.counter(
+                        "serving_requests_admitted_total").inc()
+                    self._reg.histogram(
+                        "serving_queue_wait_seconds").observe(
+                            now - req.arrival_t)
+                    if req.admitted_t is None:
+                        req.admitted_t = now
+                        if not req.needs_prefill:   # a one-token prompt
+                            req.prefill_done_t = now
 
-        # ---- prefill lane: a bounded token budget per step
-        budget = self.prefill_chunk
-        prefilled = 0
-        for req in list(sched.running):
-            if budget <= 0:
-                break
-            if not req.needs_prefill:
-                continue
-            n = min(budget, req.feed_len - 1 - req.ctx)
-            self._prefill(req, n)
-            budget -= n
-            prefilled += n
+            # ---- prefill lane: a bounded token budget per step
+            budget = self.prefill_chunk
+            prefilled = 0
+            for req in list(sched.running):
+                if budget <= 0:
+                    break
+                if not req.needs_prefill:
+                    continue
+                n = min(budget, req.feed_len - 1 - req.ctx)
+                self._prefill(req, n, root.sid)
+                if req.prefill_done_t is None and not req.needs_prefill:
+                    req.prefill_done_t = clock()
+                budget -= n
+                prefilled += n
 
-        # ---- decode lane: every decode-ready request advances one token
-        ready = []
-        for req in [r for r in sched.running if r.decode_ready]:
-            if req.state != RUNNING:
-                continue            # a victim of an earlier grow()
-            if sched.grow(req):
-                ready.append(req)
-        ready = [r for r in ready if r.state == RUNNING]
-        # ready ⊆ running and admit() caps running at max_running, so
-        # the static decode program always has a slot for every row
-        assert len(ready) <= self.max_running
-        if ready:
-            self._decode(ready)
+            # ---- decode lane: every decode-ready request advances one
+            # token
+            with _trace.traced("serving.schedule", parent=root.sid,
+                               cat="serving"):
+                ready = []
+                for req in [r for r in sched.running if r.decode_ready]:
+                    if req.state != RUNNING:
+                        continue        # a victim of an earlier grow()
+                    if sched.grow(req):
+                        ready.append(req)
+                ready = [r for r in ready if r.state == RUNNING]
+            # ready ⊆ running and admit() caps running at max_running, so
+            # the static decode program always has a slot for every row
+            assert len(ready) <= self.max_running
+            if ready:
+                self._decode(ready, root.sid)
 
-        self._reg.gauge("serving_queue_depth").set(sched.queue_depth)
-        self._reg.gauge("serving_running_requests").set(len(sched.running))
-        self._reg.gauge("serving_free_blocks").set(self.pool.free_blocks)
+            self._reg.gauge("serving_queue_depth").set(sched.queue_depth)
+            self._reg.gauge("serving_running_requests").set(
+                len(sched.running))
+            self._reg.gauge("serving_free_blocks").set(
+                self.pool.free_blocks)
+            root.counts["decode_rows"] = len(ready)
         return {"admitted": len(admitted), "decoded": len(ready),
                 "prefilled": prefilled,
                 "running": len(sched.running),
@@ -289,11 +312,11 @@ class LLMEngine:
                       if r.finish_reason == "drained")
         for req in list(self.scheduler.waiting):
             self._finish(req, "drained")
-        deadline = None if ttl_s is None else time.monotonic() + ttl_s
+        deadline = None if ttl_s is None else clock() + ttl_s
         n = 0
         while self.scheduler.running and \
                 (max_steps is None or n < max_steps):
-            if deadline is not None and time.monotonic() > deadline:
+            if deadline is not None and clock() > deadline:
                 for req in list(self.scheduler.running):
                     self._finish(req, "drained")
                 break
@@ -454,48 +477,66 @@ class LLMEngine:
         raise KeyError(f"unknown serving program key {key!r}")
 
     # ------------------------------------------------------------- prefill
-    def _prefill(self, req, n):
+    def _prefill(self, req, n, parent=None):
         bucket = self.policy.bucket(n)
-        feed = req.feed_tokens()
-        chunk = feed[req.ctx:req.ctx + n]
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :n] = chunk
-        table = np.zeros((1, self.table_cols), np.int32)
-        table[0, :len(req.block_table)] = req.block_table
-        pos = np.asarray([req.ctx], np.int32)
-        limit = np.asarray([req.ctx + n], np.int32)
-        ks, vs = self._run_program(
-            ("prefill", bucket), self._build_prefill,
-            self._p_arrays, self._b_arrays, self.pool.k, self.pool.v,
-            table, pos, tokens, limit)
-        self.pool.k, self.pool.v = list(ks), list(vs)
+        with _trace.traced("serving.prefill", parent=parent, rid=req.id,
+                           cat="serving"):
+            feed = req.feed_tokens()
+            chunk = feed[req.ctx:req.ctx + n]
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :n] = chunk
+            table = np.zeros((1, self.table_cols), np.int32)
+            table[0, :len(req.block_table)] = req.block_table
+            pos = np.asarray([req.ctx], np.int32)
+            limit = np.asarray([req.ctx + n], np.int32)
+            ks, vs = self._run_program(
+                ("prefill", bucket), self._build_prefill,
+                self._p_arrays, self._b_arrays, self.pool.k, self.pool.v,
+                table, pos, tokens, limit)
+            self.pool.k, self.pool.v = list(ks), list(vs)
         req.ctx += n
         self._reg.counter("serving_prefill_tokens_total").inc(n)
 
     # -------------------------------------------------------------- decode
-    def _decode(self, ready):
+    def _decode(self, ready, parent=None):
         R, M = self.max_running, self.table_cols
-        tables = np.zeros((R, M), np.int32)
-        pos = np.zeros(R, np.int32)
-        tokens = np.zeros(R, np.int32)
-        limit = np.zeros(R, np.int32)    # 0 = dead slot, writes dropped
-        for i, req in enumerate(ready):
-            tables[i, :len(req.block_table)] = req.block_table
-            pos[i] = req.ctx
-            tokens[i] = req.feed_tokens()[req.ctx]
-            limit[i] = req.ctx + 1
-        logits, ks, vs = self._run_program(
-            ("decode",), self._build_decode,
-            self._p_arrays, self._b_arrays, self.pool.k, self.pool.v,
-            tables, pos, tokens, limit)
-        self.pool.k, self.pool.v = list(ks), list(vs)
-        rows = np.asarray(logits)
-        now = time.monotonic()
-        self._reg.counter("serving_decode_steps_total").inc()
-        self._reg.histogram("serving_decode_batch").observe(len(ready))
-        for i, req in enumerate(ready):
-            req.ctx += 1
-            self._emit(req, rows[i], now)
+        with _trace.traced("serving.decode.prepare", parent=parent,
+                           cat="serving"):
+            tables = np.zeros((R, M), np.int32)
+            pos = np.zeros(R, np.int32)
+            tokens = np.zeros(R, np.int32)
+            limit = np.zeros(R, np.int32)   # 0 = dead slot, writes dropped
+            for i, req in enumerate(ready):
+                tables[i, :len(req.block_table)] = req.block_table
+                pos[i] = req.ctx
+                tokens[i] = req.feed_tokens()[req.ctx]
+                limit[i] = req.ctx + 1
+        with _trace.traced("serving.decode.dispatch", parent=parent,
+                           cat="serving"):
+            logits, ks, vs = self._run_program(
+                ("decode",), self._build_decode,
+                self._p_arrays, self._b_arrays, self.pool.k, self.pool.v,
+                tables, pos, tokens, limit)
+            self.pool.k, self.pool.v = list(ks), list(vs)
+        # the wait is its own span, so that the fetch times the copy
+        # alone.  The copy is queued behind the program first, as a bare
+        # np.asarray would queue it: left to start after the wait has
+        # returned it costs the step 0.2 ms (PERF.md, PR 25)
+        with _trace.traced("serving.decode.wait", parent=parent,
+                           cat="serving"):
+            logits.copy_to_host_async()
+            logits.block_until_ready()
+        with _trace.traced("serving.decode.fetch", parent=parent,
+                           cat="serving"):
+            rows = np.asarray(logits)
+        with _trace.traced("serving.sample", parent=parent,
+                           cat="serving"):
+            now = clock()
+            self._reg.counter("serving_decode_steps_total").inc()
+            self._reg.histogram("serving_decode_batch").observe(len(ready))
+            for i, req in enumerate(ready):
+                req.ctx += 1
+                self._emit(req, rows[i], now)
 
     def _emit(self, req, logits_row, now):
         if req.poisoned:
@@ -535,6 +576,7 @@ class LLMEngine:
             return        # already settled: finishing is idempotent
         self.scheduler.finish(req, reason)
         self._finished.append(req)
+        self._record_request(req)
         if reason in ("eos", "length"):
             self._reg.counter("serving_requests_finished_total").inc()
         elif reason in ("error", "cancelled"):
@@ -549,6 +591,20 @@ class LLMEngine:
             self._reg.counter("serving_requests_failed_total").inc()
         if req.on_finish is not None:
             req.on_finish(req)
+
+    @staticmethod
+    def _record_request(req):
+        """The request's life as ONE `serving.request` span, arrival to
+        finish, with the marks in between (ns on the recorder's clock; a
+        mark it never reached is left out) as its counts."""
+        marks = {"admitted": req.admitted_t,
+                 "prefill_done": req.prefill_done_t,
+                 "first_token": req.first_token_t}
+        _trace.record(
+            "serving.request", int(req.arrival_t * 1e9), _trace.now_ns(),
+            rid=req.id, cat="serving",
+            counts={k: int(t * 1e9) for k, t in marks.items()
+                    if t is not None})
 
 
 def _sample_row(req, logits_row):

@@ -79,6 +79,7 @@ from ..observability import metrics as _metrics
 from ..resilience import chaos
 from ..resilience.backoff import Backoff, CrashLoopDetector
 from .engine import ShedRequest
+from .scheduler import clock
 
 # replica-slot states
 HEALTHY = "healthy"
@@ -263,7 +264,7 @@ class RoutedRequest:
         self.finish_reason = None
         self.replica_names = []     # every replica that served this req
         self.unplaced_since = None  # waiting at the router for a replica
-        self.arrival_t = time.monotonic()
+        self.arrival_t = clock()    # goes with the request to its replica
         self.first_token_t = None
         self.last_token_t = None
 
@@ -525,7 +526,7 @@ class Router:
             # seeded resume tokens, so its length IS the absolute
             # stream position (+1) of this token
             pos = len(ereq.generated) - 1
-            now = time.monotonic()
+            now = clock()
             if pos < len(rr.emitted):
                 # failover overlap: the survivor re-generated a token
                 # the client already has.  Dedup it — and require it to
@@ -643,7 +644,7 @@ class Router:
         for rr in self._unplaced:
             if rr.state != "live":
                 continue
-            if rr.ttl_s is not None and now - rr.arrival_t > rr.ttl_s:
+            if rr.ttl_s is not None and clock() - rr.arrival_t > rr.ttl_s:
                 self._settle(rr, "expired", "expired-ttl")
             elif (rr.queue_deadline_s is not None
                   and rr.unplaced_since is not None

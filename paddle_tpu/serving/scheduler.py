@@ -37,7 +37,8 @@ Policy (vLLM-style, adapted to the static-slot decode program):
 from __future__ import annotations
 
 import collections
-import time
+
+from ..observability import trace as _trace
 
 WAITING = "waiting"
 RUNNING = "running"
@@ -45,6 +46,13 @@ PREEMPTED = "preempted"
 FINISHED = "finished"
 FAILED = "failed"
 EXPIRED = "expired"
+
+
+def clock():
+    """Seconds on the span recorder's clock (Unix time, monotonic): every
+    mark of a request is taken on it, so that a request's life and the
+    engine steps that served it can be laid side by side."""
+    return _trace.now_ns() * 1e-9
 
 
 class Request:
@@ -89,9 +97,13 @@ class Request:
         self.admit_skips = 0        # head-of-line blocked admit passes
         self.promoted = False       # starvation guard: victim immunity
 
-        self.arrival_t = (time.monotonic() if arrival_t is None
+        # marks, in seconds on `clock()`; the engine writes them out as
+        # one `serving.request` span when the request finishes
+        self.arrival_t = (clock() if arrival_t is None
                           else float(arrival_t))
-        self.queued_t = time.monotonic()   # start of the CURRENT wait
+        self.queued_t = clock()     # start of the CURRENT wait
+        self.admitted_t = None      # first admission
+        self.prefill_done_t = None  # the step that made it decode-ready
         self.queue_deadline_s = (None if queue_deadline_s is None
                                  else float(queue_deadline_s))
         self.ttl_s = None if ttl_s is None else float(ttl_s)
@@ -160,7 +172,7 @@ class Scheduler:
 
     def submit(self, req):
         req.state = WAITING
-        req.queued_t = time.monotonic()
+        req.queued_t = clock()
         self.waiting.append(req)
 
     def admit(self):
@@ -239,7 +251,7 @@ class Scheduler:
         req.ctx = 0
         req.preemptions += 1
         req.state = PREEMPTED
-        req.queued_t = time.monotonic()   # re-arm the queue-wait clock
+        req.queued_t = clock()      # re-arm the queue-wait clock
         self._maybe_promote(req)
         self.running.remove(req)
         self.waiting.appendleft(req)
