@@ -130,17 +130,31 @@ override("sdpa", sdpa_with_flash)
 _xla_paged_attention = get("paged_attention").fn
 
 
+def _paged_kernel(q_shape, pool_shape, dtype):
+    """(mode, split) where the pallas kernel serves a `paged_attention`
+    call of these shapes, here and now (backend, PADDLE_TPU_PALLAS, the
+    mesh); None where the XLA gather does."""
+    mode = _mode()
+    if mode is None:
+        return None
+    split = _mesh_split()
+    shards = _shards(split)
+    if shards is None or not _pa.supports(q_shape, pool_shape, dtype,
+                                          mp=shards[1]):
+        return None
+    return mode, split
+
+
 def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None):
     """Serving decode steps stream blocks through the pallas kernel;
     prefill chunks (s > 1) and unsupported shapes keep the XLA gather
     fallback, which is also the parity reference.  Under a mesh the
     kernel sees its replica's local heads: q and the pool shard on "mp"
     (`BlockPool.shard_`), tables and positions are replicated."""
-    mode = _mode()
-    split = _mesh_split() if mode is not None else None
-    shards = _shards(split)
-    if mode is not None and shards is not None and \
-            _pa.supports(q.shape, k_pool.shape, q.dtype, mp=shards[1]):
+    served = _paged_kernel(q.shape, k_pool.shape, q.dtype)
+    if served is not None:
+        mode, split = served
+
         def kernel(q, k_pool, v_pool, tables, pos):
             return _pa.paged_decode_attention(
                 q, k_pool, v_pool, tables, pos + 1, scale=scale,
@@ -151,6 +165,17 @@ def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None):
                           (heads, heads, heads, (None, None), (None,)),
                           heads)
     return _xla_paged_attention(q, k_pool, v_pool, tables, pos, scale=scale)
+
+
+def paged_blocks_read(lens, table_cols, q_shape, pool_shape, dtype):
+    """Pool blocks one `paged_attention` call reads for rows of visible
+    lengths `lens` (host numbers), by the path that serves those shapes:
+    the kernel's ragged walk (`walked_blocks`), or every column of every
+    row's table where the XLA fallback gathers.  The gate is the one the
+    call itself takes; nothing is read back from the device."""
+    if _paged_kernel(q_shape, pool_shape, dtype) is None:
+        return len(lens) * table_cols
+    return _pa.walked_blocks(lens, table_cols, pool_shape[1])
 
 
 override("paged_attention", paged_attention_with_pallas)

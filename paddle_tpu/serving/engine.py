@@ -28,6 +28,7 @@ docs/serving.md for the full table.
 """
 from __future__ import annotations
 
+import functools
 import warnings
 
 import numpy as np
@@ -38,6 +39,7 @@ from ..autograd import engine as _autograd
 from ..jit import functional_bridge as FB
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
+from ..ops.pallas import paged_blocks_read
 from ..resilience import chaos
 from ..tensor import Tensor
 from ..text.generation import BucketPolicy
@@ -94,6 +96,16 @@ class LLMEngine:
         if max_pos is not None:
             self.max_model_len = min(self.max_model_len, int(max_pos))
         self.table_cols = self.pool.blocks_for(self.max_model_len)
+        # pool blocks a layer of the decode program reads: the shapes its
+        # attention op sees, one query token a slot (for step()'s counts)
+        pool = self.pool
+        self._blocks_read = functools.partial(
+            paged_blocks_read, table_cols=self.table_cols,
+            q_shape=(self.max_running, 1, model.cfg.num_heads,
+                     pool.head_dim),
+            pool_shape=(pool.num_blocks, pool.block_size,
+                        pool.num_kv_heads, pool.head_dim),
+            dtype=next(iter(model.parameters()))._array.dtype)
 
         self._pn, self._p_arrays, self._bn, self._b_arrays = \
             FB.split_state(model)
@@ -269,7 +281,17 @@ class LLMEngine:
             # ready ⊆ running and admit() caps running at max_running, so
             # the static decode program always has a slot for every row
             assert len(ready) <= self.max_running
+            live = walked = 0
             if ready:
+                # how far the decode program's attention follows the
+                # traffic: the blocks the rows live in, and the blocks a
+                # layer reads for all slots (a dead slot shows the length
+                # 1) on the path that serves the program: the kernel's
+                # ragged walk, or the fallback's gather of whole tables
+                lens = [r.ctx + 1 for r in ready]
+                live = sum(self.pool.blocks_for(n) for n in lens)
+                walked = self._blocks_read(
+                    lens + [1] * (self.max_running - len(ready)))
                 self._decode(ready, root.sid)
 
             self._reg.gauge("serving_queue_depth").set(sched.queue_depth)
@@ -277,7 +299,8 @@ class LLMEngine:
                 len(sched.running))
             self._reg.gauge("serving_free_blocks").set(
                 self.pool.free_blocks)
-            root.counts["decode_rows"] = len(ready)
+            root.counts.update(decode_rows=len(ready), kv_blocks_live=live,
+                               kv_blocks_walked=walked)
         return {"admitted": len(admitted), "decoded": len(ready),
                 "prefilled": prefilled,
                 "running": len(sched.running),
@@ -457,7 +480,6 @@ class LLMEngine:
         """(builder, example ShapeDtypeStructs) for AOT lowering.  The
         builder produces the ALIAS-FREE (non-donating) build — serialized
         alias-baked executables are the PR-7 segfault class."""
-        import functools
         s = jax.ShapeDtypeStruct
         p = [s(a.shape, a.dtype) for a in self._p_arrays]
         b = [s(a.shape, a.dtype) for a in self._b_arrays]

@@ -64,17 +64,24 @@ def test_flash_fwd_bwd_compiles(one_chip, H, Hkv):
     assert KERNEL in text
 
 
-@pytest.mark.parametrize("dtype,H,Hkv", [
-    (jnp.bfloat16, 16, 16), (jnp.float32, 16, 16), (jnp.bfloat16, 12, 2)],
-    ids=["gpt1.3B-bf16", "gpt1.3B-f32", "gqa12over2-bf16"])
-def test_paged_decode_compiles(one_chip, dtype, H, Hkv):
-    """gpt3-1.3B serving shape: 8 slots, 16 heads x 128, 16-token blocks."""
+@pytest.mark.parametrize("dtype,H,Hkv,B,N,M", [
+    (jnp.bfloat16, 16, 16, 8, 280, 34), (jnp.float32, 16, 16, 8, 280, 34),
+    (jnp.bfloat16, 12, 2, 8, 280, 34),
+    (jnp.bfloat16, 16, 16, 32, 2600, 128),
+    (jnp.float32, 16, 16, 32, 2600, 128),
+    (jnp.bfloat16, 32, 8, 32, 2600, 128)],
+    ids=["gpt1.3B-bf16", "gpt1.3B-f32", "gqa12over2-bf16",
+         "cell-bf16", "cell-f32", "cell-gqa32over8-bf16"])
+def test_paged_decode_compiles(one_chip, dtype, H, Hkv, B, N, M):
+    """gpt3-1.3B serving shapes, 16 heads x 128 in 16-token blocks: 8
+    slots, and the benchmark's cells (32 slots, a table of 128 columns
+    over a pool of 2,600 blocks)."""
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
-    q = s((8, 1, H, 128), dtype)
-    pool = s((280, 16, Hkv, 128), dtype)
+    q = s((B, 1, H, 128), dtype)
+    pool = s((N, 16, Hkv, 128), dtype)
     assert pa.supports(q.shape, pool.shape, dtype)
     text = _compiled_text(pa.paged_decode_attention, q, pool, pool,
-                          s((8, 34), jnp.int32), s((8,), jnp.int32))
+                          s((B, M), jnp.int32), s((B,), jnp.int32))
     assert KERNEL in text
 
 
@@ -119,14 +126,15 @@ def test_flash_through_dispatch_under_2x2_mesh(topo, monkeypatch):
     hs = NamedSharding(mesh_mod.get_mesh(), P(None, None, "mp", None))
     rep = NamedSharding(mesh_mod.get_mesh(), P())
     s = jax.ShapeDtypeStruct
-    text = _compiled_text(
-        plo.paged_attention_with_pallas,
-        s((8, 1, 16, 128), jnp.bfloat16, sharding=hs),
-        s((280, 16, 16, 128), jnp.bfloat16, sharding=hs),
-        s((280, 16, 16, 128), jnp.bfloat16, sharding=hs),
-        s((8, 34), jnp.int32, sharding=rep),
-        s((8,), jnp.int32, sharding=rep))
-    assert KERNEL in text
+    for B, N, M in [(8, 280, 34), (32, 2600, 128)]:
+        text = _compiled_text(
+            plo.paged_attention_with_pallas,
+            s((B, 1, 16, 128), jnp.bfloat16, sharding=hs),
+            s((N, 16, 16, 128), jnp.bfloat16, sharding=hs),
+            s((N, 16, 16, 128), jnp.bfloat16, sharding=hs),
+            s((B, M), jnp.int32, sharding=rep),
+            s((B,), jnp.int32, sharding=rep))
+        assert KERNEL in text
 
 
 def test_ring_block_fwd_bwd_compiles(one_chip):

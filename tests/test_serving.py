@@ -276,6 +276,137 @@ def test_paged_attention_pallas_matches_fallback():
     np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-6)
 
 
+def _ragged_case(rng, M, bs, Hkv, g, dtype, chunk):
+    """Rows of every kind of length the walk meets, each in its own
+    blocks; columns past a row's context name a block of NaNs (POISON),
+    so a walk that reduces one shows.  The last row is a dead slot as
+    the engine builds it: length 1, table all zero."""
+    import jax.numpy as jnp
+    full = M * bs
+    lens = [1, min(chunk * bs, full), min(2 * bs, full),
+            min(2 * bs + 1, full), max(full - bs - 3, 1), full, 1]
+    B, D = len(lens), 128
+    N = sum(-(-n // bs) for n in lens) + 2          # + block 0 and POISON
+    poison = N - 1
+    tables = np.full((B, M), poison, np.int32)
+    free = list(rng.permutation(np.arange(1, N - 1)))
+    for b, n in enumerate(lens[:-1]):
+        for j in range(-(-n // bs)):
+            tables[b, j] = free.pop()
+    tables[-1] = 0
+    q = rng.randn(B, 1, g * Hkv, D).astype(np.float32)
+    k = rng.randn(N, bs, Hkv, D).astype(np.float32)
+    v = rng.randn(N, bs, Hkv, D).astype(np.float32)
+    # the values both sides see are the pool dtype's own
+    q, k, v = (np.array(jnp.asarray(a, dtype).astype(jnp.float32))
+               for a in (q, k, v))
+    return q, k, v, tables, np.asarray(lens, np.int32), poison
+
+
+@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("M,bs,Hkv,dtype,chunk", [
+    (4, 8, 2, "float32", 4),        # a table shorter than a chunk
+    (7, 16, 16, "float32", 4),      # a table that ends inside a chunk
+    (7, 16, 16, "bfloat16", 7),
+    (128, 16, 16, "bfloat16", 8),   # the benchmark's cells
+    (128, 16, 16, "float32", 4),
+    (128, 16, 8, "bfloat16", 16),   # ... under mp 2
+])
+def test_paged_kernel_walks_ragged_rows_like_the_fallback(
+        M, bs, Hkv, dtype, chunk, g):
+    import jax.numpy as jnp
+    from paddle_tpu.ops.nn_kernels import paged_attention_k
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    assert pa.chunk_blocks(M, bs, Hkv, 128, dtype) == chunk
+    rng = np.random.RandomState(M + g)
+    q, k, v, tables, lens, poison = _ragged_case(
+        rng, M, bs, Hkv, g, dtype, chunk)
+    clean_k, clean_v = k.copy(), v.copy()
+    k[poison] = v[poison] = np.nan
+    clean_k[poison] = clean_v[poison] = 0.0
+    ref = np.asarray(paged_attention_k(
+        jnp.asarray(q), jnp.asarray(clean_k), jnp.asarray(clean_v),
+        jnp.asarray(tables), jnp.asarray(lens - 1)))
+    out = pa.paged_decode_attention(
+        jnp.asarray(q, dtype), jnp.asarray(k, dtype), jnp.asarray(v, dtype),
+        jnp.asarray(tables), jnp.asarray(lens), interpret=True)
+    assert out.dtype == jnp.dtype(dtype)
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=1e-2)     # the output's own rounding
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref,
+                               **tol)
+
+
+def test_walked_blocks_are_the_blocks_the_kernel_touches():
+    """Column j of every table made a block of NaNs in turn: a row's
+    output shows whether its walk reached that column."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    rng = np.random.RandomState(5)
+    B, M, bs, Hkv, D = 5, 7, 16, 16, 128
+    assert pa.chunk_blocks(M, bs, Hkv, D, "float32") == 4   # two chunks
+    lens = np.asarray([1, 16, 17, 48, 112], np.int32)
+    N = B * M + 1
+    k = rng.randn(N, bs, Hkv, D).astype(np.float32)
+    v = rng.randn(N, bs, Hkv, D).astype(np.float32)
+    k[N - 1] = v[N - 1] = np.nan
+    q = jnp.asarray(rng.randn(B, 1, Hkv, D), jnp.float32)
+    clean = rng.permutation(N - 1).reshape(B, M).astype(np.int32)
+    touched = 0
+    for j in range(M):
+        tables = clean.copy()
+        tables[:, j] = N - 1
+        out = np.asarray(pa.paged_decode_attention(
+            q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables),
+            jnp.asarray(lens), interpret=True))
+        touched += int(np.isnan(out).any(axis=(1, 2, 3)).sum())
+    assert touched == pa.walked_blocks(lens, M, bs) == 1 + 1 + 2 + 3 + 7
+    assert touched < B * M
+    # a length of 0 walks as a dead slot does, a length past the table
+    # stops at the table's end
+    assert pa.walked_blocks([0, 1, 10 ** 6], M, bs) == 1 + 1 + M
+
+
+@pytest.mark.parametrize("mode,q,pool,dtype,ragged", [
+    ("interpret", (3, 1, 4, 128), (12, 8, 2, 128), "float32", True),
+    ("interpret", (3, 1, 4, 64), (12, 8, 2, 64), "float32", False),
+    ("interpret", (3, 1, 4, 128), (12, 8, 2, 128), "float16", False),
+    ("0", (3, 1, 4, 128), (12, 8, 2, 128), "float32", False),
+], ids=["kernel", "head-dim-64", "float16", "pallas-off"])
+def test_blocks_read_follow_the_path_that_serves_the_call(
+        monkeypatch, mode, q, pool, dtype, ragged):
+    """The count is the kernel's walk only where the kernel runs: the
+    XLA fallback gathers every column of every row's table."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops import pallas as plo
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", mode)
+    lens, M = [1, 9, 30], 4
+    got = plo.paged_blocks_read(lens, M, q, pool, jnp.dtype(dtype))
+    assert got == (1 + 2 + 4 if ragged else 3 * M)
+    s = jax.ShapeDtypeStruct
+    # (a lambda: a trace of the function itself is cached across modes)
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: plo.paged_attention_with_pallas(*a))(
+        s(q, dtype), s(pool, dtype), s(pool, dtype),
+        s((3, M), jnp.int32), s((3,), jnp.int32)))
+    assert ("pallas_call" in jaxpr) == ragged
+
+
+def test_paged_kernel_takes_the_scale_as_any_host_number():
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    rng = np.random.RandomState(2)
+    q = jnp.asarray(rng.randn(2, 1, 2, 128), jnp.float32)
+    kp = jnp.asarray(rng.randn(5, 8, 2, 128), jnp.float32)
+    tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    lens = jnp.asarray([3, 12], jnp.int32)
+    outs = [np.asarray(pa.paged_decode_attention(
+        q, kp, kp, tables, lens, scale=s, interpret=True))
+        for s in (0.25, np.float32(0.25), jnp.asarray(0.25))]
+    assert (outs[0] == outs[1]).all() and (outs[0] == outs[2]).all()
+
+
 def test_paged_attention_supports_gate():
     import jax.numpy as jnp
     from paddle_tpu.ops.pallas import paged_attention as pa

@@ -54,7 +54,8 @@ def served(model):
     hist = reg.histogram("serving_decode_batch")
     out = {"reqs": reqs, "recs": recs, "summaries": summaries,
            "decode_batch": (hist.count, hist.sum),
-           "dropped": trace.dropped()}
+           "dropped": trace.dropped(),
+           "slots": eng.max_running, "table_cols": eng.table_cols}
     assert eng.close() == ([], [])
     trace.clear()
     return out
@@ -103,10 +104,62 @@ def test_the_root_counts_the_rows_it_decoded_and_phases_count_nothing(
         served):
     for (root, kids), summary in zip(_steps(served["recs"]),
                                      served["summaries"]):
-        assert root[F["counts"]] == {"decode_rows": summary["decoded"]}
+        counts = root[F["counts"]]
+        assert sorted(counts) == ["decode_rows", "kv_blocks_live",
+                                  "kv_blocks_walked"]
+        rows = counts["decode_rows"]
+        assert rows == summary["decoded"]
+        # every row lives in a block or more.  This model's heads are 8
+        # wide, so the XLA fallback serves its decode steps, and that
+        # gathers every column of every slot's table
+        assert rows <= counts["kv_blocks_live"] \
+            <= counts["kv_blocks_walked"] \
+            <= served["slots"] * served["table_cols"]
+        assert counts["kv_blocks_walked"] \
+            == (served["slots"] * served["table_cols"] if rows else 0)
         assert all(c[F["counts"]] == {} for c in kids)
         assert bool(summary["prefilled"]) == any(
             c[F["name"]] == "serving.prefill" for c in kids)
+
+
+def test_where_the_kernel_serves_the_root_counts_its_ragged_walk(
+        monkeypatch):
+    """Heads 128 wide under PADDLE_TPU_PALLAS=interpret: the decode
+    program runs the pallas kernel, and the root counts the blocks the
+    rows live in and one more for each dead slot.  The tokens are the
+    fallback's."""
+    pt.seed(0)
+    wide = GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=128, num_layers=1, num_heads=1,
+        max_position_embeddings=64, hidden_dropout=0.0,
+        attention_dropout=0.0, tensor_parallel=False))
+    rng = np.random.RandomState(1)
+    prompts = [rng.randint(0, 64, size=n).tolist() for n in (5, 19, 9)]
+
+    def run():
+        trace.clear()
+        eng = LLMEngine(wide, num_blocks=24, block_size=8, max_running=4,
+                        prefill_chunk=16)
+        reqs = [eng.add_request(p, max_new_tokens=4) for p in prompts]
+        eng.run()
+        roots = [r[F["counts"]] for r, _ in _steps(trace.spans())]
+        trace.clear()
+        return [r.generated for r in reqs], roots, eng
+
+    tokens, gathered, eng = run()
+    full = eng.max_running * eng.table_cols
+    assert {c["kv_blocks_walked"] for c in gathered} <= {0, full}
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    got, walked, _ = run()
+    assert got == tokens
+    decoding = [c for c in walked if c["decode_rows"]]
+    assert decoding
+    for c in decoding:
+        assert c["kv_blocks_walked"] - c["kv_blocks_live"] \
+            == eng.max_running - c["decode_rows"]
+        assert c["kv_blocks_walked"] < full
+    assert [c["kv_blocks_live"] for c in walked] \
+        == [c["kv_blocks_live"] for c in gathered]
 
 
 def test_decode_rows_equal_the_decode_batch_observations(served):
