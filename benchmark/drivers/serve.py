@@ -111,7 +111,8 @@ def drive(cell, schedule, seconds, follow, on_tick=None, patience=60.0,
           t_open=None):
     """The arrival loop.  Returns (records, steps, t_open, t_close) with
     every time on time.perf_counter(); `steps` holds (start, duration,
-    decoded, prefilled, pool blocks in use) per engine step."""
+    decoded, prefilled, pool blocks in use, seconds this thread was on
+    the CPU) per engine step."""
     from paddle_tpu.serving.scheduler import WAITING
     eng = cell.eng
     if t_open is None:
@@ -136,10 +137,12 @@ def drive(cell, schedule, seconds, follow, on_tick=None, patience=60.0,
                                or all(settled(r) for r in in_window)):
             break
         if eng.has_work:
+            cpu = time.thread_time()
             with harness.span("engine.step"):
                 st = eng.step()
             steps.append((now, time.perf_counter() - now, st["decoded"],
-                          st["prefilled"], eng.pool.used_blocks))
+                          st["prefilled"], eng.pool.used_blocks,
+                          time.thread_time() - cpu))
             still = []
             for rec in waiting:
                 if rec.req.state == WAITING:
@@ -153,6 +156,17 @@ def drive(cell, schedule, seconds, follow, on_tick=None, patience=60.0,
         else:
             break
     return records, steps, t_open, t_close
+
+
+def depth_by_thirds(depth, t_open, t_close):
+    """Mean of the `depth` readings [(time, requests waiting)] in each
+    third of the window: a queue that grows third to third is above the
+    knee, one that stays under a request is below it."""
+    third = (t_close - t_open) / 3
+    return [float(np.mean([d for t, d in depth
+                           if t_open + k * third <= t
+                           < t_open + (k + 1) * third] or [0]))
+            for k in range(3)]
 
 
 def _latencies(records, t_open, t_close, t_end):
@@ -245,8 +259,10 @@ def run(spec, args, t_start, device):
     state = {"open": None, "trace_from": None, "closed": False,
              "c_open": None, "c_cut": None}
     t_plan_open = time.perf_counter() + lead
+    depth = []                  # (time, requests waiting) per loop turn
 
     def on_tick(now):
+        depth.append((now, cell.eng.scheduler.queue_depth))
         if state["open"] is None and now >= t_plan_open:
             state["open"] = now
             state["c_open"] = cell.counters()
@@ -290,11 +306,19 @@ def run(spec, args, t_start, device):
     harness.say(f"pool blocks in use over the window: mean "
                 f"{pool['in_use_mean']:.0f} peak {pool['in_use_peak']} of "
                 f"{pool['blocks']} (the rest of the pool is reserve)")
+    thirds = depth_by_thirds(depth, t_open, t_close)
+    closing = [s[2] for s in steps if s[0] < t_close][-8:]
+    harness.say("mean queue depth by third of the window: "
+                + " / ".join(f"{d:.1f}" for d in thirds)
+                + f"; rows running at the close {max(closing or [0])}")
     slow = max(steps, key=lambda s: s[1])
     harness.say(f"arrival generator lateness ms: p95 "
                 f"{stats.percentile(late, 95):.2f} max {max(late):.2f}; "
                 f"longest engine step {slow[1] * 1e3:.0f} ms at "
-                f"{slow[0] - t_open:.1f} s (a stall of the host shows here)")
+                f"{slow[0] - t_open:.1f} s, this thread on the CPU for "
+                f"{slow[5] * 1e3:.0f} ms of it (a stall shows here: on the "
+                f"CPU it is the process's own work, off it the thread "
+                f"waited or was descheduled)")
     harness.say(f"ttft ms mean {float(np.mean(ttft)):.2f} p50 "
                 f"{stats.percentile(ttft, 50):.1f} p95 "
                 f"{stats.percentile(ttft, 95):.1f}; tpot ms p50 "
@@ -359,7 +383,7 @@ def run(spec, args, t_start, device):
     return {"e2e": e2e, "attempted": attempted, "failed": failed,
             "checks": checks, "peak": peak, "run": run_data,
             "device_extra": dev_extra, "breakdown": breakdown,
-            "extra": {"pool": pool}}
+            "extra": {"pool": pool, "queue_depth_by_third": thirds}}
 
 
 def sweep(spec, args, device):
@@ -382,10 +406,7 @@ def sweep(spec, args, device):
         t_end = time.perf_counter()
         ttft, tpot, wait, finished, failed = _latencies(
             records, t_open, t_close, t_end)
-        thirds = [np.mean([d for t, d in depth
-                           if t_open + k * args.seconds / 3 <= t
-                           < t_open + (k + 1) * args.seconds / 3] or [0])
-                  for k in range(3)]
+        thirds = depth_by_thirds(depth, t_open, t_close)
         rate_tok = stats.rate_over_window([t for t, _ in cell.emit],
                                           t_open, t_close)
         print(f"sweep rate {rate:.2f}/s: due "

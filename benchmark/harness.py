@@ -200,8 +200,19 @@ class CompileCounter:
 
 
 # ---------------------------------------------------------------- profiler
+# host spans kept from a trace: the harness's own, then the program's
+# (each `traced()` block of `LLMEngine.step` is also an annotation of the
+# same name), so that an idle gap is labelled by the innermost phase
 SPANS = ("train.step", "train.feed", "train.read_loss", "engine.step",
-         "arrivals", "idle.wait")
+         "arrivals", "idle.wait",
+         "serving.step", "serving.schedule", "serving.prefill",
+         "serving.decode.prepare", "serving.decode.dispatch",
+         "serving.decode.wait", "serving.decode.fetch", "serving.sample")
+# the runtime's own host events around a program, kept beside the spans
+# for a reader to pin the device clock's shift with (PERF.md section 7);
+# they label no gap
+RUNTIME_EVENTS = ("tpu::System::Execute=>IssueSequencedEvent",
+                  "ReadSyncFlag")
 
 
 def span(name):
@@ -237,7 +248,7 @@ class Profiler:
 
     def read(self):
         from benchmark import trace
-        out = trace.load(self.dir, SPANS)
+        out = trace.load(self.dir, SPANS + RUNTIME_EVENTS)
         shutil.rmtree(self.dir, ignore_errors=True)
         return out
 
@@ -254,8 +265,9 @@ def reduce_trace(tr, chips):
     ops0 = tr["devices"][used[0]]["ops"]
     breakdown = {
         "device_ops": trace.top_ops(ops0, 10),
-        "idle_gaps": trace.label_gaps(trace.idle_gaps(ops0, t0, t1),
-                                      tr["spans"], 10)}
+        "idle_gaps": trace.label_gaps(
+            trace.idle_gaps(ops0, t0, t1),
+            [s for s in tr["spans"] if s[0] in SPANS], 10)}
     return ({"busy_s": sum(busy) / len(busy) / 1e9,
              "window_s": (t1 - t0) / 1e9}, breakdown)
 

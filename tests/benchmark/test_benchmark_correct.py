@@ -112,6 +112,18 @@ def test_sound_serving_run_is_correct(chat_spec):
     assert out["attempted"] > 0 and out["failed"] == 0
     for name in ("ttft_p95_ms", "tpot_p95_ms", "serve_tokens_per_s"):
         assert out["e2e"][name] > 0
+    assert len(out["extra"]["queue_depth_by_third"]) == 3
+
+
+@pytest.mark.parametrize("depths, want", [
+    ([(0.5, 0), (1.5, 0), (2.5, 1)], [0.0, 0.0, 1.0]),         # below a knee
+    ([(0.2, 2), (0.8, 4), (1.5, 9), (2.9, 20), (3.0, 99)], [3.0, 9.0, 20.0]),
+    ([(-1.0, 50), (1.5, 2)], [0.0, 2.0, 0.0])])     # lead-in never counts
+def test_queue_depth_is_the_mean_of_each_third_of_the_window(depths, want):
+    t_open = 100.0
+    got = serve.depth_by_thirds([(t_open + t, d) for t, d in depths],
+                                t_open, t_open + 3.0)
+    assert got == want
 
 
 class _AlteredToken(serve.ServeCell):

@@ -80,6 +80,28 @@ def test_window_and_reduce_over_two_devices():
     assert one["busy_s"] == pytest.approx(550e-9)
 
 
+@pytest.mark.parametrize("gap, phase", [
+    ((110, 30), "serving.decode.dispatch"), ((160, 30), "serving.decode.wait"),
+    ((262, 6), "serving.decode.fetch"), ((282, 8), "serving.step"),
+    ((296, 3), "engine.step")])
+def test_a_serving_gap_is_labelled_by_the_programs_phase(gap, phase):
+    """The program's phases lie inside its `serving.step`, which lies
+    inside the harness's `engine.step`; the runtime's events, kept for
+    the device clock, lie inside a phase and name no gap."""
+    spans = [("engine.step", 95, 205), ("serving.step", 100, 195),
+             ("serving.decode.dispatch", 100, 50),
+             ("serving.decode.wait", 150, 100),
+             ("serving.decode.fetch", 250, 30),
+             ("tpu::System::Execute=>IssueSequencedEvent", 120, 10),
+             ("ReadSyncFlag", 170, 5)]
+    assert {s[0] for s in spans} <= set(harness.SPANS
+                                        + harness.RUNTIME_EVENTS)
+    ops = [("fusion.1", 0, gap[0]), ("fusion.2", gap[0] + gap[1], 400)]
+    tr = {"devices": {0: {"ops": ops, "modules": []}}, "spans": spans}
+    _, breakdown = harness.reduce_trace(tr, 1)
+    assert breakdown["idle_gaps"] == [[phase, gap[1] / 1e9]]
+
+
 def test_a_trace_without_device_events_is_an_error():
     with pytest.raises(ValueError):
         trace.window_of({"devices": {0: {"ops": [], "modules": []}}})

@@ -6,7 +6,9 @@ import os
 import numpy as np
 import pytest
 
-from benchmark import harness, traffic
+import appended
+from appended import BENCHES
+from benchmark import traffic
 
 MIX = {"arrivals": {"gaps": "exponential", "rate_per_s": 6.0},
        "prompt_tokens": {"dist": "lognormal", "mean": 350, "sigma": 0.8,
@@ -15,7 +17,6 @@ MIX = {"arrivals": {"gaps": "exponential", "rate_per_s": 6.0},
                          "min": 16, "max": 256},
        "lead_s": 5.0, "base_seed": 1}
 BIG = 2 ** 31 + 12345
-BENCH = json.load(open(os.path.join(harness.ROOT, "BENCHMARK.json")))
 
 
 def _sched(seed, seconds=30, mix=MIX):
@@ -105,27 +106,50 @@ def test_train_rows_all_differ_and_repeat_by_seed():
     assert 0 <= a.min() and a.max() < 50304
 
 
-def test_every_committed_mix_generates():
-    folder = os.path.join(harness.HERE, "traffic")
+@pytest.mark.parametrize("which", list(BENCHES))
+def test_every_committed_mix_generates(which, roots):
+    """Each mix against the configurations whose cells use it: their
+    vocabulary, their positions.  `which` is the committed benchmark or
+    the copy a later PR appended to (tests/benchmark/appended.py)."""
+    bench, root = BENCHES[which], roots[which]
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    folder = os.path.join(root, "benchmark", "traffic")
     for name in sorted(os.listdir(folder)):
         mix = json.load(open(os.path.join(folder, name)))
         assert mix["kind"] in ("train", "serve"), name
-        if mix["kind"] == "serve":
-            s = traffic.serve_schedule(mix, BIG, 10, 50304)
-            assert s and all(r["due"] < 10 + mix.get("tail_s", 0)
-                             for r in s)
-            assert mix["source"]["lengths"] and mix["source"]["arrivals"]
-            # every seed sees the same requests: the pool holds the
-            # max_running longest of a whole run (lead-in and window) at
-            # once, so it cannot run out whatever the order
-            run = traffic.serve_schedule(mix, BIG, BENCH["run_seconds"],
-                                         50304)
-            eng = mix["engine"]
-            blocks = sorted(-(-(len(r["prompt"]) + r["max_new_tokens"])
-                              // eng["block_size"]) for r in run)
-            assert sum(blocks[-eng["max_running"]:]) <= eng["num_blocks"]
-            assert mix["prompt_tokens"]["max"] + \
-                mix["output_tokens"]["max"] <= 2048
-        else:
+        users = [json.load(open(os.path.join(root, files[w["config"]])))
+                 for w in bench["workloads"]
+                 if w["traffic"] + ".json" == name]
+        assert users, f"no cell uses {name}"
+        if mix["kind"] == "train":
             assert traffic.train_tokens(mix, 1, 100, pool=2).shape == \
                 (2, mix["batch"], mix["seq"] + 1)
+            continue
+        assert mix["source"]["lengths"] and mix["source"]["arrivals"]
+        for cfg in users:
+            vocab = cfg["vocab_size"]
+            s = traffic.serve_schedule(mix, BIG, 10, vocab)
+            assert s and all(r["due"] < 10 + mix.get("tail_s", 0)
+                             for r in s)
+            assert max(int(r["prompt"].max()) for r in s) < vocab
+            assert mix["prompt_tokens"]["max"] + \
+                mix["output_tokens"]["max"] <= cfg["max_position_embeddings"]
+        # every seed sees the same requests: the pool holds the
+        # max_running longest of a whole run (lead-in and window) at
+        # once, so it cannot run out whatever the order (lengths do not
+        # depend on the vocabulary)
+        run = traffic.serve_schedule(mix, BIG, bench["run_seconds"],
+                                     users[0]["vocab_size"])
+        eng = mix["engine"]
+        blocks = sorted(-(-(len(r["prompt"]) + r["max_new_tokens"])
+                          // eng["block_size"]) for r in run)
+        assert sum(blocks[-eng["max_running"]:]) <= eng["num_blocks"]
+
+
+def test_the_appended_mix_is_beyond_the_first_configuration():
+    """What the committed numbers (50304, 2048) would have refused."""
+    mix = appended.MIX
+    assert mix["prompt_tokens"]["max"] + mix["output_tokens"]["max"] == 8192
+    s = traffic.serve_schedule(mix, BIG, 10, appended.CONFIG["vocab_size"])
+    assert max(int(r["prompt"].max()) for r in s) >= 50304
+    assert max(len(r["prompt"]) for r in s) > 2048
