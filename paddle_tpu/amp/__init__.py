@@ -79,7 +79,10 @@ def decorate(models=None, optimizers=None, level="O2", dtype="bfloat16",
         if m is None:
             continue
         for p in m.parameters():
-            if jnp.issubdtype(p._array.dtype, jnp.floating):
+            # a parameter born in the target dtype stays as it is: the
+            # jitted cast would hold a second copy of it meanwhile
+            if jnp.issubdtype(p._array.dtype, jnp.floating) \
+                    and p._array.dtype != target:
                 to_cast.append(p)
     if to_cast:
         import jax

@@ -27,6 +27,7 @@ from ...autograd import engine
 from ...distributed import mesh as mesh_mod
 from ...nn import initializer as I
 from ...nn.layer import Layer
+from ...ops.dispatch import call_raw
 from ...tensor import Tensor
 
 
@@ -160,6 +161,131 @@ def moe_ffn(x, wg, w1, b1, w2, b2, *, top_k, capacity, act="gelu",
     out = _maybe_shard(out, "ep", None, None)
     y = jnp.einsum("nec,ecd->nd", combine.astype(compute_dtype), out)
     return y, aux
+
+
+def moe_route(x, wg, bias, *, top_k, scoring="softmax", norm_topk=True,
+              route_scale=1.0):
+    """The router of the dropless layer: x [N, d], wg [d, E], bias [E] or
+    None -> (picked [N, k] int32 expert ids, weights [N, k] float32).
+    Scores (`scoring`: "softmax" | "sigmoid") are computed in float32;
+    `bias` is a SELECTION bias: the top-k are taken of ``scores + bias``
+    but weighed by the scores alone; `norm_topk` divides the picked
+    scores by their sum; `route_scale` multiplies the result."""
+    logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
+                        precision="highest")
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    chosen_by = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, picked = jax.lax.top_k(chosen_by, top_k)
+    w = jnp.take_along_axis(scores, picked, axis=-1)
+    if norm_topk:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return picked.astype(jnp.int32), w * route_scale
+
+
+def moe_dropless(x, picked, weights, w_gate, w_up, w_down):
+    """SwiGLU experts over routed tokens with NO capacity: every one of
+    the N x k assignments is computed whatever the imbalance.
+
+    x [N, d]; picked, weights [N, k] (from `moe_route`); w_gate, w_up
+    [E, d, f]; w_down [E, f, d] -- the experts stacked on a leading axis,
+    so that "the experts held here" is a slice of it.  Assignments are
+    sorted by expert, the three products run as grouped products over the
+    stacked weights (op `grouped_matmul`: each expert's contiguous rows
+    against its own matrix; `jax.lax.ragged_dot`, or the tiled kernel of
+    ops/pallas/ on TPU), then unsorted and combined by `weights`.
+    Shapes are static ([N * k, ...] rows whatever the routing), so one
+    program serves every routing.  Returns y [N, d] in x's dtype."""
+    n, d = x.shape
+    k = picked.shape[1]
+    experts = w_gate.shape[0]
+    flat = picked.reshape(-1)
+    order = jnp.argsort(flat)                  # stable: by expert, by token
+    rows = x[order // k]                       # [N * k, d]
+    sizes = jnp.bincount(flat, length=experts).astype(jnp.int32)
+    h = jax.nn.silu(call_raw("grouped_matmul", rows, w_gate, sizes)) \
+        * call_raw("grouped_matmul", rows, w_up, sizes)
+    out = call_raw("grouped_matmul", h.astype(x.dtype), w_down, sizes)
+    back = jnp.argsort(order)                  # the inverse permutation
+    out = out[back].reshape(n, k, d)
+    return jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype))
+
+
+class DroplessMoE(Layer):
+    """Routed SwiGLU experts without capacity or drops, with optional
+    shared experts that every token passes through.
+
+    `scoring`, `score_bias` (a selection bias that picks and never
+    weighs), `norm_topk`, `route_scale` and `num_shared` are values of
+    this one layer, not subclasses.  ``forward(x)`` returns y; with
+    ``live`` (a bool mask over the flattened tokens) it returns
+    ``(y, load)`` where ``load`` [E] int32 counts the live tokens each
+    expert received (the serving engine's `experts_touched`)."""
+
+    def __init__(self, d_model, d_hidden, num_experts, top_k,
+                 scoring="softmax", score_bias=False, norm_topk=True,
+                 route_scale=1.0, num_shared=0, init_std=0.02,
+                 dtype="float32"):
+        super().__init__(dtype=dtype)
+        if top_k > num_experts:
+            raise ValueError(f"top_k={top_k} > num_experts={num_experts}")
+        self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.scoring, self.norm_topk = scoring, bool(norm_topk)
+        self.route_scale = float(route_scale)
+        init = I.Normal(0.0, init_std)
+
+        def param(*shape):
+            return self.create_parameter(list(shape),
+                                         default_initializer=init)
+
+        self.gate_weight = param(d_model, num_experts)
+        self.score_bias = self.create_parameter(
+            [num_experts], is_bias=True,
+            default_initializer=I.Constant(0.0)) if score_bias else None
+        self.w_gate = param(num_experts, d_model, d_hidden)
+        self.w_up = param(num_experts, d_model, d_hidden)
+        self.w_down = param(num_experts, d_hidden, d_model)
+        self.num_shared = int(num_shared)
+        if self.num_shared:
+            width = self.num_shared * d_hidden
+            self.shared_gate = param(d_model, width)
+            self.shared_up = param(d_model, width)
+            self.shared_down = param(width, d_model)
+
+    def forward(self, x, live=None):
+        shape = x.shape
+        x2 = x.reshape([-1, shape[-1]])
+        if self.score_bias is None:
+            route, args = (lambda x_, wg, **kw: moe_route(x_, wg, None, **kw),
+                           [x2, self.gate_weight])
+        else:
+            route, args = moe_route, [x2, self.gate_weight, self.score_bias]
+        picked, weights = engine.apply(
+            "moe_route", route, args,
+            {"top_k": self.top_k, "scoring": self.scoring,
+             "norm_topk": self.norm_topk, "route_scale": self.route_scale})
+        y = engine.apply("moe_dropless", moe_dropless,
+                         [x2, picked, weights, self.w_gate, self.w_up,
+                          self.w_down])
+        if self.num_shared:
+            from ...nn import functional as F
+            y = y + F.linear(F.silu(F.linear(x2, self.shared_gate))
+                             * F.linear(x2, self.shared_up),
+                             self.shared_down)
+        y = y.reshape(list(shape))
+        if live is None:
+            return y
+        load = engine.apply(
+            "moe_load",
+            lambda p, l, experts: jnp.sum(
+                (p[:, :, None] == jnp.arange(experts, dtype=p.dtype))
+                & l[:, None, None], axis=(0, 1), dtype=jnp.int32),
+            [picked, live], {"experts": self.num_experts})
+        return y, load
 
 
 class MoELayer(Layer):

@@ -410,6 +410,56 @@ def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None):
     return sdpa_k(q, K, V, mask=mask, scale=scale)
 
 
+@register("paged_gather")
+def paged_gather_k(pool, tables):
+    """A row's blocks as one contiguous window: pool [N, bs, ...],
+    tables [b, M] -> [b, M * bs, ...] (what `paged_attention` attends;
+    a prefill chunk over a latent pool expands K and V from it)."""
+    b, m = tables.shape
+    rows = jnp.take(pool, tables.astype(jnp.int32).reshape(-1), axis=0)
+    return rows.reshape((b, m * pool.shape[1]) + pool.shape[2:])
+
+
+def paged_visible(s, length, pos):
+    """[b, s, length] bool: query row i of a request at context offset
+    pos[b] sees absolute positions <= pos[b] + i of its gathered window."""
+    cols = jnp.arange(length, dtype=jnp.int32)[None, None, :]
+    return cols <= (pos.astype(jnp.int32)[:, None, None]
+                    + jnp.arange(s, dtype=jnp.int32)[None, :, None])
+
+
+@register("latent_paged_attention", amp="allow")
+def latent_paged_attention_k(q, pool, tables, pos, value_dim, scale=None):
+    """Attention of ABSORBED queries over a latent paged pool -- the jnp
+    gather reference (ops/pallas/latent_paged_attention.py overrides it
+    for decode steps on TPU).
+
+    One cached row serves every head as key (all of its `W` columns) and
+    as value (its first `value_dim` columns): q [b, s, H, W], pool
+    [N, bs, W], tables [b, M], pos [b] -> [b, s, H, value_dim].  Query
+    row i of a request at context offset pos attends positions
+    <= pos + i; the softmax is float32, as `sdpa_k`'s."""
+    b, s = q.shape[0], q.shape[1]
+    rows = paged_gather_k(pool, tables)                       # [b, L, W]
+    scale = float(scale) if scale is not None else q.shape[-1] ** -0.5
+    scores = (jnp.einsum("bshw,blw->bhsl", q, rows) * scale).astype(
+        jnp.float32)
+    seen = paged_visible(s, rows.shape[1], pos)
+    scores = jnp.where(seen[:, None], scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhsl,blv->bshv", probs, rows[..., :value_dim])
+
+
+# ------------------------------------------------- grouped products (MoE)
+@register("grouped_matmul")
+def grouped_matmul_k(rows, weights, group_sizes):
+    """Each group's contiguous rows against its own matrix: rows [m, k]
+    sorted by group, weights [g, k, n], group_sizes [g] int32 (their sum
+    is m) -> [m, n].  XLA's own ragged product; ops/pallas/ overrides it
+    with a tiled grouped-matmul kernel on TPU."""
+    return jax.lax.ragged_dot(rows, weights, group_sizes)
+
+
 # ------------------------------------------------------------------ losses
 @register("softmax_ce", amp="deny")
 def softmax_ce_k(logits, label, soft_label=False, ignore_index=-100,

@@ -14,10 +14,12 @@ from __future__ import annotations
 import os
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..dispatch import get, override
 from . import flash_attention as _fa
+from . import latent_paged_attention as _la
 from . import paged_attention as _pa
 
 
@@ -179,3 +181,119 @@ def paged_blocks_read(lens, table_cols, q_shape, pool_shape, dtype):
 
 
 override("paged_attention", paged_attention_with_pallas)
+
+
+_xla_latent_paged_attention = get("latent_paged_attention").fn
+
+
+def _latent_kernel(q_shape, pool_shape, value_dim, dtype):
+    """As `_paged_kernel`, for a `latent_paged_attention` call."""
+    mode = _mode()
+    if mode is None:
+        return None
+    split = _mesh_split()
+    shards = _shards(split)
+    if shards is None or not _la.supports(q_shape, pool_shape, value_dim,
+                                          dtype, mp=shards[1]):
+        return None
+    return mode, split
+
+
+def latent_paged_attention_with_pallas(q, pool, tables, pos, value_dim,
+                                       scale=None):
+    """Decode steps walk the latent pool through the pallas kernel;
+    chunks of more than one token and unsupported shapes keep the XLA
+    gather, which is also the parity reference.  Under a mesh the
+    queries' heads shard on "mp"; the pool's rows serve every head and
+    are replicated, as are tables and positions."""
+    served = _latent_kernel(q.shape, pool.shape, value_dim, q.dtype)
+    if served is not None:
+        mode, split = served
+
+        def kernel(q, pool, tables, pos):
+            return _la.latent_paged_decode_attention(
+                q, pool, tables, pos + 1, value_dim, scale=scale,
+                interpret=(mode == "interpret"))
+
+        heads = (None, None, "mp", None)
+        return _over_mesh(split, kernel, (q, pool, tables, pos),
+                          (heads, (None, None, None), (None, None),
+                           (None,)), heads)
+    return _xla_latent_paged_attention(q, pool, tables, pos, value_dim,
+                                       scale=scale)
+
+
+def latent_blocks_read(lens, table_cols, q_shape, pool_shape, dtype):
+    """As `paged_blocks_read`, for a `latent_paged_attention` call: the
+    latent kernel's own walk (`latent_paged_attention.walked_blocks`), or
+    every column of every row's table where the XLA fallback gathers.
+    The values are the leading lanes of a row: for the gate, any whole
+    lane tiles of it, so the row's own width stands in."""
+    if _latent_kernel(q_shape, pool_shape, pool_shape[2], dtype) is None:
+        return len(lens) * table_cols
+    return _la.walked_blocks(lens, table_cols, pool_shape[1])
+
+
+override("latent_paged_attention", latent_paged_attention_with_pallas)
+
+
+_BLOCKS_READ = {"paged_attention": paged_blocks_read,
+                "latent_paged_attention": latent_blocks_read}
+
+
+def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype):
+    """Pool blocks one layer of a decode program of `rows` slots reads
+    for visible lengths `lens`, by the registered op `op` that the model
+    says reads its planes (`plane_shapes`: {name: one layer's array
+    shape}, the planes of one op alike; the last axis is the width a
+    query meets)."""
+    shape = next(iter(plane_shapes.values()))
+    return _BLOCKS_READ[op](lens, table_cols, (rows, 1, heads, shape[-1]),
+                            shape, dtype)
+
+
+_xla_grouped_matmul = get("grouped_matmul").fn
+_GMM_ROWS = 128             # the kernel's row tile
+_GMM_BLOCK_BYTES = 6 << 20  # one weight block in VMEM (two are in flight)
+
+
+def _gmm_tiling(k, n, itemsize):
+    """(row, k, n) tile sizes of the grouped-matmul kernel, or None where
+    the shapes are not whole lane tiles: all of k and as much of n (in
+    multiples of 128 that divide it) as keeps one weight block under
+    `_GMM_BLOCK_BYTES`, so that a group's matrix is read once a row tile
+    and the product is bound by that read."""
+    if k % 128 or n % 128 or k * 128 * itemsize > _GMM_BLOCK_BYTES:
+        return None
+    tn = max(t for t in range(128, n + 1, 128)
+             if n % t == 0 and k * t * itemsize <= _GMM_BLOCK_BYTES)
+    return _GMM_ROWS, k, tn
+
+
+def grouped_matmul_with_pallas(rows, weights, group_sizes):
+    """On TPU the grouped products of the dropless expert layer run
+    through JAX's tiled grouped-matmul kernel (`megablox.gmm`, pallas:
+    row tiles visit only the groups they overlap, a group's weights
+    stream through VMEM once a tile), which XLA's own ragged product
+    trails 3-4 x at the expert shapes (PERF.md, PR 28).  Rows are padded
+    to whole row tiles; the pad rows belong to no group and are cut off.
+    Anything else (CPU, a fleet mesh, unaligned widths, other dtypes)
+    keeps `jax.lax.ragged_dot`."""
+    mode = _mode()
+    tiling = _gmm_tiling(weights.shape[1], weights.shape[2],
+                         weights.dtype.itemsize)
+    if mode is None or tiling is None or _mesh_split() is not None \
+            or rows.dtype != weights.dtype \
+            or rows.dtype not in (jnp.float32, jnp.bfloat16):
+        return _xla_grouped_matmul(rows, weights, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    m = rows.shape[0]
+    pad = -m % _GMM_ROWS
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    out = gmm(rows, weights, group_sizes.astype(jnp.int32),
+              rows.dtype, tiling, None, None, False, mode == "interpret")
+    return out[:m] if pad else out
+
+
+override("grouped_matmul", grouped_matmul_with_pallas)

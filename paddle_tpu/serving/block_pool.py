@@ -1,19 +1,24 @@
-"""Block-paged KV cache pool — one allocation per serving replica.
+"""Block-paged cache pool — one allocation per serving replica.
 
-The pool is the serving engine's only KV memory: per-layer
-[num_blocks, block_size, Hkv, D] arrays allocated ONCE, carved into
-fixed-size token blocks handed to requests through a host-side
-free list with reference counts.  Freed requests return their blocks
-immediately (refcount 0 -> back on the free list), so pool pressure is
-a pure function of live context tokens — the scheduler admits, evicts
-and preempts against `free_blocks`.
+The pool is the serving engine's only cache memory.  It allocates what
+the MODEL says it caches (`model.cache_planes()`): per layer, named
+planes with their trailing shape per token -- `k` and `v` of
+[Hkv, D] for the K/V models, ONE latent row for a latent-attention
+model -- each a [num_blocks, block_size, *trailing] array allocated
+ONCE and carved into fixed-size token blocks handed to requests through
+a host-side free list with reference counts.  A block id means the same
+block in every plane of every layer, so the allocator, the tables, the
+refcounts and the scheduler never see the layout.  Freed requests return
+their blocks immediately (refcount 0 -> back on the free list), so pool
+pressure is a pure function of live context tokens — the scheduler
+admits, evicts and preempts against `free_blocks`.
 
-Mesh layout: the pool arrays are shaped so the kv-head axis (dim 2) is
-the natural tensor-parallel shard axis — `shard_()` places them as
-PartitionSpec(None, None, "mp", None) on the fleet mesh, the same axis
-the model's ColumnParallel qkv projections shard, so a tensor-parallel
-replica's pool shards with its weights and the paged attention op runs
-on local heads only.
+Mesh layout: a plane with a kv-head axis ([N, bs, Hkv, D]) shards that
+axis — `shard_()` places it as PartitionSpec(None, None, "mp", None) on
+the fleet mesh, the same axis the model's ColumnParallel qkv projections
+shard, so a tensor-parallel replica's pool shards with its weights and
+the paged attention op runs on local heads only.  A plane without one
+(a latent row, which every head reads) stays replicated.
 """
 from __future__ import annotations
 
@@ -28,35 +33,44 @@ class PoolExhausted(RuntimeError):
 
 
 class BlockPool:
-    def __init__(self, num_layers, num_blocks, block_size, num_kv_heads,
-                 head_dim, dtype="float32"):
+    def __init__(self, num_layers, num_blocks, block_size, planes,
+                 dtype="float32"):
+        """`planes`: {name: trailing shape per token}, the same for every
+        layer (a K/V model's: `text.decode.kv_cache_planes`)."""
         self.num_layers = int(num_layers)
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
-        self.num_kv_heads = int(num_kv_heads)
-        self.head_dim = int(head_dim)
         self.dtype = dtype
-        shape = (self.num_blocks, self.block_size, self.num_kv_heads,
-                 self.head_dim)
-        self.k = [jnp.zeros(shape, dtype=dtype)
-                  for _ in range(self.num_layers)]
-        self.v = [jnp.zeros(shape, dtype=dtype)
-                  for _ in range(self.num_layers)]
+        self.planes = {
+            name: [jnp.zeros((self.num_blocks, self.block_size)
+                             + tuple(int(n) for n in trailing), dtype=dtype)
+                   for _ in range(self.num_layers)]
+            for name, trailing in planes.items()}
         # host-side allocator: LIFO free list + per-block refcounts
         self._free = list(range(self.num_blocks - 1, -1, -1))
         self._refs = [0] * self.num_blocks
 
     @classmethod
     def for_model(cls, model, num_blocks, block_size=16, dtype=None):
-        """Size the pool from the model config (kv heads and head_dim
-        follow `new_caches`: GQA models keep unrepeated kv heads)."""
-        cfg = model.cfg
-        hd = cfg.hidden_size // cfg.num_heads
-        hkv = getattr(cfg, "num_kv_heads", None) or cfg.num_heads
+        """Size the pool from what the model declares it caches
+        (`cache_planes()`: one {name: trailing shape} per layer)."""
+        per_layer = model.cache_planes()
+        if any(p != per_layer[0] for p in per_layer):
+            raise NotImplementedError(
+                "layers that cache different planes need a pool per kind")
         if dtype is None:
             dtype = next(iter(model.parameters()))._array.dtype
-        return cls(cfg.num_layers, num_blocks, block_size, hkv, hd,
+        return cls(len(per_layer), num_blocks, block_size, per_layer[0],
                    dtype=dtype)
+
+    def plane_shapes(self):
+        """{name: one layer's array shape}."""
+        return {name: arrays[0].shape
+                for name, arrays in self.planes.items()}
+
+    def release(self):
+        """Drop the device arrays (the allocator's books stay)."""
+        self.planes = {}
 
     # ------------------------------------------------------------ allocator
     @property
@@ -117,16 +131,18 @@ class BlockPool:
 
     # ------------------------------------------------------------- sharding
     def shard_(self):
-        """Lay the pool out on the fleet mesh: kv heads sharded along
-        "mp" (the tensor-parallel axis the qkv projections shard), all
-        other axes replicated.  No-op without a multi-device mp mesh or
-        when heads don't divide it."""
+        """Lay the pool out on the fleet mesh: planes with a kv-head axis
+        shard it along "mp" (the tensor-parallel axis the qkv projections
+        shard), everything else is replicated.  No-op without a
+        multi-device mp mesh or when heads don't divide it."""
         if not mesh_mod.has_mesh() or mesh_mod.degree("mp") <= 1:
-            return False
-        if self.num_kv_heads % mesh_mod.degree("mp"):
             return False
         import jax
         sh = mesh_mod.sharding(None, None, "mp", None)
-        self.k = [jax.device_put(a, sh) for a in self.k]
-        self.v = [jax.device_put(a, sh) for a in self.v]
-        return True
+        done = False
+        for name, arrays in self.planes.items():
+            if arrays[0].ndim == 4 \
+                    and arrays[0].shape[2] % mesh_mod.degree("mp") == 0:
+                self.planes[name] = [jax.device_put(a, sh) for a in arrays]
+                done = True
+        return done

@@ -39,7 +39,7 @@ from ..autograd import engine as _autograd
 from ..jit import functional_bridge as FB
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
-from ..ops.pallas import paged_blocks_read
+from ..ops.pallas import pool_blocks_read
 from ..resilience import chaos
 from ..tensor import Tensor
 from ..text.generation import BucketPolicy
@@ -96,20 +96,19 @@ class LLMEngine:
         if max_pos is not None:
             self.max_model_len = min(self.max_model_len, int(max_pos))
         self.table_cols = self.pool.blocks_for(self.max_model_len)
-        # pool blocks a layer of the decode program reads: the shapes its
-        # attention op sees, one query token a slot (for step()'s counts)
-        pool = self.pool
+        # pool blocks a layer of the decode program reads: by the op the
+        # model says reads its planes, one query token a slot (for
+        # step()'s counts)
         self._blocks_read = functools.partial(
-            paged_blocks_read, table_cols=self.table_cols,
-            q_shape=(self.max_running, 1, model.cfg.num_heads,
-                     pool.head_dim),
-            pool_shape=(pool.num_blocks, pool.block_size,
-                        pool.num_kv_heads, pool.head_dim),
+            pool_blocks_read, model.cache_op, table_cols=self.table_cols,
+            plane_shapes=self.pool.plane_shapes(),
+            rows=self.max_running, heads=model.cfg.num_heads,
             dtype=next(iter(model.parameters()))._array.dtype)
 
         self._pn, self._p_arrays, self._bn, self._b_arrays = \
             FB.split_state(model)
         self._programs = {}     # key -> live jitted program
+        self._chunk_loads = []  # routed layers' load of chunks, unread yet
         self._aot_execs = {}    # key -> deserialized AOT executable
         self._finished = []
         self._reg = _metrics.registry()
@@ -282,6 +281,7 @@ class LLMEngine:
             # the static decode program always has a slot for every row
             assert len(ready) <= self.max_running
             live = walked = 0
+            moe = {}
             if ready:
                 # how far the decode program's attention follows the
                 # traffic: the blocks the rows live in, and the blocks a
@@ -292,7 +292,20 @@ class LLMEngine:
                 live = sum(self.pool.blocks_for(n) for n in lens)
                 walked = self._blocks_read(
                     lens + [1] * (self.max_running - len(ready)))
-                self._decode(ready, root.sid)
+                load = self._decode(ready, root.sid)
+                if load is not None:
+                    # [routed layers, experts] live rows each received;
+                    # and the chunks' own, now that the fetch has waited
+                    # for the device: this step's, and those of steps that
+                    # decoded nothing
+                    chunks = [np.asarray(a) for a in self._chunk_loads]
+                    self._chunk_loads = []
+                    moe = {"moe_assignments": int(load.sum()),
+                           "experts_touched": int((load > 0).sum()),
+                           "prefill_moe_assignments": sum(
+                               int(a.sum()) for a in chunks),
+                           "prefill_experts_touched": sum(
+                               int((a > 0).sum()) for a in chunks)}
 
             self._reg.gauge("serving_queue_depth").set(sched.queue_depth)
             self._reg.gauge("serving_running_requests").set(
@@ -300,7 +313,7 @@ class LLMEngine:
             self._reg.gauge("serving_free_blocks").set(
                 self.pool.free_blocks)
             root.counts.update(decode_rows=len(ready), kv_blocks_live=live,
-                               kv_blocks_walked=walked)
+                               kv_blocks_walked=walked, **moe)
         return {"admitted": len(admitted), "decoded": len(ready),
                 "prefilled": prefilled,
                 "running": len(sched.running),
@@ -358,8 +371,8 @@ class LLMEngine:
                     + list(self.scheduler.waiting)):
             self._finish(req, "drained")
         leaks = self.pool.check_leaks()
-        self.pool.k = []
-        self.pool.v = []
+        self.pool.release()
+        self._chunk_loads.clear()
         self._programs.clear()
         self._aot_execs.clear()
         self._closed = True
@@ -407,51 +420,60 @@ class LLMEngine:
         non-donating build."""
         return jax.default_backend() != "cpu"
 
+    def _caches(self, planes, tables, pos, limit):
+        """One cache dict per layer over the pool's planes, as the
+        models' paged branches read them."""
+        return [dict({name: Tensor._from_array(arrays[i])
+                      for name, arrays in planes.items()},
+                     table=Tensor._from_array(tables),
+                     pos=Tensor._from_array(pos),
+                     limit=Tensor._from_array(limit))
+                for i in range(self.pool.num_layers)]
+
+    @staticmethod
+    def _written(caches, planes, layers=None):
+        """What a program hands back of its caches: ({name: the planes as
+        written},) and, where routed layers left their load (the real
+        tokens each expert received), the stack [routed layers, experts]
+        of the first `layers` layers' behind it."""
+        out = ({name: [c[name]._array for c in caches] for name in planes},)
+        load = [c["expert_load"]._array for c in caches[:layers]
+                if "expert_load" in c]
+        return out + ((jnp.stack(load),) if load else ())
+
     def _build_decode(self, donate=None):
         model, pn, bn = self.model, self._pn, self._bn
-        nl = self.pool.num_layers
 
-        def pure(p_arrays, b_arrays, ks, vs, tables, pos, tokens, limit):
-            caches = [{"k": Tensor._from_array(ks[i]),
-                       "v": Tensor._from_array(vs[i]),
-                       "table": Tensor._from_array(tables),
-                       "pos": Tensor._from_array(pos),
-                       "limit": Tensor._from_array(limit)}
-                      for i in range(nl)]
+        def pure(p_arrays, b_arrays, planes, tables, pos, tokens, limit):
+            caches = self._caches(planes, tables, pos, limit)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
                     logits = model(Tensor._from_array(tokens[:, None]),
                                    caches=caches)
-            new_ks = [c["k"]._array for c in caches]
-            new_vs = [c["v"]._array for c in caches]
             return (logits._array[:, -1, :].astype(jnp.float32),
-                    new_ks, new_vs)
+                    ) + self._written(caches, planes)
 
         donate = self._donate_pools() if donate is None else donate
-        return jax.jit(pure, donate_argnums=(2, 3) if donate else ())
+        return jax.jit(pure, donate_argnums=(2,) if donate else ())
 
     def _build_prefill(self, donate=None):
         model, pn, bn = self.model, self._pn, self._bn
-        nl = self.pool.num_layers
 
-        def pure(p_arrays, b_arrays, ks, vs, table, pos, tokens, limit):
-            caches = [{"k": Tensor._from_array(ks[i]),
-                       "v": Tensor._from_array(vs[i]),
-                       "table": Tensor._from_array(table),
-                       "pos": Tensor._from_array(pos),
-                       "limit": Tensor._from_array(limit)}
-                      for i in range(nl)]
+        def pure(p_arrays, b_arrays, planes, table, pos, tokens, limit):
+            caches = self._caches(planes, table, pos, limit)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
                     model(Tensor._from_array(tokens), caches=caches)
-            # only the written pools leave the program: the lm_head
-            # matmul (and every logit) is dead code XLA prunes, so a
-            # prefill chunk costs attention+MLP only
-            return ([c["k"]._array for c in caches],
-                    [c["v"]._array for c in caches])
+            # only the written pools (and the routed layers' load) leave
+            # the program: the lm_head matmul (and every logit) is dead
+            # code XLA prunes, and with it all of the LAST layer but the
+            # rows it caches.  That layer's load is left out, so that its
+            # attention and routing stay dead: a chunk's counts are those
+            # of the products that run
+            return self._written(caches, planes, layers=-1)
 
         donate = self._donate_pools() if donate is None else donate
-        return jax.jit(pure, donate_argnums=(2, 3) if donate else ())
+        return jax.jit(pure, donate_argnums=(2,) if donate else ())
 
     def program_keys(self, prompt_lens=()):
         """The program inventory a replica needs: the decode program
@@ -483,18 +505,18 @@ class LLMEngine:
         s = jax.ShapeDtypeStruct
         p = [s(a.shape, a.dtype) for a in self._p_arrays]
         b = [s(a.shape, a.dtype) for a in self._b_arrays]
-        ks = [s(a.shape, a.dtype) for a in self.pool.k]
-        vs = [s(a.shape, a.dtype) for a in self.pool.v]
+        planes = {name: [s(a.shape, a.dtype) for a in arrays]
+                  for name, arrays in self.pool.planes.items()}
         i32 = np.int32
         if key[0] == "decode":
             R, M = self.max_running, self.table_cols
             return functools.partial(self._build_decode, donate=False), (
-                p, b, ks, vs, s((R, M), i32), s((R,), i32), s((R,), i32),
+                p, b, planes, s((R, M), i32), s((R,), i32), s((R,), i32),
                 s((R,), i32))
         if key[0] == "prefill":
             Lb = int(key[1])
             return functools.partial(self._build_prefill, donate=False), (
-                p, b, ks, vs, s((1, self.table_cols), i32), s((1,), i32),
+                p, b, planes, s((1, self.table_cols), i32), s((1,), i32),
                 s((1, Lb), i32), s((1,), i32))
         raise KeyError(f"unknown serving program key {key!r}")
 
@@ -502,7 +524,8 @@ class LLMEngine:
     def _prefill(self, req, n, parent=None):
         bucket = self.policy.bucket(n)
         with _trace.traced("serving.prefill", parent=parent, rid=req.id,
-                           cat="serving"):
+                           cat="serving") as span:
+            span.counts.update(tokens=n, ctx=req.ctx)
             feed = req.feed_tokens()
             chunk = feed[req.ctx:req.ctx + n]
             tokens = np.zeros((1, bucket), np.int32)
@@ -511,11 +534,15 @@ class LLMEngine:
             table[0, :len(req.block_table)] = req.block_table
             pos = np.asarray([req.ctx], np.int32)
             limit = np.asarray([req.ctx + n], np.int32)
-            ks, vs = self._run_program(
+            self.pool.planes, *load = self._run_program(
                 ("prefill", bucket), self._build_prefill,
-                self._p_arrays, self._b_arrays, self.pool.k, self.pool.v,
+                self._p_arrays, self._b_arrays, self.pool.planes,
                 table, pos, tokens, limit)
-            self.pool.k, self.pool.v = list(ks), list(vs)
+            for a in load:
+                # read when a decode's fetch has next waited for the
+                # device (step()): a chunk never waits for its own
+                a.copy_to_host_async()
+                self._chunk_loads.append(a)
         req.ctx += n
         self._reg.counter("serving_prefill_tokens_total").inc(n)
 
@@ -535,11 +562,10 @@ class LLMEngine:
                 limit[i] = req.ctx + 1
         with _trace.traced("serving.decode.dispatch", parent=parent,
                            cat="serving"):
-            logits, ks, vs = self._run_program(
+            logits, self.pool.planes, *load = self._run_program(
                 ("decode",), self._build_decode,
-                self._p_arrays, self._b_arrays, self.pool.k, self.pool.v,
+                self._p_arrays, self._b_arrays, self.pool.planes,
                 tables, pos, tokens, limit)
-            self.pool.k, self.pool.v = list(ks), list(vs)
         # the wait is its own span, so that the fetch times the copy
         # alone.  The copy is queued behind the program first, as a bare
         # np.asarray would queue it: left to start after the wait has
@@ -547,10 +573,13 @@ class LLMEngine:
         with _trace.traced("serving.decode.wait", parent=parent,
                            cat="serving"):
             logits.copy_to_host_async()
+            for a in load:
+                a.copy_to_host_async()
             logits.block_until_ready()
         with _trace.traced("serving.decode.fetch", parent=parent,
                            cat="serving"):
             rows = np.asarray(logits)
+            load = np.asarray(load[0]) if load else None
         with _trace.traced("serving.sample", parent=parent,
                            cat="serving"):
             now = clock()
@@ -559,6 +588,7 @@ class LLMEngine:
             for i, req in enumerate(ready):
                 req.ctx += 1
                 self._emit(req, rows[i], now)
+        return load
 
     def _emit(self, req, logits_row, now):
         if req.poisoned:

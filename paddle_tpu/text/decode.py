@@ -63,6 +63,15 @@ def _update_prealloc_cache(cache, k, v, s, window=None):
     return K, V, mask
 
 
+def kv_cache_planes(cfg):
+    """What a K/V model caches per token per layer, for the serving pool
+    (`BlockPool.for_model`): `k` and `v` of [kv heads, head dim]; GQA
+    models keep unrepeated kv heads."""
+    hd = cfg.hidden_size // cfg.num_heads
+    hkv = getattr(cfg, "num_kv_heads", None) or cfg.num_heads
+    return [{"k": (hkv, hd), "v": (hkv, hd)}] * cfg.num_layers
+
+
 def _update_paged_cache(cache, k, v):
     """Serving path: write k/v [b, s, H, D] into the block-paged pool at
     each row's context offset and return (k_pool, v_pool) for the paged
@@ -123,19 +132,23 @@ def _decode_state(model, batch, max_length):
     pn, p_arrays, bn, b_arrays = FB.split_state(model)
     proto = model.new_caches(batch, dtype=p_arrays[0].dtype,
                              max_length=max_length)
-    caches = [(c["k"]._array, c["v"]._array) for c in proto]
+    # whatever the model caches per layer (K and V, or one latent row),
+    # by name; the offset travels beside it
+    caches = [{k: t._array for k, t in c.items() if k != "pos"}
+              for c in proto]
     return pn, p_arrays, bn, b_arrays, caches
 
 
 def _model_step(model, pn, bn, p_arrays, b_arrays, ids, cache_arrays, pos):
     """One functional forward over the preallocated caches."""
-    caches = [{"k": Tensor._from_array(ck), "v": Tensor._from_array(cv),
-               "pos": Tensor._from_array(pos)}
-              for ck, cv in cache_arrays]
+    caches = [dict({k: Tensor._from_array(a) for k, a in c.items()},
+                   pos=Tensor._from_array(pos))
+              for c in cache_arrays]
     with FB._swapped(model, pn, p_arrays, bn, b_arrays):
         with engine.no_grad():
             logits = model(Tensor._from_array(ids), caches=caches)
-    new_cache_arrays = [(c["k"]._array, c["v"]._array) for c in caches]
+    new_cache_arrays = [{k: new[k]._array for k in old}
+                        for new, old in zip(caches, cache_arrays)]
     return logits._array, new_cache_arrays
 
 
@@ -237,7 +250,7 @@ def jit_beam_search(model, input_ids, beam_size=4, max_new_tokens=20,
 
         def _build():
             def gather_caches(caches, g):
-                return [(ck[g], cv[g]) for ck, cv in caches]
+                return [{k: a[g] for k, a in c.items()} for c in caches]
 
             def pure(p_arrays, b_arrays, ids, caches):
                 ids = jnp.repeat(ids.astype(jnp.int32), beam, axis=0)

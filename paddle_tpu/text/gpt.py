@@ -298,6 +298,12 @@ class GPTForCausalLM(nn.Layer):
         x = self.gpt.ln_f(x)
         return x.matmul(self.gpt.wte.weight, transpose_y=True)
 
+    cache_op = "paged_attention"            # the op that reads the planes
+
+    def cache_planes(self):
+        from .decode import kv_cache_planes
+        return kv_cache_planes(self.cfg)
+
     def new_caches(self, batch_size, dtype="float32", max_length=None):
         """Concat-style caches (eager decode) or, with `max_length`, the
         preallocated static-shape caches the jitted decode loop uses."""

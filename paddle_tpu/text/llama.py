@@ -281,6 +281,12 @@ class LlamaForCausalLM(nn.Layer):
         x = self.llama(input_ids, caches)
         return self.lm_head(x)
 
+    cache_op = "paged_attention"            # the op that reads the planes
+
+    def cache_planes(self):
+        from .decode import kv_cache_planes
+        return kv_cache_planes(self.cfg)
+
     def new_caches(self, batch_size, dtype="float32", max_length=None):
         from .. import tensor_api as T
         hd = self.cfg.hidden_size // self.cfg.num_heads

@@ -20,6 +20,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import latent_paged_attention as la
 from paddle_tpu.ops.pallas import paged_attention as pa
 
 KERNEL = "tpu_custom_call"
@@ -83,6 +84,40 @@ def test_paged_decode_compiles(one_chip, dtype, H, Hkv, B, N, M):
     text = _compiled_text(pa.paged_decode_attention, q, pool, pool,
                           s((B, M), jnp.int32), s((B,), jnp.int32))
     assert KERNEL in text
+
+
+@pytest.mark.parametrize("dtype,B,N,M", [
+    (jnp.bfloat16, 32, 32000, 1024), (jnp.float32, 8, 512, 64)],
+    ids=["cell-bf16", "small-f32"])
+def test_latent_paged_decode_compiles(one_chip, dtype, B, N, M):
+    """The latent cell's decode shapes: 32 slots of 16 heads over rows of
+    [c 512 | k_rope 64 | 64 zeros] in 16-token blocks, a table of 1,024
+    columns (16,384 positions) riding scalar prefetch."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q, pool = s((B, 1, 16, 640), dtype), s((N, 16, 640), dtype)
+    assert la.supports(q.shape, pool.shape, 512, dtype)
+    text = _compiled_text(
+        lambda q, pool, t, n: la.latent_paged_decode_attention(
+            q, pool, t, n, 512, scale=192 ** -0.5),
+        q, pool, s((B, M), jnp.int32), s((B,), jnp.int32))
+    assert KERNEL in text
+
+
+@pytest.mark.parametrize("rows", [192, 6144], ids=["decode", "chunk"])
+def test_dropless_experts_compile_as_grouped_products(one_chip, rows):
+    """`moe_dropless` at the latent cell's widths (64 experts of 2048 x
+    1408, 6 a token): a decode step's 32 x 6 assignments and a prefill
+    chunk's 1,024 x 6.  The grouped products stay grouped (no [N, E, C]
+    dispatch tensor): the program's temporaries are the sorted rows."""
+    from paddle_tpu.incubate.nn import moe_dropless
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    n = rows // 6
+    compiled = jax.jit(moe_dropless).lower(
+        s((n, 2048), bf16), s((n, 6), jnp.int32), s((n, 6), jnp.float32),
+        s((64, 2048, 1408), bf16), s((64, 2048, 1408), bf16),
+        s((64, 1408, 2048), bf16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 40 * rows * 2048
 
 
 def test_supports_refuses_what_the_compiler_refuses(one_chip):

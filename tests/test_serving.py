@@ -133,7 +133,7 @@ def test_sampled_requests_deterministic_per_seed(gpt_engine):
 # ===================================================================
 def test_block_pool_alloc_free_refcount():
     pool = BlockPool(num_layers=1, num_blocks=8, block_size=4,
-                     num_kv_heads=2, head_dim=8)
+                     planes={"k": (2, 8), "v": (2, 8)})
     a = pool.allocate(3)
     assert len(a) == 3 and pool.free_blocks == 5
     pool.ref(a)                       # rc 2
@@ -154,8 +154,93 @@ def test_block_pool_alloc_free_refcount():
 
 
 def test_block_pool_blocks_for():
-    pool = BlockPool(1, 8, 16, 2, 8)
+    pool = BlockPool(1, 8, 16, {"k": (2, 8), "v": (2, 8)})
     assert [pool.blocks_for(n) for n in (1, 16, 17, 32)] == [1, 1, 2, 2]
+
+
+def _tiny_latent():
+    from paddle_tpu.text.deepseek import (DeepseekV3Config,
+                                          DeepseekV3ForCausalLM)
+    pt.seed(0)
+    return DeepseekV3ForCausalLM(DeepseekV3Config(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+        intermediate_size=64, max_position_embeddings=64, kv_lora_rank=24,
+        qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=8,
+        moe_intermediate_size=16, n_routed_experts=4, n_shared_experts=1,
+        num_experts_per_tok=2))
+
+
+@pytest.mark.parametrize("build, planes", [
+    (_tiny_gpt, {"k": (4, 8), "v": (4, 8)}),
+    (_tiny_llama, {"k": (2, 8), "v": (2, 8)}),       # GQA: unrepeated
+    # ONE latent row a token: [c 24 | k_rope 8] padded to 128 lanes
+    (_tiny_latent, {"kv": (128,)})], ids=["gpt", "llama-gqa", "latent"])
+def test_the_pool_allocates_the_planes_the_model_declares(build, planes):
+    model = build()
+    assert model.cache_planes() == [planes] * 2
+    pool = BlockPool.for_model(model, num_blocks=6, block_size=4)
+    assert pool.plane_shapes() == {
+        name: (6, 4) + trailing for name, trailing in planes.items()}
+    assert all(len(arrays) == 2 for arrays in pool.planes.values())
+    # the allocator never sees the layout
+    assert pool.blocks_for(9) == 3 and len(pool.allocate(6)) == 6
+    assert pool.allocate(1) is None
+    pool.release()
+    assert pool.planes == {}
+
+
+@pytest.mark.parametrize("build", [_tiny_gpt, _tiny_latent],
+                         ids=["gpt", "latent"])
+def test_engine_threads_the_pools_planes_through_both_programs(build):
+    """Whatever the planes, the programs take and return them by name,
+    `program_structs` describes them, the greedy output equals the
+    sequential `generate`, and `close()` releases them."""
+    model = build()
+    eng = LLMEngine(model, num_blocks=24, block_size=4, max_running=3,
+                    prefill_chunk=8)
+    names = sorted(eng.pool.planes)
+    for key in eng.program_keys():
+        _, structs = eng.program_structs(key)
+        assert sorted(structs[2]) == names
+        assert [s.shape for s in structs[2][names[0]]] == \
+            [a.shape for a in eng.pool.planes[names[0]]]
+    prompts = [np.arange(3, 3 + n) % 64 for n in (11, 4, 7)]
+    outs = eng.generate_batch(prompts, max_new_tokens=5)
+    for p, got in zip(prompts, outs):
+        assert got == _seq_ref(model, p, 5)
+    assert sorted(eng.pool.planes) == names
+    assert eng.close() == ([], [])
+    assert eng.pool.planes == {}
+
+
+@pytest.mark.parametrize("pos, limit, width, real", [
+    ([0], [5], 8, 5),                   # a chunk of 5 padded to 8
+    ([6], [9], 4, 3),                   # a later chunk
+    ([3, 0, 1], [4, 0, 2], 1, 2)],      # a decode step with a dead slot
+    ids=["chunk", "later-chunk", "decode"])
+def test_a_routed_layer_reports_the_load_of_the_real_tokens(pos, limit,
+                                                             width, real):
+    """Under a paged cache a routed layer leaves, beside its result, how
+    many of the tokens from `pos` up to `limit` each expert received: the
+    serving engine's `moe_assignments` and `experts_touched`."""
+    import jax.numpy as jnp
+    from paddle_tpu.tensor import Tensor
+    m = _tiny_latent()
+    m.eval()
+    pool = BlockPool.for_model(m, num_blocks=8, block_size=4)
+    rows = len(pos)
+    table = np.tile(np.arange(3, dtype=np.int32), (rows, 1)) \
+        + 3 * np.arange(rows, dtype=np.int32)[:, None] % 6
+    caches = [{"kv": Tensor._from_array(pool.planes["kv"][i]),
+               "table": Tensor._from_array(jnp.asarray(table)),
+               "pos": Tensor._from_array(jnp.asarray(pos, jnp.int32)),
+               "limit": Tensor._from_array(jnp.asarray(limit, jnp.int32))}
+              for i in range(pool.num_layers)]
+    with pt.no_grad():
+        m(pt.randint(0, 64, [rows, width]), caches=caches)
+    assert "expert_load" not in caches[0]           # the dense layer
+    load = np.asarray(caches[1]["expert_load"]._array)
+    assert load.shape == (4,) and load.sum() == real * 2
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5, 6, 7])
@@ -168,7 +253,7 @@ def test_block_pool_random_interleavings_property(seed):
     final teardown."""
     rng = np.random.RandomState(seed)
     pool = BlockPool(num_layers=1, num_blocks=16, block_size=4,
-                     num_kv_heads=2, head_dim=8)
+                     planes={"k": (2, 8), "v": (2, 8)})
     shadow = {}                 # block id -> refcount (held blocks only)
     tables = []                 # simulated per-request block tables
 
@@ -434,8 +519,8 @@ def test_paged_prefill_matches_dense_forward(gpt):
         pool = BlockPool.for_model(m, num_blocks=8, block_size=4)
         table = np.zeros((1, 2), np.int32)
         table[0] = [3, 5]
-        caches = [{"k": Tensor._from_array(pool.k[i]),
-                   "v": Tensor._from_array(pool.v[i]),
+        caches = [{"k": Tensor._from_array(pool.planes["k"][i]),
+                   "v": Tensor._from_array(pool.planes["v"][i]),
                    "table": Tensor._from_array(jnp.asarray(table)),
                    "pos": Tensor._from_array(jnp.zeros(1, jnp.int32)),
                    "limit": Tensor._from_array(
@@ -504,7 +589,7 @@ def test_serving_aot_roundtrip_zero_compile(gpt, tmp_path):
 # ===================================================================
 def test_pool_exhausted_chaos_site():
     from paddle_tpu.resilience import chaos
-    pool = BlockPool(1, 8, 4, 2, 8)
+    pool = BlockPool(1, 8, 4, {"k": (2, 8), "v": (2, 8)})
     with chaos.scoped("serving.pool_exhausted@1"):
         assert pool.allocate(1) is None     # injected refusal
         a = pool.allocate(1)                # next hit is clean

@@ -295,7 +295,7 @@ def test_engine_drain_and_close(gpt):
     assert ei.value.reason == "draining"
     leaks = eng.close()
     assert leaks == ([], [])
-    assert eng.pool.k == [] and eng.pool.v == []
+    assert eng.pool.planes == {}
     with pytest.raises(RuntimeError, match="closed"):
         eng.add_request([1, 2], max_new_tokens=2)
 

@@ -100,7 +100,7 @@ def test_children_lie_inside_their_root_and_in_order(served):
         assert names.count("serving.schedule") == 2
 
 
-def test_the_root_counts_the_rows_it_decoded_and_phases_count_nothing(
+def test_the_root_counts_the_rows_it_decoded_and_a_prefill_its_chunk(
         served):
     for (root, kids), summary in zip(_steps(served["recs"]),
                                      served["summaries"]):
@@ -117,9 +117,15 @@ def test_the_root_counts_the_rows_it_decoded_and_phases_count_nothing(
             <= served["slots"] * served["table_cols"]
         assert counts["kv_blocks_walked"] \
             == (served["slots"] * served["table_cols"] if rows else 0)
-        assert all(c[F["counts"]] == {} for c in kids)
-        assert bool(summary["prefilled"]) == any(
-            c[F["name"]] == "serving.prefill" for c in kids)
+        # a prefill counts its chunk's tokens and the context it started
+        # at; the other phases count nothing
+        chunks = [c[F["counts"]] for c in kids
+                  if c[F["name"]] == "serving.prefill"]
+        assert all(sorted(c) == ["ctx", "tokens"] for c in chunks)
+        assert sum(c["tokens"] for c in chunks) == summary["prefilled"]
+        assert all(c[F["counts"]] == {} for c in kids
+                   if c[F["name"]] != "serving.prefill")
+        assert bool(summary["prefilled"]) == bool(chunks)
 
 
 def test_where_the_kernel_serves_the_root_counts_its_ragged_walk(
