@@ -13,7 +13,8 @@ Layout under `path/`:
 
 Compatibility is validated at LOAD time with the same refuse-with-reason
 stamp checks as `jit.load_inference` (platform, device kind/count, mesh,
-jax/jaxlib versions); a refused or damaged artifact is skipped with the
+jax/jaxlib versions) and against the layout of what the engine's
+programs return; a refused or damaged artifact is skipped with the
 reason — the engine's live-jit path serves instead, never an abort.
 
 Trade-off baked into the format: serialized executables are ALIAS-FREE
@@ -38,6 +39,12 @@ from ..observability import metrics as _metrics
 
 _MANIFEST = "serving_manifest.json"
 _PROGRAMS = "programs"
+# What the programs return, as the engine unpacks it; a manifest without
+# the key is layout 1.  2: the decode program returns (logits, ids,
+# finite, planes, *load) where it returned (logits, planes, *load).  An
+# executable of another layout takes the same arguments, so nothing but
+# this number would refuse it
+_LAYOUT = 2
 
 
 def _key_name(key):
@@ -58,7 +65,7 @@ def export_serving_artifacts(engine, path, prompt_lens=()):
     manifest dict."""
     path = os.path.abspath(path)
     os.makedirs(os.path.join(path, _PROGRAMS), exist_ok=True)
-    manifest = {"stamp": _env_stamp(), "programs": {}}
+    manifest = {"stamp": _env_stamp(), "layout": _LAYOUT, "programs": {}}
     for key in engine.program_keys(prompt_lens=prompt_lens):
         # always an alias-free twin from program_structs' builder — the
         # engine's LIVE program may donate the pool buffers, and a
@@ -97,6 +104,11 @@ def load_serving_artifacts(engine, path, strict=False):
                       f"cold start will compile", UserWarning, stacklevel=2)
         return []
     ok, reason = _aot_compatible(manifest.get("stamp", {}))
+    if ok and manifest.get("layout", 1) != _LAYOUT:
+        ok, reason = False, (
+            f"program layout mismatch: artifact exported for layout "
+            f"{manifest.get('layout', 1)!r}, this engine's programs "
+            f"return layout {_LAYOUT}")
     if not ok:
         if strict:
             raise AOTIncompatible(reason)

@@ -281,6 +281,7 @@ class LLMEngine:
             # the static decode program always has a slot for every row
             assert len(ready) <= self.max_running
             live = walked = 0
+            picks = {"rows_picked_on_device": 0, "logit_rows_fetched": 0}
             moe = {}
             if ready:
                 # how far the decode program's attention follows the
@@ -292,7 +293,7 @@ class LLMEngine:
                 live = sum(self.pool.blocks_for(n) for n in lens)
                 walked = self._blocks_read(
                     lens + [1] * (self.max_running - len(ready)))
-                load = self._decode(ready, root.sid)
+                load, picks = self._decode(ready, root.sid)
                 if load is not None:
                     # [routed layers, experts] live rows each received;
                     # and the chunks' own, now that the fetch has waited
@@ -313,7 +314,7 @@ class LLMEngine:
             self._reg.gauge("serving_free_blocks").set(
                 self.pool.free_blocks)
             root.counts.update(decode_rows=len(ready), kv_blocks_live=live,
-                               kv_blocks_walked=walked, **moe)
+                               kv_blocks_walked=walked, **picks, **moe)
         return {"admitted": len(admitted), "decoded": len(ready),
                 "prefilled": prefilled,
                 "running": len(sched.running),
@@ -450,7 +451,13 @@ class LLMEngine:
                 with _autograd.no_grad():
                     logits = model(Tensor._from_array(tokens[:, None]),
                                    caches=caches)
-            return (logits._array[:, -1, :].astype(jnp.float32),
+            # the greedy choice and the finite test are made here, over
+            # the float32 row the host would scan (first index on a tie,
+            # as np.argmax), so that a step brings [max_running] ids to
+            # the host and the logits stay on the device
+            rows = logits._array[:, -1, :].astype(jnp.float32)
+            return (rows, jnp.argmax(rows, -1).astype(jnp.int32),
+                    jnp.isfinite(rows).all(-1)
                     ) + self._written(caches, planes)
 
         donate = self._donate_pools() if donate is None else donate
@@ -562,23 +569,28 @@ class LLMEngine:
                 limit[i] = req.ctx + 1
         with _trace.traced("serving.decode.dispatch", parent=parent,
                            cat="serving"):
-            logits, self.pool.planes, *load = self._run_program(
+            logits, ids, finite, self.pool.planes, *load = self._run_program(
                 ("decode",), self._build_decode,
                 self._p_arrays, self._b_arrays, self.pool.planes,
                 tables, pos, tokens, limit)
         # the wait is its own span, so that the fetch times the copy
-        # alone.  The copy is queued behind the program first, as a bare
-        # np.asarray would queue it: left to start after the wait has
-        # returned it costs the step 0.2 ms (PERF.md, PR 25)
+        # alone.  The copies are queued behind the program first, as a
+        # bare np.asarray would queue them: left to start after the wait
+        # has returned they cost the step 0.2 ms (PERF.md, PR 25).  The
+        # logits are copied only for a row that draws its token on the
+        # host; a greedy step leaves them on the device
+        sampled = any(r.do_sample for r in ready)
         with _trace.traced("serving.decode.wait", parent=parent,
                            cat="serving"):
-            logits.copy_to_host_async()
-            for a in load:
+            for a in [ids, finite] + load + ([logits] if sampled else []):
                 a.copy_to_host_async()
-            logits.block_until_ready()
+            ids.block_until_ready()
         with _trace.traced("serving.decode.fetch", parent=parent,
                            cat="serving"):
-            rows = np.asarray(logits)
+            out = _DecodeOut(logits, np.asarray(ids).tolist(),
+                             np.asarray(finite).tolist())
+            if sampled:
+                out.rows()
             load = np.asarray(load[0]) if load else None
         with _trace.traced("serving.sample", parent=parent,
                            cat="serving"):
@@ -587,16 +599,27 @@ class LLMEngine:
             self._reg.histogram("serving_decode_batch").observe(len(ready))
             for i, req in enumerate(ready):
                 req.ctx += 1
-                self._emit(req, rows[i], now)
-        return load
+                self._emit(req, _Row(out, i), now)
+        fetched = out.host is not None
+        return load, {
+            "rows_picked_on_device": 0 if fetched else out.picked,
+            "logit_rows_fetched": R if fetched else 0}
 
     def _emit(self, req, logits_row, now):
+        """The hook a decoded row goes through: `logits_row` is the
+        step's `_Row` (the device's token and finite test, the float32
+        logits behind `np.asarray`), or an ndarray a wrapper hands on,
+        which is tested and sampled on the host."""
         if req.poisoned:
             # chaos serving.request_poison: this request's logits are
             # ruined; the guard below must fail IT without touching the
             # rest of the batch
-            logits_row = np.full_like(logits_row, np.nan)
-        if not np.isfinite(logits_row).all():
+            finite = False
+        elif isinstance(logits_row, _Row):
+            finite = logits_row.finite
+        else:
+            finite = np.isfinite(logits_row).all()
+        if not finite:
             self._finish(req, "error")
             return
         tok = _sample_row(req, logits_row)
@@ -659,21 +682,63 @@ class LLMEngine:
                     if t is not None})
 
 
+class _DecodeOut:
+    """What one decode step left: the program's choice and finite test of
+    every row, on the host, and the float32 logits, on the device until
+    somebody needs a row of them; one copy then serves the whole step."""
+
+    def __init__(self, logits, ids, finite):
+        self.logits, self.ids, self.finite = logits, ids, finite
+        self.host = None    # the logits, once they have been copied
+        self.picked = 0     # rows that took the program's token
+
+    def rows(self):
+        if self.host is None:
+            self.host = np.asarray(self.logits)
+        return self.host
+
+
+class _Row:
+    """One row of a decode step as `_emit` receives it.  `np.array(row)`
+    and `np.asarray(row)` give its float32 logits, fetched at that
+    moment."""
+    __slots__ = ("_out", "_i")
+
+    def __init__(self, out, i):
+        self._out, self._i = out, i
+
+    @property
+    def finite(self):
+        return self._out.finite[self._i]
+
+    def pick(self):
+        """The token the program chose: the row's argmax."""
+        self._out.picked += 1
+        return self._out.ids[self._i]
+
+    def __array__(self, dtype=None, copy=None):
+        row = self._out.rows()[self._i]
+        return row if dtype is None else row.astype(dtype)
+
+
 def _sample_row(req, logits_row):
-    """Host-side sampling from one fp32 logits row.  Greedy is
-    np.argmax — token-identical to the sequential generate() path;
-    sampled mode filters through the ONE `generation.filter_logits`
-    implementation (so temperature/top-k/top-p semantics can never
-    drift from generate()) and draws from a numpy Generator seeded per
-    (request seed, POSITION) — deterministic regardless of batch
-    composition AND of where the request is served: a failover resume
-    re-derives exactly the stream a single replica would have drawn
-    (one shared stateful Generator could not survive a resume — its
-    cursor would restart)."""
+    """One row's token.  Greedy is the row's argmax — the decode
+    program's own where the row carries it, np.argmax over an ndarray:
+    either way token-identical to the sequential generate() path.
+    Sampled mode stays on the host: it filters the fp32 row through the
+    ONE `generation.filter_logits` implementation (so
+    temperature/top-k/top-p semantics can never drift from generate())
+    and draws from a numpy Generator seeded per (request seed, POSITION)
+    — deterministic regardless of batch composition AND of where the
+    request is served: a failover resume re-derives exactly the stream a
+    single replica would have drawn (one shared stateful Generator could
+    not survive a resume — its cursor would restart)."""
     if not req.do_sample:
+        if isinstance(logits_row, _Row):
+            return logits_row.pick()
         return int(np.argmax(logits_row))
     from ..text.generation import filter_logits
-    filtered = filter_logits(jnp.asarray(logits_row)[None, :],
+    filtered = filter_logits(jnp.asarray(np.asarray(logits_row))[None, :],
                              req.temperature, req.top_k, req.top_p)[0]
     p = np.asarray(jax.nn.softmax(filtered), dtype=np.float64)
     p = p / p.sum()      # exact renormalization for rng.choice

@@ -129,6 +129,200 @@ def test_sampled_requests_deterministic_per_seed(gpt_engine):
 
 
 # ===================================================================
+# the decode program picks a greedy row's token; the logits stay on the
+# device until a row needs them
+# ===================================================================
+def _wide_gpt():
+    """Heads 128 wide: under PADDLE_TPU_PALLAS=interpret the decode
+    program runs the pallas kernel."""
+    pt.seed(0)
+    return GPTForCausalLM(GPTConfig(
+        vocab_size=64, hidden_size=128, num_layers=1, num_heads=1,
+        max_position_embeddings=64, hidden_dropout=0.0,
+        attention_dropout=0.0, tensor_parallel=False))
+
+
+def _roots(run):
+    """(what `run()` returned, the counts of the `serving.step` roots of
+    the steps it made that decoded a row)."""
+    from paddle_tpu.observability import trace
+    trace.clear()
+    out = run()
+    roots = [s[6] for s in trace.spans()
+             if s[0] == "serving.step" and s[6]["decode_rows"]]
+    trace.clear()
+    return out, roots
+
+
+def _on_the_host(eng):
+    """Wrap `eng._emit` as the parent served every row: the step's
+    float32 logits fetched, the finite test and the token on the host."""
+    emit = eng._emit
+    eng._emit = lambda req, row, now: emit(req, np.asarray(row), now)
+
+
+@pytest.mark.parametrize("build, pallas", [
+    (_tiny_gpt, None), (_wide_gpt, "interpret")], ids=["gather", "kernel"])
+def test_the_programs_choice_is_the_hosts_argmax_over_the_fetched_row(
+        build, pallas, monkeypatch):
+    if pallas:
+        monkeypatch.setenv("PADDLE_TPU_PALLAS", pallas)
+    model = build()
+    eng = LLMEngine(model, num_blocks=24, block_size=8, max_running=4,
+                    prefill_chunk=16)
+    emit, host = eng._emit, {}
+
+    def spy(req, row, now):
+        # the parent's choice, over the row fetched through the view;
+        # the view itself goes on, so the engine takes the program's
+        assert not isinstance(row, np.ndarray)
+        fetched = np.asarray(row)
+        assert fetched.dtype == np.float32 and fetched.shape == (64,)
+        assert row.finite == bool(np.isfinite(fetched).all())
+        host.setdefault(req.id, []).append(int(np.argmax(fetched)))
+        return emit(req, row, now)
+
+    eng._emit = spy
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, 64, size=n).tolist() for n in (5, 19, 9, 12)]
+    reqs = [eng.add_request(p, max_new_tokens=6) for p in prompts]
+    eng.run()
+    for req, p in zip(reqs, prompts):
+        assert req.generated == host[req.id] == _seq_ref(model, p, 6)
+    assert eng.close() == ([], [])
+
+
+def test_a_greedy_step_copies_no_logits_and_a_sampled_row_copies_them_once(
+        gpt):
+    kw = dict(num_blocks=24, block_size=8, max_running=4, prefill_chunk=16)
+    greedy = [[5, 6, 7], [9, 8, 7, 6], [1, 2, 3, 4, 5]]
+    drawn = dict(do_sample=True, temperature=0.9, top_k=20, seed=123)
+
+    def serve(eng, sampled):
+        reqs = [eng.add_request(p, max_new_tokens=6) for p in greedy]
+        if sampled:
+            reqs.append(eng.add_request([4, 4, 2], max_new_tokens=3,
+                                        **drawn))
+        eng.run()
+        return [r.generated for r in reqs]
+
+    eng = LLMEngine(gpt, **kw)
+    alone, roots = _roots(lambda: serve(eng, False))
+    assert roots
+    for c in roots:
+        assert c["logit_rows_fetched"] == 0
+        assert c["rows_picked_on_device"] == c["decode_rows"]
+    assert alone == [_seq_ref(gpt, p, 6) for p in greedy]
+
+    mixed, roots = _roots(lambda: serve(eng, True))
+    # the drawn row lives three steps: those copy the step's logits, all
+    # slots of them and once; the steps after it copy nothing again
+    with_drawn = [c for c in roots if c["decode_rows"] == 4]
+    assert len(with_drawn) == 3 and len(roots) > 3
+    for c in roots:
+        if c["decode_rows"] == 4:
+            assert c["logit_rows_fetched"] == eng.max_running
+            assert c["rows_picked_on_device"] == 0
+        else:
+            assert c["logit_rows_fetched"] == 0
+            assert c["rows_picked_on_device"] == c["decode_rows"]
+    # both kinds of row produce what the host's path produces
+    host = LLMEngine(gpt, **kw)
+    _on_the_host(host)
+    assert mixed == serve(host, True)
+    assert mixed[:3] == alone
+
+
+@pytest.mark.parametrize("how", ["poison", "nan"])
+def test_a_row_that_is_not_finite_fails_alone_and_copies_no_logits(
+        how, monkeypatch):
+    """chaos `serving.request_poison`, or a NaN that the decode program
+    itself finds in one row: that request ends with `error` and no
+    token, the others are served as if it were not there."""
+    import jax.numpy as jnp
+    from paddle_tpu.resilience import chaos
+    from paddle_tpu.tensor import Tensor
+    model = _tiny_gpt()
+    prompts = [[5, 6, 7], None, [1, 2, 3, 4, 5]]
+    refs = [p and _seq_ref(model, p, 5) for p in prompts]
+    # a token that only the second request is ever fed
+    odd = next(t for t in range(63, 0, -1)
+               if t not in prompts[0] + prompts[2] + refs[0] + refs[2])
+    prompts[1] = [9, 8, 7, odd]
+    if how == "nan":
+        forward = model.forward
+
+        def planted(ids, caches=None, **kw):
+            # one NaN in the logits of the row that is fed that token
+            logits = forward(ids, caches=caches, **kw)._array
+            bad = (ids._array[:, -1] == odd)[:, None, None] \
+                & (jnp.arange(logits.shape[-1]) == 11)
+            return Tensor._from_array(jnp.where(bad, jnp.nan, logits))
+
+        monkeypatch.setattr(model, "forward", planted)
+    eng = LLMEngine(model, num_blocks=24, block_size=8, max_running=4,
+                    prefill_chunk=16)
+
+    def serve():
+        with chaos.scoped("serving.request_poison@2" if how == "poison"
+                          else ""):
+            reqs = [eng.add_request(p, max_new_tokens=5) for p in prompts]
+            eng.run()
+        return reqs
+
+    reqs, roots = _roots(serve)
+    assert [r.finish_reason for r in reqs] == ["length", "error", "length"]
+    assert reqs[1].generated == []
+    assert [r.generated for r in reqs[::2]] == refs[::2]
+    assert roots and all(c["logit_rows_fetched"] == 0 for c in roots)
+    assert eng.close() == ([], [])
+
+
+def test_a_wrapped_emit_reads_the_row_and_an_altered_row_is_sampled_on_host(
+        gpt):
+    """What the benchmark's altered-token fault does: `np.array(row)`
+    gives the float32 logits row, and an ndarray handed on is tested and
+    sampled on the host."""
+    eng = LLMEngine(gpt, num_blocks=24, block_size=8, max_running=4,
+                    prefill_chunk=16)
+    emit, seen = eng._emit, []
+
+    def altered(req, row, now):
+        row = np.array(row)
+        assert row.dtype == np.float32 and row.flags.writeable
+        best = int(np.argmax(row))
+        row[best] = row.min() - 1.0
+        seen.append((req.id, best, int(np.argmax(row))))
+        return emit(req, row, now)
+
+    eng._emit = altered
+    prompts = [[5, 6, 7], [9, 8, 7, 6]]
+
+    def serve():
+        reqs = [eng.add_request(p, max_new_tokens=4) for p in prompts]
+        eng.run()
+        return reqs
+
+    reqs, roots = _roots(serve)
+    for req in reqs:
+        mine = [s for s in seen if s[0] == req.id]
+        assert req.generated == [second for _, _, second in mine]
+        assert all(best != second for _, best, second in mine)
+    # the first token is the sequential path's best, pushed down
+    assert [s[1] for s in seen[:2]] \
+        == [_seq_ref(gpt, p, 1)[0] for p in prompts]
+    for c in roots:
+        assert c["logit_rows_fetched"] == eng.max_running
+        assert c["rows_picked_on_device"] == 0
+    # a row of NaN handed on fails its request on the host's test
+    eng._emit = lambda req, row, now: emit(
+        req, np.full(64, np.nan, np.float32), now)
+    req = eng.add_request([3, 1, 4], max_new_tokens=3)
+    eng.run()
+    assert req.finish_reason == "error" and req.generated == []
+
+
+# ===================================================================
 # block pool invariants
 # ===================================================================
 def test_block_pool_alloc_free_refcount():
@@ -581,6 +775,34 @@ def test_serving_aot_roundtrip_zero_compile(gpt, tmp_path):
         assert load_serving_artifacts(cold, str(tmp_path)) == []
     from paddle_tpu.jit.save_load import AOTIncompatible
     with pytest.raises(AOTIncompatible):
+        load_serving_artifacts(cold, str(tmp_path), strict=True)
+
+
+def test_serving_aot_of_another_program_layout_is_refused(gpt, tmp_path):
+    """An artifact exported before the decode program returned its ids
+    (a manifest without `layout`) takes the same arguments and returns
+    other outputs: it is refused with the reason, and the live programs
+    serve."""
+    import json
+    prompts = [[1, 2, 3, 4, 5], [7] * 11]
+    kw = dict(num_blocks=16, block_size=8, max_running=4,
+              prefill_chunk=16)
+    eng = LLMEngine(gpt, **kw)
+    refs = eng.generate_batch(prompts, max_new_tokens=5)
+    assert export_serving_artifacts(eng, str(tmp_path))["layout"] == 2
+    man = os.path.join(str(tmp_path), "serving_manifest.json")
+    with open(man) as f:
+        data = json.load(f)
+    del data["layout"]
+    with open(man, "w") as f:
+        json.dump(data, f)
+    cold = LLMEngine(gpt, **kw)
+    with pytest.warns(UserWarning, match="program layout mismatch"):
+        assert load_serving_artifacts(cold, str(tmp_path)) == []
+    assert cold.generate_batch(prompts, max_new_tokens=5) == refs
+    assert ("decode",) in cold._programs
+    from paddle_tpu.jit.save_load import AOTIncompatible
+    with pytest.raises(AOTIncompatible, match="layout 1"):
         load_serving_artifacts(cold, str(tmp_path), strict=True)
 
 

@@ -106,9 +106,14 @@ def test_the_root_counts_the_rows_it_decoded_and_a_prefill_its_chunk(
                                      served["summaries"]):
         counts = root[F["counts"]]
         assert sorted(counts) == ["decode_rows", "kv_blocks_live",
-                                  "kv_blocks_walked"]
+                                  "kv_blocks_walked", "logit_rows_fetched",
+                                  "rows_picked_on_device"]
         rows = counts["decode_rows"]
         assert rows == summary["decoded"]
+        # every request is greedy: the program chose each row's token,
+        # and no step copied its logits
+        assert counts["rows_picked_on_device"] == rows
+        assert counts["logit_rows_fetched"] == 0
         # every row lives in a block or more.  This model's heads are 8
         # wide, so the XLA fallback serves its decode steps, and that
         # gathers every column of every slot's table
