@@ -20,13 +20,13 @@ to one process at a time, so nothing here starts a child:
    the seed, 32 new tokens each; chunked prefill and continuous batching
    asserted from the engine's counters; two streams compared token for
    token with sequential ``generation.generate``; no pool leaks; the
-   paged kernel in the decode program.  Weights are f32 (as in bench.py's
-   serving legs) and matmuls run at "highest" precision: greedy token
-   identity between two attention paths that sum in different orders is
-   not a property of bf16 arithmetic (the tiny bf16 rehearsal already
-   differs on the CPU), so the comparison is made where it is one.  The
-   bf16 paged kernel is run against the gather fallback on its own.  The
-   train state is released before the engine is built.
+   paged kernel in the decode program.  Weights are f32 and matmuls run
+   at "highest" precision: greedy token identity between two attention
+   paths that sum in different orders is not a property of bf16
+   arithmetic (the tiny bf16 rehearsal already differs on the CPU), so
+   the comparison is made where it is one.  The bf16 paged kernel is run
+   against the gather fallback on its own.  The train state is released
+   before the engine is built.
 4. compile cache: the directory in use and its entry count.
 
 ``--chips 4`` runs ONLY the sharded path and what it is compared with:

@@ -106,23 +106,6 @@ def tpu_chips_visible() -> int:
             or len(glob.glob("/dev/vfio/[0-9]*")))
 
 
-# bf16 peak of ONE chip by `device_kind` — the denominator of every MFU
-# this repo prints (bench.py, tools/mfu_profile.py).  Source: Google Cloud
-# documentation, "TPU v5e" (197 TFLOP/s bf16; 16 GB HBM at 819 GB/s).
-# An unknown kind is an error, never a default.
-PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0, "TPU v5e": 197.0}
-
-
-def peak_bf16_tflops(device_kind=None) -> float:
-    kind = device_kind or jax.devices()[0].device_kind
-    try:
-        return PEAK_BF16_TFLOPS[kind]
-    except KeyError:
-        raise KeyError(
-            f"no peak FLOP/s known for device kind {kind!r}; add it to "
-            f"paddle_tpu.device.PEAK_BF16_TFLOPS with its source") from None
-
-
 # ------------------------------------------------------- cuda-compat shims
 class _CudaNamespace:
     """paddle.device.cuda compatibility (reference: python/paddle/device/

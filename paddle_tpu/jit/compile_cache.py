@@ -125,8 +125,8 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def place_jax_cache():
     """Switch on JAX's persistent compilation cache for this process and
     return its directory.  Called by process entry points (chip_smoke.py,
-    tools/serve.py, the serving worker, bench.py, init_parallel_env) —
-    never at import.
+    tools/serve.py, the serving worker, init_parallel_env) — never at
+    import.
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its
     cache there and nothing here sets another.  Where it is not, the
