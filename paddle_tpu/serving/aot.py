@@ -14,7 +14,7 @@ Layout under `path/`:
 Compatibility is validated at LOAD time with the same refuse-with-reason
 stamp checks as `jit.load_inference` (platform, device kind/count, mesh,
 jax/jaxlib versions) and against the layout of what the engine's
-programs return; a refused or damaged artifact is skipped with the
+programs take and return; a refused or damaged artifact is skipped with the
 reason — the engine's live-jit path serves instead, never an abort.
 
 Trade-off baked into the format: serialized executables are ALIAS-FREE
@@ -39,12 +39,16 @@ from ..observability import metrics as _metrics
 
 _MANIFEST = "serving_manifest.json"
 _PROGRAMS = "programs"
-# What the programs return, as the engine unpacks it; a manifest without
-# the key is layout 1.  2: the decode program returns (logits, ids,
-# finite, planes, *load) where it returned (logits, planes, *load).  An
-# executable of another layout takes the same arguments, so nothing but
-# this number would refuse it
-_LAYOUT = 2
+# What the programs take and return, as the engine calls and unpacks
+# them; a manifest without the key is layout 1.  2: the decode program
+# returns (logits, ids, finite, planes, *load) where it returned
+# (logits, planes, *load): the same arguments, so nothing but this
+# number would refuse the older one.  3: the decode program takes two
+# more arguments, `prev_ids` and `src` (a chained row's token is the
+# pick of the program before, read on the device); an older executable
+# would refuse every call with a TypeError and a warning a step, so it
+# is refused once, here, with the reason
+_LAYOUT = 3
 
 
 def _key_name(key):
@@ -108,7 +112,7 @@ def load_serving_artifacts(engine, path, strict=False):
         ok, reason = False, (
             f"program layout mismatch: artifact exported for layout "
             f"{manifest.get('layout', 1)!r}, this engine's programs "
-            f"return layout {_LAYOUT}")
+            f"are layout {_LAYOUT}")
     if not ok:
         if strict:
             raise AOTIncompatible(reason)

@@ -12,9 +12,16 @@ never recompiles:
   so prefill pays attention+MLP only.
 
 `step()` is one scheduler iteration: admit → bounded prefill chunking →
-one batched decode step → sample/stream/finish.  Long prompts therefore
-chunk across many steps while every decode-ready request still advances
-one token per step — prefill never stalls in-flight decode.
+dispatch one batched decode program → stream/finish the picks of the
+program dispatched a step AGO.  The engine runs one decode program ahead
+of the host: a greedy row's next token is the previous program's pick,
+taken on the device (`prev_ids[src]`) before the host has seen it, so the
+device never stands still while the host emits a step and builds the
+next.  A row that draws its token on the host (`do_sample`) cannot
+chain; a step that holds one lands what is in flight first and then runs
+dispatch → wait → emit in place.  Long prompts chunk across many steps
+while every decode-ready request still advances one token per step —
+prefill never stalls in-flight decode.
 
 Token parity: with greedy sampling the engine's per-request output is
 token-identical to a sequential `generation.generate` call — decode
@@ -28,6 +35,7 @@ docs/serving.md for the full table.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import warnings
 
@@ -36,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from ..autograd import engine as _autograd
+from ..distributed import mesh as mesh_mod
 from ..jit import functional_bridge as FB
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
@@ -75,7 +84,7 @@ class LLMEngine:
         model.eval()
         self.pool = BlockPool.for_model(model, num_blocks,
                                         block_size=block_size, dtype=dtype)
-        self.pool.shard_()
+        sharded = self.pool.shard_()
         self.scheduler = Scheduler(self.pool, max_running=max_running,
                                    promote_after=promote_after)
         self.max_running = int(max_running)
@@ -109,6 +118,16 @@ class LLMEngine:
             FB.split_state(model)
         self._programs = {}     # key -> live jitted program
         self._chunk_loads = []  # routed layers' load of chunks, unread yet
+        # the decode program dispatched and not landed yet (its picks
+        # are emitted by the next step), and the newest program's `ids`
+        # ON THE DEVICE, from which the next one takes its chained rows'
+        # tokens; zeros stand for it before the first step, so that
+        # every call has one argument form and the program compiles once
+        self._flight = None
+        self._prev_ids = jnp.zeros(self.max_running, jnp.int32)
+        if sharded:     # as the program under the mesh returns its ids
+            self._prev_ids = jax.device_put(self._prev_ids,
+                                            mesh_mod.replicated())
         self._aot_execs = {}    # key -> deserialized AOT executable
         self._finished = []
         self._reg = _metrics.registry()
@@ -196,7 +215,11 @@ class LLMEngine:
 
     @property
     def has_work(self):
-        return bool(self.scheduler.waiting or self.scheduler.running)
+        """True until every token is delivered: a request leaves
+        `running` when its last token is EMITTED, and picks in flight
+        (of finished requests too: they are dropped) are work."""
+        return bool(self.scheduler.waiting or self.scheduler.running
+                    or self._flight is not None)
 
     def metrics_snapshot(self, prefix="serving_"):
         """Point-in-time snapshot of this replica's serving metrics —
@@ -228,7 +251,11 @@ class LLMEngine:
 
     # ----------------------------------------------------------------- step
     def step(self):
-        """One continuous-batching iteration.  Returns a summary dict.
+        """One continuous-batching iteration.  Returns a summary dict:
+        `decoded` rows DISPATCHED in this step's decode program,
+        `emitted` tokens streamed by this step (the picks of the program
+        a step ago, or this step's own where a `do_sample` row made it
+        synchronous), `admitted`, `prefilled`, `running`, `waiting`.
 
         Every step writes its phases to the span recorder (see
         docs/serving.md): `serving.step` is the root, its children name
@@ -267,7 +294,21 @@ class LLMEngine:
                 prefilled += n
 
             # ---- decode lane: every decode-ready request advances one
-            # token
+            # token.  The order follows the rows: a row that draws its
+            # token on the host needs the token before its next row can
+            # be built, so a step that holds one lands what is in flight
+            # first and its own program in place; every other step
+            # dispatches first and lands the program of the step before
+            # while its own runs
+            landed = collections.Counter(
+                rows_picked_on_device=0, logit_rows_fetched=0,
+                rows_dropped=0, emitted=0)
+            behind = self._flight
+            in_place = any(r.do_sample and r.decode_ready
+                           for r in sched.running)
+            if in_place:
+                self._land(behind, root.sid, landed)
+                behind = None
             with _trace.traced("serving.schedule", parent=root.sid,
                                cat="serving"):
                 ready = []
@@ -280,9 +321,7 @@ class LLMEngine:
             # ready ⊆ running and admit() caps running at max_running, so
             # the static decode program always has a slot for every row
             assert len(ready) <= self.max_running
-            live = walked = 0
-            picks = {"rows_picked_on_device": 0, "logit_rows_fetched": 0}
-            moe = {}
+            live = walked = chained = 0
             if ready:
                 # how far the decode program's attention follows the
                 # traffic: the blocks the rows live in, and the blocks a
@@ -293,30 +332,23 @@ class LLMEngine:
                 live = sum(self.pool.blocks_for(n) for n in lens)
                 walked = self._blocks_read(
                     lens + [1] * (self.max_running - len(ready)))
-                load, picks = self._decode(ready, root.sid)
-                if load is not None:
-                    # [routed layers, experts] live rows each received;
-                    # and the chunks' own, now that the fetch has waited
-                    # for the device: this step's, and those of steps that
-                    # decoded nothing
-                    chunks = [np.asarray(a) for a in self._chunk_loads]
-                    self._chunk_loads = []
-                    moe = {"moe_assignments": int(load.sum()),
-                           "experts_touched": int((load > 0).sum()),
-                           "prefill_moe_assignments": sum(
-                               int(a.sum()) for a in chunks),
-                           "prefill_experts_touched": sum(
-                               int((a > 0).sum()) for a in chunks)}
+                chained = sum(1 for r in ready if r.in_flight)
+                self._flight = self._dispatch(ready, root.sid)
+            self._land(behind, root.sid, landed)
+            if in_place:
+                self._land(self._flight, root.sid, landed)
 
             self._reg.gauge("serving_queue_depth").set(sched.queue_depth)
             self._reg.gauge("serving_running_requests").set(
                 len(sched.running))
             self._reg.gauge("serving_free_blocks").set(
                 self.pool.free_blocks)
-            root.counts.update(decode_rows=len(ready), kv_blocks_live=live,
-                               kv_blocks_walked=walked, **picks, **moe)
+            emitted = landed.pop("emitted")
+            root.counts.update(decode_rows=len(ready), rows_chained=chained,
+                               kv_blocks_live=live, kv_blocks_walked=walked,
+                               **landed)
         return {"admitted": len(admitted), "decoded": len(ready),
-                "prefilled": prefilled,
+                "emitted": emitted, "prefilled": prefilled,
                 "running": len(sched.running),
                 "waiting": sched.queue_depth}
 
@@ -333,7 +365,8 @@ class LLMEngine:
     # ------------------------------------------------------ drain / close
     def cancel(self, req, reason="cancelled"):
         """Abort a queued or running request: frees its blocks, fires
-        `on_finish` with the given reason.  No-op once finished."""
+        `on_finish` with the given reason; a pick of it still in flight
+        is dropped when it lands.  No-op once finished."""
         if req.finish_reason is None:
             self._finish(req, reason)
 
@@ -351,11 +384,12 @@ class LLMEngine:
             self._finish(req, "drained")
         deadline = None if ttl_s is None else clock() + ttl_s
         n = 0
-        while self.scheduler.running and \
+        while (self.scheduler.running or self._flight is not None) and \
                 (max_steps is None or n < max_steps):
             if deadline is not None and clock() > deadline:
                 for req in list(self.scheduler.running):
                     self._finish(req, "drained")
+                self._flight = None     # every pick in it is surplus now
                 break
             self.step()
             n += 1
@@ -373,7 +407,8 @@ class LLMEngine:
             self._finish(req, "drained")
         leaks = self.pool.check_leaks()
         self.pool.release()
-        self._chunk_loads.clear()
+        self._flight = self._prev_ids = None    # picks in flight: dropped
+        self._chunk_loads = []
         self._programs.clear()
         self._aot_execs.clear()
         self._closed = True
@@ -445,7 +480,13 @@ class LLMEngine:
     def _build_decode(self, donate=None):
         model, pn, bn = self.model, self._pn, self._bn
 
-        def pure(p_arrays, b_arrays, planes, tables, pos, tokens, limit):
+        def pure(p_arrays, b_arrays, planes, tables, pos, tokens, limit,
+                 prev_ids, src):
+            # a chained row's token is the pick of row `src` of the
+            # decode program before this one, read where it lies: the
+            # host has not seen it yet.  src < 0: the host's `tokens`
+            tokens = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)],
+                               tokens)
             caches = self._caches(planes, tables, pos, limit)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
@@ -519,7 +560,7 @@ class LLMEngine:
             R, M = self.max_running, self.table_cols
             return functools.partial(self._build_decode, donate=False), (
                 p, b, planes, s((R, M), i32), s((R,), i32), s((R,), i32),
-                s((R,), i32))
+                s((R,), i32), s((R,), i32), s((R,), i32))
         if key[0] == "prefill":
             Lb = int(key[1])
             return functools.partial(self._build_prefill, donate=False), (
@@ -554,7 +595,13 @@ class LLMEngine:
         self._reg.counter("serving_prefill_tokens_total").inc(n)
 
     # -------------------------------------------------------------- decode
-    def _decode(self, ready, parent=None):
+    def _dispatch(self, ready, parent=None):
+        """Build and dispatch one decode program over `ready`, and queue
+        the copies of what the host reads of it behind it.  Returns the
+        flight; nothing waits here.  A row with a pick in flight takes
+        its token from that pick on the device (`src` names its row in
+        the program before); the host sends the token of every other
+        row."""
         R, M = self.max_running, self.table_cols
         with _trace.traced("serving.decode.prepare", parent=parent,
                            cat="serving"):
@@ -562,48 +609,95 @@ class LLMEngine:
             pos = np.zeros(R, np.int32)
             tokens = np.zeros(R, np.int32)
             limit = np.zeros(R, np.int32)   # 0 = dead slot, writes dropped
+            src = np.full(R, -1, np.int32)  # -1 = the host's token
             for i, req in enumerate(ready):
                 tables[i, :len(req.block_table)] = req.block_table
                 pos[i] = req.ctx
-                tokens[i] = req.feed_tokens()[req.ctx]
                 limit[i] = req.ctx + 1
+                if req.in_flight:
+                    src[i] = req.slot
+                else:
+                    tokens[i] = req.feed_tokens()[req.ctx]
+        # the logits are copied only for a row that draws its token on
+        # the host; a greedy step leaves them on the device
+        sampled = any(r.do_sample for r in ready)
         with _trace.traced("serving.decode.dispatch", parent=parent,
                            cat="serving"):
             logits, ids, finite, self.pool.planes, *load = self._run_program(
                 ("decode",), self._build_decode,
                 self._p_arrays, self._b_arrays, self.pool.planes,
-                tables, pos, tokens, limit)
-        # the wait is its own span, so that the fetch times the copy
-        # alone.  The copies are queued behind the program first, as a
-        # bare np.asarray would queue them: left to start after the wait
-        # has returned they cost the step 0.2 ms (PERF.md, PR 25).  The
-        # logits are copied only for a row that draws its token on the
-        # host; a greedy step leaves them on the device
-        sampled = any(r.do_sample for r in ready)
-        with _trace.traced("serving.decode.wait", parent=parent,
-                           cat="serving"):
+                tables, pos, tokens, limit, self._prev_ids, src)
+            self._prev_ids = ids
+            # the copies are queued behind the program at once: left to
+            # start after a wait has returned they cost the step 0.2 ms
+            # (PERF.md, PR 25)
             for a in [ids, finite] + load + ([logits] if sampled else []):
                 a.copy_to_host_async()
-            ids.block_until_ready()
+        # the chunks dispatched before this program have ended when its
+        # ids arrive: their loads are read with it
+        flight = _Flight(list(ready), logits, ids, finite, load,
+                         self._chunk_loads, sampled)
+        self._chunk_loads = []
+        for i, req in enumerate(ready):
+            req.ctx += 1
+            req.in_flight += 1
+            req.slot = i
+        self._reg.counter("serving_decode_steps_total").inc()
+        self._reg.histogram("serving_decode_batch").observe(len(ready))
+        return flight
+
+    def _land(self, flight, parent, landed):
+        """Wait for a dispatched decode program, fetch its picks and
+        emit them; the counts of what it brought are added to `landed`.
+        A row whose request has finished meanwhile (EOS, a failed finite
+        test, a cancel or an expiry are seen one step late) is a surplus
+        row: its pick is dropped, never emitted, and counted
+        (`rows_dropped`).  No-op on None."""
+        if flight is None:
+            return
+        if flight is self._flight:
+            self._flight = None
+        # the wait is its own span, so that the fetch times the copy
+        # alone
+        with _trace.traced("serving.decode.wait", parent=parent,
+                           cat="serving"):
+            flight.ids.block_until_ready()
         with _trace.traced("serving.decode.fetch", parent=parent,
                            cat="serving"):
-            out = _DecodeOut(logits, np.asarray(ids).tolist(),
-                             np.asarray(finite).tolist())
-            if sampled:
-                out.rows()
-            load = np.asarray(load[0]) if load else None
+            flight.fetch()
         with _trace.traced("serving.sample", parent=parent,
                            cat="serving"):
             now = clock()
-            self._reg.counter("serving_decode_steps_total").inc()
-            self._reg.histogram("serving_decode_batch").observe(len(ready))
-            for i, req in enumerate(ready):
-                req.ctx += 1
-                self._emit(req, _Row(out, i), now)
-        fetched = out.host is not None
-        return load, {
-            "rows_picked_on_device": 0 if fetched else out.picked,
-            "logit_rows_fetched": R if fetched else 0}
+            for i, req in enumerate(flight.rows):
+                if req is not None:
+                    req.in_flight -= 1
+                if req is None or req.finish_reason is not None:
+                    landed["rows_dropped"] += 1
+                    continue
+                self._emit(req, _Row(flight, i), now)
+                landed["emitted"] += 1
+                if (req.in_flight and req.finish_reason is None
+                        and req.generated[-1] != flight.ids[i]):
+                    # the host chose another token than the program's
+                    # pick (a wrapper of `_emit` handed on a row of its
+                    # own): the row already chained on that pick is
+                    # surplus, and the host feeds the position again
+                    self._flight.rows[req.slot] = None
+                    req.in_flight -= 1
+                    req.ctx -= 1
+        if flight.host is not None:
+            landed["logit_rows_fetched"] += self.max_running
+        else:
+            landed["rows_picked_on_device"] += flight.picked
+        if flight.load is not None:
+            # [routed layers, experts] live rows each received; and the
+            # load of the chunks that ran before the program
+            landed["moe_assignments"] += int(flight.load.sum())
+            landed["experts_touched"] += int((flight.load > 0).sum())
+            landed["prefill_moe_assignments"] += sum(
+                int(a.sum()) for a in flight.chunks)
+            landed["prefill_experts_touched"] += sum(
+                int((a > 0).sum()) for a in flight.chunks)
 
     def _emit(self, req, logits_row, now):
         """The hook a decoded row goes through: `logits_row` is the
@@ -682,24 +776,38 @@ class LLMEngine:
                     if t is not None})
 
 
-class _DecodeOut:
-    """What one decode step left: the program's choice and finite test of
-    every row, on the host, and the float32 logits, on the device until
-    somebody needs a row of them; one copy then serves the whole step."""
+class _Flight:
+    """One decode program from its dispatch to the step that lands it:
+    the requests by slot, the program's choice and finite test of every
+    row (device arrays until `fetch`), the routed layers' load with that
+    of the chunks dispatched before it, and the float32 logits, on the
+    device until somebody needs a row of them; one copy then serves the
+    whole program."""
 
-    def __init__(self, logits, ids, finite):
-        self.logits, self.ids, self.finite = logits, ids, finite
+    def __init__(self, rows, logits, ids, finite, load, chunks, sampled):
+        self.rows, self.logits, self.ids, self.finite = (
+            rows, logits, ids, finite)
+        self.load, self.chunks, self.sampled = load, chunks, sampled
         self.host = None    # the logits, once they have been copied
         self.picked = 0     # rows that took the program's token
 
-    def rows(self):
+    def fetch(self):
+        """Bring to the host what the copies queued at dispatch carry."""
+        self.ids = np.asarray(self.ids).tolist()
+        self.finite = np.asarray(self.finite).tolist()
+        if self.sampled:
+            self.logits_rows()
+        self.load = np.asarray(self.load[0]) if self.load else None
+        self.chunks = [np.asarray(a) for a in self.chunks]
+
+    def logits_rows(self):
         if self.host is None:
             self.host = np.asarray(self.logits)
         return self.host
 
 
 class _Row:
-    """One row of a decode step as `_emit` receives it.  `np.array(row)`
+    """One row of a landed decode program as `_emit` receives it.  `np.array(row)`
     and `np.asarray(row)` give its float32 logits, fetched at that
     moment."""
     __slots__ = ("_out", "_i")
@@ -717,7 +825,7 @@ class _Row:
         return self._out.ids[self._i]
 
     def __array__(self, dtype=None, copy=None):
-        row = self._out.rows()[self._i]
+        row = self._out.logits_rows()[self._i]
         return row if dtype is None else row.astype(dtype)
 
 

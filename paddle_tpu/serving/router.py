@@ -624,7 +624,10 @@ class Router:
             except (chaos.ChaosInterrupt, Exception) as e:  # noqa: B014
                 self._evict(slot, "crash", error=e)
                 continue
+            # a step that only lands the picks in flight (`emitted`)
+            # dispatched nothing and still made progress
             if summary and (summary.get("decoded")
+                            or summary.get("emitted")
                             or summary.get("admitted")
                             or summary.get("prefilled")):
                 progressed = True

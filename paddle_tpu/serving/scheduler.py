@@ -91,6 +91,11 @@ class Request:
         self.resumed = resume_tokens is not None
         self.block_table = []       # pool block ids, position-ordered
         self.ctx = 0                # tokens whose K/V live in the pool
+        # picks the engine has dispatched and not emitted yet (it runs
+        # one decode program ahead of the host), and this request's row
+        # in the newest of those programs
+        self.in_flight = 0
+        self.slot = None
         self.finish_reason = None
         self.poisoned = False       # chaos serving.request_poison
         self.preemptions = 0
@@ -116,14 +121,24 @@ class Request:
     # into the pool in chunks; the decode step then consumes feed[ctx]
     # (the last prompt token on a fresh request, the newest generated
     # token afterwards), writes its K/V, and samples the next token —
-    # ONE uniform decode path does all sampling.
+    # ONE uniform decode path does all sampling.  A pick in flight
+    # (`in_flight`) has advanced `ctx` at its dispatch and reaches
+    # `generated` when it is emitted, a step later: until then
+    # ctx == feed_len - 1 + in_flight.
     @property
     def feed_len(self):
         return len(self.prompt) + len(self.generated)
 
     @property
     def decode_ready(self):
-        return self.state == RUNNING and self.ctx == self.feed_len - 1
+        """Every token before the next input is in the pool or on its
+        way there, and a token is left to pick: a row whose pick in
+        flight is its last waits for it, so a length finish never
+        dispatches a surplus row."""
+        return (self.state == RUNNING
+                and self.ctx == self.feed_len - 1 + self.in_flight
+                and len(self.generated) + self.in_flight
+                < self.max_new_tokens)
 
     @property
     def needs_prefill(self):
@@ -201,10 +216,12 @@ class Scheduler:
         return admitted
 
     def grow(self, req):
-        """Ensure `req` has a block for its next token; preempts the
-        youngest OTHER running request when the pool is dry.  Returns
-        False when no space could be made (req should retry next step)."""
-        need_blocks = self.pool.blocks_for(req.feed_len)
+        """Ensure `req` has a block for the position its next decode
+        row writes (`ctx`: feed_len - 1, one further with a pick in
+        flight); preempts the youngest OTHER running request when the
+        pool is dry.  Returns False when no space could be made (req
+        should retry next step)."""
+        need_blocks = self.pool.blocks_for(req.ctx + 1)
         while len(req.block_table) < need_blocks:
             got = self.pool.allocate(1)
             if got is not None:

@@ -141,19 +141,25 @@ def test_served_logits_equal_the_references_full_forward(
     chunks = [s[6] for s in mine if s[0] == "serving.prefill"]
     # every chunk touched 2..8 experts in the FIRST of the two routed
     # layers (the last layer's output is dead code in a prefill program,
-    # its routing with it), read in the step whose decode next waited for
-    # the device
+    # its routing with it), read with the picks of the decode program
+    # dispatched next after it
     fed = sum(len(p) - 1 for p in prompts)
     assert sum(c["tokens"] for c in chunks) == fed
-    steps = [s[6] for s in mine if s[0] == "serving.step"]
+    steps = [s[6] for s in sorted(mine, key=lambda s: s[1])
+             if s[0] == "serving.step"]
     assert sum(c.get("prefill_moe_assignments", 0) for c in steps) \
         == fed * 2
     touched = sum(c.get("prefill_experts_touched", 0) for c in steps)
     assert 2 * len(chunks) <= touched <= 8 * len(chunks)
     assert not eng._chunk_loads
+    # the routed layers' load comes to the host with the picks, in the
+    # step AFTER the one that dispatched the rows
+    for before, c in zip(steps, steps[1:]):
+        assert c.get("moe_assignments", 0) == before["decode_rows"] * 2 * 2
+        if before["decode_rows"]:
+            assert 2 <= c["experts_touched"] <= min(16, c["moe_assignments"])
+    # the blocks describe the program the step dispatched
     for c in roots:
-        assert c["moe_assignments"] == c["decode_rows"] * 2 * 2
-        assert 2 <= c["experts_touched"] <= min(16, c["moe_assignments"])
         assert c["kv_blocks_live"] <= c["kv_blocks_walked"]
         if pallas:      # live blocks, and one block for each dead slot
             assert c["kv_blocks_walked"] == c["kv_blocks_live"] \

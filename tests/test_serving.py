@@ -144,14 +144,21 @@ def _wide_gpt():
 
 def _roots(run):
     """(what `run()` returned, the counts of the `serving.step` roots of
-    the steps it made that decoded a row)."""
+    the steps it made, in order).  A step counts the rows it DISPATCHED
+    (`decode_rows`, `rows_chained`) and what it brought to the host of
+    the program it landed: the one of the step before, or its own where
+    a `do_sample` row makes the step wait in place."""
     from paddle_tpu.observability import trace
     trace.clear()
     out = run()
-    roots = [s[6] for s in trace.spans()
-             if s[0] == "serving.step" and s[6]["decode_rows"]]
+    roots = [s[6] for s in sorted(trace.spans(), key=lambda s: s[1])
+             if s[0] == "serving.step"]
     trace.clear()
     return out, roots
+
+
+def _total(roots, key):
+    return sum(c[key] for c in roots)
 
 
 def _on_the_host(eng):
@@ -208,24 +215,29 @@ def test_a_greedy_step_copies_no_logits_and_a_sampled_row_copies_them_once(
 
     eng = LLMEngine(gpt, **kw)
     alone, roots = _roots(lambda: serve(eng, False))
-    assert roots
-    for c in roots:
-        assert c["logit_rows_fetched"] == 0
-        assert c["rows_picked_on_device"] == c["decode_rows"]
+    assert _total(roots, "decode_rows") == 18
+    assert _total(roots, "logit_rows_fetched") == 0
+    # a step lands the picks of the step before
+    assert [c["rows_picked_on_device"] for c in roots[1:]] \
+        == [c["decode_rows"] for c in roots[:-1]]
     assert alone == [_seq_ref(gpt, p, 6) for p in greedy]
 
     mixed, roots = _roots(lambda: serve(eng, True))
-    # the drawn row lives three steps: those copy the step's logits, all
-    # slots of them and once; the steps after it copy nothing again
+    # the drawn row lives three steps: those wait for their own program
+    # and copy its logits, all slots of them and once, and chain
+    # nothing; the steps after it copy nothing and run ahead again
     with_drawn = [c for c in roots if c["decode_rows"] == 4]
     assert len(with_drawn) == 3 and len(roots) > 3
-    for c in roots:
-        if c["decode_rows"] == 4:
-            assert c["logit_rows_fetched"] == eng.max_running
-            assert c["rows_picked_on_device"] == 0
-        else:
-            assert c["logit_rows_fetched"] == 0
-            assert c["rows_picked_on_device"] == c["decode_rows"]
+    for c in with_drawn:
+        assert c["logit_rows_fetched"] == eng.max_running
+        assert c["rows_picked_on_device"] == c["rows_chained"] == 0
+    after = roots[max(i for i, c in enumerate(roots)
+                      if c["decode_rows"] == 4) + 1:]
+    assert after and _total(after, "logit_rows_fetched") == 0
+    assert _total(after, "rows_picked_on_device") \
+        == _total(after, "decode_rows") == 9
+    # the first of them follows a step that left nothing in flight
+    assert _total(after, "rows_chained") == 6
     # both kinds of row produce what the host's path produces
     host = LLMEngine(gpt, **kw)
     _on_the_host(host)
@@ -274,7 +286,11 @@ def test_a_row_that_is_not_finite_fails_alone_and_copies_no_logits(
     assert [r.finish_reason for r in reqs] == ["length", "error", "length"]
     assert reqs[1].generated == []
     assert [r.generated for r in reqs[::2]] == refs[::2]
-    assert roots and all(c["logit_rows_fetched"] == 0 for c in roots)
+    assert _total(roots, "logit_rows_fetched") == 0
+    # the finite test is seen one step late: the row already chained on
+    # the failed request's pick is thrown away, and that is all
+    assert _total(roots, "rows_dropped") == 1
+    assert _total(roots, "decode_rows") == 5 + 2 + 5
     assert eng.close() == ([], [])
 
 
@@ -304,22 +320,284 @@ def test_a_wrapped_emit_reads_the_row_and_an_altered_row_is_sampled_on_host(
         return reqs
 
     reqs, roots = _roots(serve)
-    for req in reqs:
+    for req, p in zip(reqs, prompts):
         mine = [s for s in seen if s[0] == req.id]
         assert req.generated == [second for _, _, second in mine]
         assert all(best != second for _, best, second in mine)
-    # the first token is the sequential path's best, pushed down
-    assert [s[1] for s in seen[:2]] \
-        == [_seq_ref(gpt, p, 1)[0] for p in prompts]
-    for c in roots:
-        assert c["logit_rows_fetched"] == eng.max_running
-        assert c["rows_picked_on_device"] == 0
+        # the stream the host chose is the stream the model was fed: the
+        # best of every row is the sequential path's next token after
+        # the tokens EMITTED so far, although the row chained on the
+        # program's own pick was already in flight
+        for k, (_, best, _) in enumerate(mine):
+            assert best == _seq_ref(gpt, p + req.generated[:k], 1)[0]
+    # every program that brought a token had its logits copied, once:
+    # the two requests run in step, four tokens each
+    assert {c["logit_rows_fetched"] for c in roots} == {0, eng.max_running}
+    assert _total(roots, "logit_rows_fetched") == 4 * eng.max_running
+    assert _total(roots, "rows_picked_on_device") == 0
+    # each altered token but a request's last costs the row that had
+    # chained on the program's pick
+    assert _total(roots, "rows_dropped") \
+        == _total(roots, "rows_chained") == 2 * 3
+    assert eng.pool.check_leaks() == ([], [])
     # a row of NaN handed on fails its request on the host's test
     eng._emit = lambda req, row, now: emit(
         req, np.full(64, np.nan, np.float32), now)
     req = eng.add_request([3, 1, 4], max_new_tokens=3)
     eng.run()
     assert req.finish_reason == "error" and req.generated == []
+
+
+# ===================================================================
+# the engine runs one decode program ahead of the host: a greedy row's
+# next token is the pick of the program before, taken on the device
+# ===================================================================
+KW = dict(num_blocks=48, block_size=8, max_running=6, prefill_chunk=16)
+
+
+def test_chained_rows_are_token_identical_and_a_length_finish_wastes_none(
+        gpt):
+    """Interleaved requests of mixed lengths: every row after a
+    request's first takes its token from the device, the streams are the
+    sequential path's, and no program carries a row whose request had its
+    last pick in flight: rows dispatched = tokens generated."""
+    eng = LLMEngine(gpt, **KW)
+    rng = np.random.RandomState(4)
+    lens, news = (5, 23, 3, 9, 14, 7), (1, 6, 9, 2, 4, 7)
+    prompts = [rng.randint(0, 64, size=n).tolist() for n in lens]
+    refs = [_seq_ref(gpt, p, n) for p, n in zip(prompts, news)]
+
+    def serve():
+        reqs = [eng.add_request(p, max_new_tokens=n)
+                for p, n in zip(prompts[:3], news)]
+        for _ in range(3):
+            eng.step()
+        reqs += [eng.add_request(p, max_new_tokens=n)
+                 for p, n in zip(prompts[3:], news[3:])]
+        eng.run()
+        return reqs
+
+    reqs, roots = _roots(serve)
+    assert [r.generated for r in reqs] == refs
+    assert {r.finish_reason for r in reqs} == {"length"}
+    assert _total(roots, "decode_rows") == sum(news)
+    assert _total(roots, "rows_chained") == sum(news) - len(news)
+    assert _total(roots, "rows_dropped") == 0
+    assert _total(roots, "rows_picked_on_device") == sum(news)
+    assert all(r.in_flight == 0 for r in reqs)
+    assert eng.close() == ([], [])
+
+
+def test_an_eos_is_seen_one_step_late_and_its_surplus_pick_is_dropped(gpt):
+    eng = LLMEngine(gpt, **KW)
+    prompt, other = [1, 2, 3, 4, 5], [9, 8, 7, 6]
+    ref = _seq_ref(gpt, prompt, 8)
+    k = next(i for i in range(1, 8) if ref[i] not in ref[:i])
+    got, done = [], []
+
+    def serve():
+        a = eng.add_request(prompt, max_new_tokens=8, eos_token_id=ref[k],
+                            on_token=lambda r, t: got.append(t),
+                            on_finish=lambda r: done.append(
+                                eng.pool.free_blocks))
+        b = eng.add_request(other, max_new_tokens=8)
+        eng.run()
+        return a, b
+
+    (a, b), roots = _roots(serve)
+    assert a.finish_reason == "eos"
+    assert got == a.generated == ref[:k + 1]     # nothing after the EOS
+    assert b.generated == _seq_ref(gpt, other, 8)
+    # the row that had chained on the EOS was in flight when it landed:
+    # thrown away, and that is the one row the run wasted
+    assert _total(roots, "rows_dropped") == 1
+    assert _total(roots, "decode_rows") == (k + 1) + 1 + 8
+    # its blocks went home when it finished, not when the row landed
+    assert len(done) == 1 and done[0] > 0
+    assert eng.pool.free_blocks == eng.pool.num_blocks
+    assert eng.pool.check_leaks() == ([], [])
+
+
+def test_a_preemption_victim_with_a_pick_in_flight_resumes_identically(gpt):
+    rng = np.random.RandomState(2)
+    prompts = [rng.randint(0, 64, size=n).tolist()
+               for n in (7, 11, 5, 9, 6, 4)]
+    refs = [_seq_ref(gpt, p, 8) for p in prompts]
+    eng = LLMEngine(gpt, num_blocks=6, block_size=4, max_running=6,
+                    prefill_chunk=8)
+    preempt, seen = eng.scheduler.preempt, []
+
+    def spy(req):
+        seen.append((req.in_flight, len(req.generated)))
+        preempt(req)
+
+    eng.scheduler.preempt = spy
+    reqs = [eng.add_request(p, max_new_tokens=8) for p in prompts]
+    eng.run()
+    # a victim whose pick was still out keeps it: the pick is emitted
+    # when it lands, and the prefix prefilled again covers it
+    assert any(flying for flying, _ in seen)
+    assert [r.generated for r in reqs] == refs
+    assert eng.pool.check_leaks() == ([], [])
+    assert eng.pool.free_blocks == eng.pool.num_blocks
+
+
+def _drawn_ref(model, prompt, n, temperature, top_k, seed):
+    """The stream a `do_sample` request draws: the full forward's
+    float32 row through `filter_logits`, one numpy Generator a position
+    seeded by (seed, position)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.text.generation import filter_logits
+    out = []
+    for _ in range(n):
+        with pt.no_grad():
+            row = model(pt.to_tensor(np.asarray([prompt + out], "int64"))
+                        ).numpy()[0, -1].astype(np.float32)
+        kept = filter_logits(jnp.asarray(row)[None, :], temperature, top_k,
+                             None)[0]
+        p = np.asarray(jax.nn.softmax(kept), dtype=np.float64)
+        out.append(int(np.random.default_rng([seed, len(out)]).choice(
+            len(p), p=p / p.sum())))
+    return out
+
+
+def test_a_do_sample_row_draws_todays_stream_and_its_steps_chain_nothing(
+        gpt):
+    eng = LLMEngine(gpt, **KW)
+    greedy, drawn = [[5, 6, 7], [9, 8, 7, 6, 5, 4]], [4, 4, 2]
+
+    def serve():
+        reqs = [eng.add_request(p, max_new_tokens=9) for p in greedy]
+        for _ in range(3):      # the greedy rows run ahead of the host
+            eng.step()
+        assert eng._flight is not None
+        reqs.append(eng.add_request(drawn, max_new_tokens=4, do_sample=True,
+                                    temperature=0.9, top_k=20, seed=123))
+        eng.run()
+        return reqs
+
+    reqs, roots = _roots(serve)
+    assert reqs[2].generated == _drawn_ref(gpt, drawn, 4, 0.9, 20, 123)
+    assert [r.generated for r in reqs[:2]] \
+        == [_seq_ref(gpt, p, 9) for p in greedy]
+    # the steps that hold the drawn row wait for their own program: they
+    # chain nothing, and neither does the step after the last of them
+    held = [i for i, c in enumerate(roots) if c["logit_rows_fetched"]]
+    assert len(held) == 4
+    for i in held + [held[-1] + 1]:
+        assert roots[i]["rows_chained"] == 0
+    assert roots[held[0] - 1]["rows_chained"] == 2
+    assert roots[held[-1] + 2]["rows_chained"] == 2
+    assert _total(roots, "rows_dropped") == 0
+    assert _total(roots, "decode_rows") == 9 + 9 + 4
+
+
+@pytest.mark.parametrize("how", ["cancel", "ttl", "close", "drain-ttl"])
+def test_a_request_that_ends_with_a_pick_in_flight_leaves_no_leak(gpt, how):
+    eng = LLMEngine(gpt, **KW)
+    done = []
+    other = eng.add_request([9, 8, 7, 6], max_new_tokens=6)
+    req = eng.add_request([1, 2, 3, 4, 5], max_new_tokens=6,
+                          on_finish=lambda r: done.append(r.finish_reason))
+    while not req.generated:
+        eng.step()
+    assert req.in_flight == 1 and eng._flight is not None
+    had = list(req.generated)
+    if how == "cancel":
+        eng.cancel(req)
+    elif how == "ttl":
+        req.ttl_s = 0.0
+    elif how == "close":
+        assert eng.close() == ([], [])
+    else:
+        eng.drain(ttl_s=-1.0)
+    if how in ("cancel", "ttl"):
+        assert eng.has_work
+        _, roots = _roots(eng.run)
+        # its pick in flight is thrown away, with the row that had
+        # chained on it where the expiry was seen a step late
+        assert 1 <= _total(roots, "rows_dropped") <= 2
+        assert other.generated == _seq_ref(gpt, [9, 8, 7, 6], 6)
+        assert eng.pool.free_blocks == eng.pool.num_blocks
+    else:
+        assert other.finish_reason == "drained"
+    assert done == [{"cancel": "cancelled", "ttl": "expired-ttl"}.get(
+        how, "drained")]
+    assert req.generated == had             # nothing after the end
+    assert not eng.has_work and eng._flight is None
+    assert eng.pool.check_leaks() == ([], [])
+
+
+def test_the_decode_program_is_traced_and_compiled_once(gpt):
+    """Host-fed rows, chained rows, rows of both kinds in one program and
+    a step that waits in place: one argument form, one executable."""
+    eng = LLMEngine(gpt, **KW)
+    traced, build = [], eng._build_decode
+
+    def counting(*a, **kw):
+        traced.append(1)
+        return build(*a, **kw)
+
+    eng._build_decode = counting
+    eng.generate_batch([[1, 2, 3]], max_new_tokens=3)
+    reqs = [eng.add_request([5, 6, 7], max_new_tokens=5)]
+    eng.step()
+    eng.step()
+    reqs.append(eng.add_request([4, 4], max_new_tokens=2, do_sample=True))
+    reqs.append(eng.add_request([9, 8, 7, 6], max_new_tokens=5))
+    eng.run()
+    assert all(r.finish_reason == "length" for r in reqs)
+    assert len(traced) == 1
+    assert eng._programs[("decode",)]._cache_size() == 1
+    assert eng.close() == ([], [])
+
+
+def test_the_decode_program_compiles_once_under_an_mp_mesh():
+    """Under the fleet mesh the program returns its ids replicated and
+    committed: the zeros that stand for them before the first step have
+    that form too, so the second call finds the first's executable."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+    mesh_mod.set_mesh(mesh_mod.build_mesh(mp=2))
+    try:
+        pt.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+            max_position_embeddings=64, hidden_dropout=0.0,
+            attention_dropout=0.0, tensor_parallel=True))
+        eng = LLMEngine(model, num_blocks=24, block_size=8, max_running=4,
+                        prefill_chunk=16)
+        prompts = [[1, 2, 3, 4, 5], [9, 8, 7]]
+        outs = eng.generate_batch(prompts, max_new_tokens=6)
+        assert eng._programs[("decode",)]._cache_size() == 1
+        assert outs == [_seq_ref(model, p, 6) for p in prompts]
+        assert eng.close() == ([], [])
+    finally:
+        mesh_mod.clear_mesh()
+
+
+def test_has_work_holds_until_the_last_token_is_emitted(gpt):
+    eng = LLMEngine(gpt, **KW)
+    req = eng.add_request([3, 1, 4, 1, 5], max_new_tokens=1)
+    first = eng.step()
+    # the one row is dispatched; its pick is still on the device
+    assert (first["decoded"], first["emitted"]) == (1, 0)
+    assert req.generated == [] and req.in_flight == 1 and eng.has_work
+    last = eng.step()
+    # nothing to dispatch (the pick in flight is the request's last):
+    # the step lands it
+    assert (last["decoded"], last["emitted"]) == (0, 1)
+    assert req.generated == _seq_ref(gpt, [3, 1, 4, 1, 5], 1)
+    assert req.finish_reason == "length" and not eng.has_work
+    # drain() delivers what is in flight too
+    reqs = [eng.add_request(p, max_new_tokens=4)
+            for p in ([5, 6, 7], [9, 8, 7, 6])]
+    eng.step()
+    eng.step()
+    assert eng.drain()["drained"] == 0
+    assert [r.generated for r in reqs] \
+        == [_seq_ref(gpt, p, 4) for p in ([5, 6, 7], [9, 8, 7, 6])]
+    assert eng.close() == ([], [])
 
 
 # ===================================================================
@@ -778,22 +1056,27 @@ def test_serving_aot_roundtrip_zero_compile(gpt, tmp_path):
         load_serving_artifacts(cold, str(tmp_path), strict=True)
 
 
-def test_serving_aot_of_another_program_layout_is_refused(gpt, tmp_path):
+@pytest.mark.parametrize("older", [None, 2], ids=["layout-1", "layout-2"])
+def test_serving_aot_of_another_program_layout_is_refused(gpt, tmp_path,
+                                                          older):
     """An artifact exported before the decode program returned its ids
-    (a manifest without `layout`) takes the same arguments and returns
-    other outputs: it is refused with the reason, and the live programs
-    serve."""
+    (a manifest without `layout`: the same arguments, other outputs), or
+    before it took `prev_ids` and `src` (layout 2: other arguments), is
+    refused with the reason, and the live programs serve."""
     import json
     prompts = [[1, 2, 3, 4, 5], [7] * 11]
     kw = dict(num_blocks=16, block_size=8, max_running=4,
               prefill_chunk=16)
     eng = LLMEngine(gpt, **kw)
     refs = eng.generate_batch(prompts, max_new_tokens=5)
-    assert export_serving_artifacts(eng, str(tmp_path))["layout"] == 2
+    assert export_serving_artifacts(eng, str(tmp_path))["layout"] == 3
     man = os.path.join(str(tmp_path), "serving_manifest.json")
     with open(man) as f:
         data = json.load(f)
-    del data["layout"]
+    if older is None:
+        del data["layout"]
+    else:
+        data["layout"] = older
     with open(man, "w") as f:
         json.dump(data, f)
     cold = LLMEngine(gpt, **kw)
@@ -802,7 +1085,7 @@ def test_serving_aot_of_another_program_layout_is_refused(gpt, tmp_path):
     assert cold.generate_batch(prompts, max_new_tokens=5) == refs
     assert ("decode",) in cold._programs
     from paddle_tpu.jit.save_load import AOTIncompatible
-    with pytest.raises(AOTIncompatible, match="layout 1"):
+    with pytest.raises(AOTIncompatible, match=f"layout {older or 1}"):
         load_serving_artifacts(cold, str(tmp_path), strict=True)
 
 

@@ -1,8 +1,12 @@
 """The spans `LLMEngine.step()` and `TrainStep.__call__` write to the
 recorder, with no `observability.enable()`: a step's phases nest inside
-its root and in order, every finished request leaves one
-`serving.request` with its marks in order, and the recorder's clock is
-the profiler's (checked against a real CPU profiler session)."""
+its root and in order (its own program's `prepare` and `dispatch`, then
+`wait`, `fetch` and `sample` of the program the step BEFORE dispatched:
+the engine runs one decode program ahead of the host), the root counts
+what it dispatched and, one step late, what it landed, every finished
+request leaves one `serving.request` with its marks in order, and the
+recorder's clock is the profiler's (checked against a real CPU profiler
+session)."""
 import glob
 import os
 
@@ -94,9 +98,12 @@ def test_children_lie_inside_their_root_and_in_order(served):
         for name in names:
             while name != want:
                 want = next(it)     # StopIteration = out of order
-        decode = [n for n in names if n.startswith("serving.decode")
-                  or n == "serving.sample"]
-        assert decode in ([], ORDER[3:])
+        # the two halves of the decode lane, each whole or absent: this
+        # step's program is built and dispatched; the program of the
+        # step BEFORE is waited for, fetched and emitted
+        dispatched = [n for n in names if n in ORDER[3:5]]
+        landed = [n for n in names if n in ORDER[5:]]
+        assert dispatched in ([], ORDER[3:5]) and landed in ([], ORDER[5:])
         assert names.count("serving.schedule") == 2
 
 
@@ -107,13 +114,19 @@ def test_the_root_counts_the_rows_it_decoded_and_a_prefill_its_chunk(
         counts = root[F["counts"]]
         assert sorted(counts) == ["decode_rows", "kv_blocks_live",
                                   "kv_blocks_walked", "logit_rows_fetched",
+                                  "rows_chained", "rows_dropped",
                                   "rows_picked_on_device"]
+        # the rows DISPATCHED by this step, of which those whose token
+        # was the pick of the program before, taken on the device
         rows = counts["decode_rows"]
         assert rows == summary["decoded"]
+        assert counts["rows_chained"] <= rows
         # every request is greedy: the program chose each row's token,
-        # and no step copied its logits
-        assert counts["rows_picked_on_device"] == rows
-        assert counts["logit_rows_fetched"] == 0
+        # and no step copied its logits; the count lands with the picks,
+        # on the step that fetched and emitted them.  No request stops
+        # early, so no pick is thrown away
+        assert counts["rows_picked_on_device"] == summary["emitted"]
+        assert counts["logit_rows_fetched"] == counts["rows_dropped"] == 0
         # every row lives in a block or more.  This model's heads are 8
         # wide, so the XLA fallback serves its decode steps, and that
         # gathers every column of every slot's table
@@ -131,6 +144,37 @@ def test_the_root_counts_the_rows_it_decoded_and_a_prefill_its_chunk(
         assert all(c[F["counts"]] == {} for c in kids
                    if c[F["name"]] != "serving.prefill")
         assert bool(summary["prefilled"]) == bool(chunks)
+
+
+def test_a_step_lands_the_program_of_the_step_before(served):
+    """The engine runs one decode program ahead of the host: a step
+    dispatches its own program FIRST and then waits for, fetches and
+    emits the picks of the program the step before dispatched, so the
+    counts of what was picked stand one step behind `decode_rows`."""
+    steps = _steps(served["recs"])
+    rows = [r[F["counts"]]["decode_rows"] for r, _ in steps]
+    picked = [r[F["counts"]]["rows_picked_on_device"] for r, _ in steps]
+    chained = [r[F["counts"]]["rows_chained"] for r, _ in steps]
+    assert picked[0] == 0 and rows[-1] == 0
+    assert picked[1:] == rows[:-1]
+    assert [s["emitted"] for s in served["summaries"]] == picked
+    assert sum(picked) == sum(rows) == NEW * len(LENS)
+    # a request's first row is fed by the host, every later one chained
+    assert sum(rows) - sum(chained) == len(LENS)
+    for (root, kids), before in zip(steps, [0] + rows[:-1]):
+        by_name = {c[F["name"]]: c for c in kids}
+        # what a step waits for is what the step before dispatched
+        assert ("serving.decode.wait" in by_name) == bool(before)
+        assert ("serving.decode.dispatch" in by_name) \
+            == bool(root[F["counts"]]["decode_rows"])
+        if "serving.decode.wait" in by_name \
+                and "serving.decode.dispatch" in by_name:
+            assert by_name["serving.decode.dispatch"][F["t1_ns"]] \
+                <= by_name["serving.decode.wait"][F["t0_ns"]]
+    # the last token of the run is delivered by a step that dispatches
+    # nothing: `has_work` held until then
+    assert served["summaries"][-1]["decoded"] == 0
+    assert served["summaries"][-1]["emitted"] > 0
 
 
 def test_where_the_kernel_serves_the_root_counts_its_ragged_walk(
