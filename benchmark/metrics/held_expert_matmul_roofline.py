@@ -1,0 +1,41 @@
+"""held_expert_matmul_roofline.* (%): the least time the chip could take
+for the grouped products of the HELD experts in the traced steps, over
+the summed device time of the grouped-matmul kernel's events (`gmm`).
+
+Work, from the traced steps' own spans, as the programs count it over
+the held experts: an assignment that fell on a held expert
+(``moe_assignments``, ``prefill_moe_assignments``) costs 2 FLOPs per
+weight of one expert and the rows it reads and writes; the weights read
+are those of the held experts TOUCHED (``experts_touched``,
+``prefill_experts_touched``).  Assignments to the other shares' experts
+are in no group and cost the kernel nothing.  The larger of FLOPs over
+the bf16 peak and bytes over the HBM peak; which binds is printed.
+Nothing matched gives nothing, never 0."""
+from benchmark import flops, flops_hybrid as fh, harness, trace
+from benchmark import program_spans as ps
+
+PATTERN = r"\bgmm\b"
+
+
+def read(run):
+    tr, got = run.get("trace"), ps.serving(run)
+    if not tr or not tr["devices"] or got is None:
+        return None
+    ops = tr["devices"][min(tr["devices"])]["ops"]
+    kernel_s = trace.named_sum_ns(ops, PATTERN) / 1e9
+    first = got["first_traced"]
+    assignments = touched = 0.0
+    for root, _ in got["steps"][first:first + got["n_traced"]]:
+        counts = root[ps.COUNTS]
+        assignments += counts.get("moe_assignments", 0) \
+            + counts.get("prefill_moe_assignments", 0)
+        touched += counts.get("experts_touched", 0) \
+            + counts.get("prefill_experts_touched", 0)
+    if kernel_s <= 0 or not assignments:
+        return None
+    least, binds = flops.roofline_seconds(
+        *fh.held_expert_work(run["config"], assignments, touched),
+        run["peaks"])
+    harness.say(f"{run['metric']}: {binds} binds, least {least * 1e3:.2f} "
+                f"ms of {kernel_s * 1e3:.2f} ms in the kernel")
+    return 100.0 * least / kernel_s

@@ -187,7 +187,7 @@ def moe_route(x, wg, bias, *, top_k, scoring="softmax", norm_topk=True,
     return picked.astype(jnp.int32), w * route_scale
 
 
-def moe_dropless(x, picked, weights, w_gate, w_up, w_down):
+def moe_dropless(x, picked, weights, w_gate, w_up, w_down, first=None):
     """SwiGLU experts over routed tokens with NO capacity: every one of
     the N x k assignments is computed whatever the imbalance.
 
@@ -199,17 +199,34 @@ def moe_dropless(x, picked, weights, w_gate, w_up, w_down):
     against its own matrix; `jax.lax.ragged_dot`, or the tiled kernel of
     ops/pallas/ on TPU), then unsorted and combined by `weights`.
     Shapes are static ([N * k, ...] rows whatever the routing), so one
-    program serves every routing.  Returns y [N, d] in x's dtype."""
+    program serves every routing.  Returns y [N, d] in x's dtype.
+
+    `first` makes the layer ONE SHARE of an expert-parallel layer: the
+    stacked weights are those of experts ``[first, first + E)`` of a
+    wider router.  Assignments to other experts are sorted behind the
+    local groups and belong to no group: the grouped products leave their
+    rows out, and they add nothing to y (what the absent shares would
+    add is theirs to compute)."""
     n, d = x.shape
     k = picked.shape[1]
     experts = w_gate.shape[0]
     flat = picked.reshape(-1)
+    if first is not None:
+        local = picked - first
+        here = (local >= 0) & (local < experts)
+        flat = jnp.where(here, local, experts).reshape(-1)
+        weights = weights * here
     order = jnp.argsort(flat)                  # stable: by expert, by token
     rows = x[order // k]                       # [N * k, d]
+    # (an index past the last group, another share's, is counted nowhere)
     sizes = jnp.bincount(flat, length=experts).astype(jnp.int32)
     h = jax.nn.silu(call_raw("grouped_matmul", rows, w_gate, sizes)) \
         * call_raw("grouped_matmul", rows, w_up, sizes)
     out = call_raw("grouped_matmul", h.astype(x.dtype), w_down, sizes)
+    if first is not None:
+        # a grouped product says nothing of the rows no group covers
+        out = jnp.where((jnp.arange(n * k) < jnp.sum(sizes))[:, None],
+                        out, 0)
     back = jnp.argsort(order)                  # the inverse permutation
     out = out[back].reshape(n, k, d)
     return jnp.einsum("nkd,nk->nd", out, weights.astype(out.dtype))
@@ -224,16 +241,30 @@ class DroplessMoE(Layer):
     this one layer, not subclasses.  ``forward(x)`` returns y; with
     ``live`` (a bool mask over the flattened tokens) it returns
     ``(y, load)`` where ``load`` [E] int32 counts the live tokens each
-    expert received (the serving engine's `experts_touched`)."""
+    expert received (the serving engine's `experts_touched`).
+
+    ``held=(first, count)`` makes the layer one chip's share of an
+    expert-parallel layer: the router keeps its `num_experts` outputs and
+    its `top_k`, the stacked weights are those of experts
+    ``[first, first + count)`` alone, y is the shared experts' result plus
+    the held experts' part for the assignments that fall on them, and
+    ``load`` [count] counts over the held experts.  The layer runs
+    without its exchange: nothing stands in for the absent shares."""
 
     def __init__(self, d_model, d_hidden, num_experts, top_k,
                  scoring="softmax", score_bias=False, norm_topk=True,
                  route_scale=1.0, num_shared=0, init_std=0.02,
-                 dtype="float32"):
+                 dtype="float32", held=None):
         super().__init__(dtype=dtype)
         if top_k > num_experts:
             raise ValueError(f"top_k={top_k} > num_experts={num_experts}")
         self.num_experts, self.top_k = int(num_experts), int(top_k)
+        self.held = None if held is None else (int(held[0]), int(held[1]))
+        if self.held and not (0 <= self.held[0] and self.held[1] >= 1
+                              and sum(self.held) <= self.num_experts):
+            raise ValueError(f"held={held} is no range of the "
+                             f"{num_experts} experts")
+        stacked = self.held[1] if self.held else self.num_experts
         self.scoring, self.norm_topk = scoring, bool(norm_topk)
         self.route_scale = float(route_scale)
         init = I.Normal(0.0, init_std)
@@ -246,9 +277,9 @@ class DroplessMoE(Layer):
         self.score_bias = self.create_parameter(
             [num_experts], is_bias=True,
             default_initializer=I.Constant(0.0)) if score_bias else None
-        self.w_gate = param(num_experts, d_model, d_hidden)
-        self.w_up = param(num_experts, d_model, d_hidden)
-        self.w_down = param(num_experts, d_hidden, d_model)
+        self.w_gate = param(stacked, d_model, d_hidden)
+        self.w_up = param(stacked, d_model, d_hidden)
+        self.w_down = param(stacked, d_hidden, d_model)
         self.num_shared = int(num_shared)
         if self.num_shared:
             width = self.num_shared * d_hidden
@@ -270,7 +301,8 @@ class DroplessMoE(Layer):
              "norm_topk": self.norm_topk, "route_scale": self.route_scale})
         y = engine.apply("moe_dropless", moe_dropless,
                          [x2, picked, weights, self.w_gate, self.w_up,
-                          self.w_down])
+                          self.w_down],
+                         {"first": self.held[0] if self.held else None})
         if self.num_shared:
             from ...nn import functional as F
             y = y + F.linear(F.silu(F.linear(x2, self.shared_gate))
@@ -279,12 +311,14 @@ class DroplessMoE(Layer):
         y = y.reshape(list(shape))
         if live is None:
             return y
+        first, experts = self.held or (0, self.num_experts)
         load = engine.apply(
             "moe_load",
-            lambda p, l, experts: jnp.sum(
-                (p[:, :, None] == jnp.arange(experts, dtype=p.dtype))
+            lambda p, l, first, experts: jnp.sum(
+                (p[:, :, None] - first
+                 == jnp.arange(experts, dtype=p.dtype))
                 & l[:, None, None], axis=(0, 1), dtype=jnp.int32),
-            [picked, live], {"experts": self.num_experts})
+            [picked, live], {"first": first, "experts": experts})
         return y, load
 
 

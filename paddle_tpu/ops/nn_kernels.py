@@ -7,6 +7,8 @@ that ops/pallas/flash_attention.py overrides on TPU.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -458,6 +460,112 @@ def grouped_matmul_k(rows, weights, group_sizes):
     is m) -> [m, n].  XLA's own ragged product; ops/pallas/ overrides it
     with a tiled grouped-matmul kernel on TPU."""
     return jax.lax.ragged_dot(rows, weights, group_sizes)
+
+
+# ------------------------------------- gated delta rule (KDA token mixer)
+_KDA_CHUNK = 64
+
+
+def _kda_inverse(n):
+    """(I + n)^-1 of a strictly lower triangular [..., C, C] as products:
+    with m = -n nilpotent, (I + m)(I + m^2)(I + m^4)... holds every power
+    below C after log2(C) factors."""
+    c = n.shape[-1]
+    eye = jnp.eye(c, dtype=n.dtype)
+    power = -n
+    inv = eye + power
+    for _ in range(max(0, (c - 1).bit_length() - 1)):
+        power = jnp.matmul(power, power, precision="highest")
+        inv = inv + jnp.matmul(inv, power, precision="highest")
+    return inv
+
+
+@register("kda_chunk")
+def kda_chunk_k(q, k, v, g, beta, state, n_valid=None, chunk=_KDA_CHUNK):
+    """Kimi Delta Attention (arXiv:2510.26692) over a run of positions in
+    its chunkwise form.  Per head, with ``a_t = exp(g_t)`` per channel:
+
+        S_t = (I - beta_t k_t k_t^T) Diag(a_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    q, k [b, T, H, dk] (normalised and scaled by the caller), v
+    [b, T, H, dv], g [b, T, H, dk] (log decay, <= 0), beta [b, T, H],
+    state [b, H, dk, dv] float32 -> (o [b, T, H, dv] float32, the state
+    after the run).  Positions at or past ``n_valid[b]`` are inert
+    (a = 1, beta = 0): the state does not move past them.
+
+    Chunks of `chunk` positions: inside one, the pseudo-values
+    ``u_t = beta_t (v_t - S~_t^T k_t)`` solve the unit lower triangular
+    system ``(I + Diag(beta) A) U = Diag(beta)(V - (K . Gamma) S_0)`` with
+    ``A[t, i] = sum_c k_t[c] k_i[c] exp(G_t[c] - G_i[c])`` (i < t, G the
+    running sum of g), solved as products (`_kda_inverse`); the state
+    moves once a chunk.  Every exponent is a difference G_t - G_i with
+    i <= t, never a bare exp(-G): per-channel decays reach exp(-500)
+    inside a chunk.  All in float32 at `highest` matmul precision."""
+    f32 = jnp.float32
+    b, t, h, dk = k.shape
+    dv = v.shape[-1]
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    if n_valid is not None:
+        real = jnp.arange(t, dtype=jnp.int32)[None, :] \
+            < n_valid.astype(jnp.int32)[:, None]
+        g = jnp.where(real[:, :, None, None], g, 0.0)
+        beta = jnp.where(real[:, :, None], beta, 0.0)
+    c = min(int(chunk), t)
+    pad = -t % c
+    if pad:
+        q, k, v, g = (jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                      for a in (q, k, v, g))
+        beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+    n = (t + pad) // c
+
+    def chunks(a):      # [b, n * c, H, ...] -> [n, b, H, c, ...]
+        a = a.reshape((b, n, c, h) + a.shape[3:])
+        return jnp.moveaxis(jnp.swapaxes(a, 2, 3), 1, 0)
+
+    strict = jnp.tril(jnp.ones((c, c), bool), -1)
+    mm = functools.partial(jnp.matmul, precision="highest")
+
+    def one(s0, xs):
+        qc, kc, vc, gc, bc = xs             # [b, H, c, d]; bc [b, H, c]
+        gsum = jnp.cumsum(gc, axis=2)
+        decay = jnp.exp(jnp.minimum(
+            gsum[:, :, :, None, :] - gsum[:, :, None, :, :], 0.0))
+        kk = jnp.sum(kc[:, :, :, None, :] * kc[:, :, None, :, :] * decay, -1)
+        qk = jnp.sum(qc[:, :, :, None, :] * kc[:, :, None, :, :] * decay, -1)
+        inv = _kda_inverse(jnp.where(strict, bc[..., None] * kk, 0.0))
+        grow = jnp.exp(gsum)
+        u = mm(inv, bc[..., None] * (vc - mm(kc * grow, s0)))
+        o = mm(qc * grow, s0) + mm(
+            jnp.where(strict | jnp.eye(c, dtype=bool), qk, 0.0), u)
+        last = gsum[:, :, -1:, :]
+        s1 = jnp.swapaxes(jnp.exp(last), 2, 3) * s0 + mm(
+            jnp.swapaxes(kc * jnp.exp(last - gsum), 2, 3), u)
+        return s1, o
+
+    state, o = jax.lax.scan(one, state.astype(f32),
+                            tuple(chunks(a) for a in (q, k, v, g, beta)))
+    o = jnp.swapaxes(jnp.moveaxis(o, 0, 1), 2, 3).reshape(b, n * c, h, dv)
+    return o[:, :t], state
+
+
+@register("kda_step")
+def kda_step_k(q, k, v, g, beta, state, slots, live):
+    """One position of the recurrence of `kda_chunk` for every row of a
+    decode program, over the pool of states, addressed by slot: q, k, g
+    [R, H, dk], v [R, H, dv], beta [R, H], state [S, H, dk, dv] float32,
+    slots [R] int32, live [R] bool -> (o [R, H, dv] float32, the pool).
+    A row that is not live reads its slot and writes nothing.  The XLA
+    form gathers, updates and scatters (ops/pallas/kda.py updates the
+    pool in place on TPU)."""
+    f32 = jnp.float32
+    q, k, v, g, beta = (a.astype(f32) for a in (q, k, v, g, beta))
+    s0 = state[slots] * jnp.exp(g)[..., None]
+    delta = beta[..., None] * (v - jnp.sum(k[..., None] * s0, axis=-2))
+    s1 = s0 + k[..., None] * delta[..., None, :]
+    o = jnp.sum(q[..., None] * s1, axis=-2)
+    at = jnp.where(live, slots.astype(jnp.int32), state.shape[0])
+    return o, state.at[at].set(s1.astype(state.dtype), mode="drop")
 
 
 # ------------------------------------------------------------------ losses

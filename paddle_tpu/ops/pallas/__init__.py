@@ -19,6 +19,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..dispatch import get, override
 from . import flash_attention as _fa
+from . import kda as _kda
 from . import latent_paged_attention as _la
 from . import paged_attention as _pa
 
@@ -297,3 +298,22 @@ def grouped_matmul_with_pallas(rows, weights, group_sizes):
 
 
 override("grouped_matmul", grouped_matmul_with_pallas)
+
+
+_xla_kda_step = get("kda_step").fn
+
+
+def kda_step_with_pallas(q, k, v, g, beta, state, slots, live):
+    """On TPU a decode step's recurrent states are updated where they lie
+    in the pool (ops/pallas/kda.py); the XLA gather-update-scatter keeps
+    everything else (CPU, a fleet mesh, head sizes other than 128)."""
+    mode = _mode()
+    if mode is None or _mesh_split() is not None \
+            or state.dtype != jnp.float32 \
+            or not _kda.supports(q.shape, state.shape):
+        return _xla_kda_step(q, k, v, g, beta, state, slots, live)
+    return _kda.kda_decode_step(q, k, v, g, beta, state, slots, live,
+                                interpret=(mode == "interpret"))
+
+
+override("kda_step", kda_step_with_pallas)

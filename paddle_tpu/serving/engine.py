@@ -83,7 +83,8 @@ class LLMEngine:
         self.model = model
         model.eval()
         self.pool = BlockPool.for_model(model, num_blocks,
-                                        block_size=block_size, dtype=dtype)
+                                        block_size=block_size, dtype=dtype,
+                                        slots=max_running)
         sharded = self.pool.shard_()
         self.scheduler = Scheduler(self.pool, max_running=max_running,
                                    promote_after=promote_after)
@@ -117,7 +118,12 @@ class LLMEngine:
         self._pn, self._p_arrays, self._bn, self._b_arrays = \
             FB.split_state(model)
         self._programs = {}     # key -> live jitted program
-        self._chunk_loads = []  # routed layers' load of chunks, unread yet
+        # routed layers' load of chunks, unread yet, each with its tokens
+        self._chunk_loads = []
+        # experts a token is sent to, where the model routes: with it the
+        # step counts the assignments ROUTED beside those its (held)
+        # experts received
+        self._top_k = getattr(model.cfg, "num_experts_per_tok", None)
         # the decode program dispatched and not landed yet (its picks
         # are emitted by the next step), and the newest program's `ids`
         # ON THE DEVICE, from which the next one takes its chained rows'
@@ -347,6 +353,14 @@ class LLMEngine:
             root.counts.update(decode_rows=len(ready), rows_chained=chained,
                                kv_blocks_live=live, kv_blocks_walked=walked,
                                **landed)
+            if self.pool.slots:
+                # the decode rows' recurrent state, read and written once
+                # by this step's program
+                in_use = self.pool.slots - self.pool.free_slots
+                self._reg.gauge("serving_state_slots_in_use").set(in_use)
+                root.counts.update(
+                    state_slots_live=len(ready),
+                    state_bytes_rw=2 * self.pool.state_bytes() * len(ready))
         return {"admitted": len(admitted), "decoded": len(ready),
                 "emitted": emitted, "prefilled": prefilled,
                 "running": len(sched.running),
@@ -456,14 +470,17 @@ class LLMEngine:
         non-donating build."""
         return jax.default_backend() != "cpu"
 
-    def _caches(self, planes, tables, pos, limit):
-        """One cache dict per layer over the pool's planes, as the
-        models' paged branches read them."""
+    def _caches(self, planes, tables, pos, limit, slots=()):
+        """One cache dict per layer over the planes that layer has, as
+        the models' paged branches read them: the rows' block tables
+        and, where the pool holds per-request planes, their slots."""
+        shared = dict(table=Tensor._from_array(tables),
+                      pos=Tensor._from_array(pos),
+                      limit=Tensor._from_array(limit),
+                      **{"slot": Tensor._from_array(a) for a in slots})
         return [dict({name: Tensor._from_array(arrays[i])
-                      for name, arrays in planes.items()},
-                     table=Tensor._from_array(tables),
-                     pos=Tensor._from_array(pos),
-                     limit=Tensor._from_array(limit))
+                      for name, arrays in planes.items()
+                      if arrays[i] is not None}, **shared)
                 for i in range(self.pool.num_layers)]
 
     @staticmethod
@@ -472,7 +489,8 @@ class LLMEngine:
         written},) and, where routed layers left their load (the real
         tokens each expert received), the stack [routed layers, experts]
         of the first `layers` layers' behind it."""
-        out = ({name: [c[name]._array for c in caches] for name in planes},)
+        out = ({name: [c[name]._array if name in c else None
+                       for c in caches] for name in planes},)
         load = [c["expert_load"]._array for c in caches[:layers]
                 if "expert_load" in c]
         return out + ((jnp.stack(load),) if load else ())
@@ -481,13 +499,13 @@ class LLMEngine:
         model, pn, bn = self.model, self._pn, self._bn
 
         def pure(p_arrays, b_arrays, planes, tables, pos, tokens, limit,
-                 prev_ids, src):
+                 prev_ids, src, *slots):
             # a chained row's token is the pick of row `src` of the
             # decode program before this one, read where it lies: the
             # host has not seen it yet.  src < 0: the host's `tokens`
             tokens = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)],
                                tokens)
-            caches = self._caches(planes, tables, pos, limit)
+            caches = self._caches(planes, tables, pos, limit, slots)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
                     logits = model(Tensor._from_array(tokens[:, None]),
@@ -507,8 +525,9 @@ class LLMEngine:
     def _build_prefill(self, donate=None):
         model, pn, bn = self.model, self._pn, self._bn
 
-        def pure(p_arrays, b_arrays, planes, table, pos, tokens, limit):
-            caches = self._caches(planes, table, pos, limit)
+        def pure(p_arrays, b_arrays, planes, table, pos, tokens, limit,
+                 *slots):
+            caches = self._caches(planes, table, pos, limit, slots)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
                     model(Tensor._from_array(tokens), caches=caches)
@@ -517,7 +536,9 @@ class LLMEngine:
             # code XLA prunes, and with it all of the LAST layer but the
             # rows it caches.  That layer's load is left out, so that its
             # attention and routing stay dead: a chunk's counts are those
-            # of the products that run
+            # of the products that run.  (A last layer that carries a
+            # recurrent state keeps its token mixer: the state it writes
+            # is returned; its expert layer stays dead)
             return self._written(caches, planes, layers=-1)
 
         donate = self._donate_pools() if donate is None else donate
@@ -553,19 +574,22 @@ class LLMEngine:
         s = jax.ShapeDtypeStruct
         p = [s(a.shape, a.dtype) for a in self._p_arrays]
         b = [s(a.shape, a.dtype) for a in self._b_arrays]
-        planes = {name: [s(a.shape, a.dtype) for a in arrays]
+        planes = {name: [a if a is None else s(a.shape, a.dtype)
+                         for a in arrays]
                   for name, arrays in self.pool.planes.items()}
         i32 = np.int32
+        # a pool with per-request planes: the rows' slots ride last
+        slots = lambda rows: (s((rows,), i32),) if self.pool.slots else ()
         if key[0] == "decode":
             R, M = self.max_running, self.table_cols
             return functools.partial(self._build_decode, donate=False), (
                 p, b, planes, s((R, M), i32), s((R,), i32), s((R,), i32),
-                s((R,), i32), s((R,), i32), s((R,), i32))
+                s((R,), i32), s((R,), i32), s((R,), i32)) + slots(R)
         if key[0] == "prefill":
             Lb = int(key[1])
             return functools.partial(self._build_prefill, donate=False), (
                 p, b, planes, s((1, self.table_cols), i32), s((1,), i32),
-                s((1, Lb), i32), s((1,), i32))
+                s((1, Lb), i32), s((1,), i32)) + slots(1)
         raise KeyError(f"unknown serving program key {key!r}")
 
     # ------------------------------------------------------------- prefill
@@ -582,15 +606,17 @@ class LLMEngine:
             table[0, :len(req.block_table)] = req.block_table
             pos = np.asarray([req.ctx], np.int32)
             limit = np.asarray([req.ctx + n], np.int32)
+            slots = () if req.state_slot is None \
+                else (np.asarray([req.state_slot], np.int32),)
             self.pool.planes, *load = self._run_program(
                 ("prefill", bucket), self._build_prefill,
                 self._p_arrays, self._b_arrays, self.pool.planes,
-                table, pos, tokens, limit)
+                table, pos, tokens, limit, *slots)
             for a in load:
                 # read when a decode's fetch has next waited for the
                 # device (step()): a chunk never waits for its own
                 a.copy_to_host_async()
-                self._chunk_loads.append(a)
+                self._chunk_loads.append((a, n))
         req.ctx += n
         self._reg.counter("serving_prefill_tokens_total").inc(n)
 
@@ -618,6 +644,15 @@ class LLMEngine:
                     src[i] = req.slot
                 else:
                     tokens[i] = req.feed_tokens()[req.ctx]
+            slots = ()
+            if self.pool.slots:
+                # every row a slot of its own: the live rows theirs, the
+                # dead rows those no live row holds (which they leave as
+                # they are), so that the step can update the pool in place
+                taken = [r.state_slot for r in ready]
+                spare = sorted(set(range(self.pool.slots)) - set(taken))
+                slots = (np.asarray(taken + spare[:R - len(taken)],
+                                    np.int32),)
         # the logits are copied only for a row that draws its token on
         # the host; a greedy step leaves them on the device
         sampled = any(r.do_sample for r in ready)
@@ -626,7 +661,7 @@ class LLMEngine:
             logits, ids, finite, self.pool.planes, *load = self._run_program(
                 ("decode",), self._build_decode,
                 self._p_arrays, self._b_arrays, self.pool.planes,
-                tables, pos, tokens, limit, self._prev_ids, src)
+                tables, pos, tokens, limit, self._prev_ids, src, *slots)
             self._prev_ids = ids
             # the copies are queued behind the program at once: left to
             # start after a wait has returned they cost the step 0.2 ms
@@ -684,7 +719,10 @@ class LLMEngine:
                     # surplus, and the host feeds the position again
                     self._flight.rows[req.slot] = None
                     req.in_flight -= 1
-                    req.ctx -= 1
+                    # a recurrent state cannot step back: it is built
+                    # again from the first position
+                    req.ctx = 0 if req.state_slot is not None \
+                        else req.ctx - 1
         if flight.host is not None:
             landed["logit_rows_fetched"] += self.max_running
         else:
@@ -695,9 +733,14 @@ class LLMEngine:
             landed["moe_assignments"] += int(flight.load.sum())
             landed["experts_touched"] += int((flight.load > 0).sum())
             landed["prefill_moe_assignments"] += sum(
-                int(a.sum()) for a in flight.chunks)
+                int(a.sum()) for a, _ in flight.chunks)
             landed["prefill_experts_touched"] += sum(
-                int((a > 0).sum()) for a in flight.chunks)
+                int((a > 0).sum()) for a, _ in flight.chunks)
+            if self._top_k:
+                landed["moe_assignments_routed"] += len(flight.rows) \
+                    * self._top_k * len(flight.load)
+                landed["prefill_moe_assignments_routed"] += sum(
+                    n * self._top_k * len(a) for a, n in flight.chunks)
 
     def _emit(self, req, logits_row, now):
         """The hook a decoded row goes through: `logits_row` is the
@@ -798,7 +841,7 @@ class _Flight:
         if self.sampled:
             self.logits_rows()
         self.load = np.asarray(self.load[0]) if self.load else None
-        self.chunks = [np.asarray(a) for a in self.chunks]
+        self.chunks = [(np.asarray(a), n) for a, n in self.chunks]
 
     def logits_rows(self):
         if self.host is None:

@@ -90,6 +90,9 @@ class Request:
         # local TTFT observation is still suppressed
         self.resumed = resume_tokens is not None
         self.block_table = []       # pool block ids, position-ordered
+        # the request's entry in the pool's per-request planes (a
+        # recurrent state), where the model has any
+        self.state_slot = None
         self.ctx = 0                # tokens whose K/V live in the pool
         # picks the engine has dispatched and not emitted yet (it runs
         # one decode program ahead of the host), and this request's row
@@ -196,6 +199,11 @@ class Scheduler:
         admitted = []
         while self.waiting and len(self.running) < self.max_running:
             req = self.waiting[0]
+            if self.pool.slots and not self.pool.free_slots:
+                from ..observability import metrics as _metrics
+                _metrics.registry().counter(
+                    "serving_state_slot_waits_total").inc()
+                break
             # blocks for the whole prefix to re/prefill plus one decode
             # token, so admission can't strand a request mid-prefill
             need = self.pool.blocks_for(req.feed_len + 1)
@@ -209,6 +217,7 @@ class Scheduler:
                 break
             self.waiting.popleft()
             req.block_table = blocks
+            req.state_slot = self.pool.allocate_slot()
             req.ctx = 0
             req.state = RUNNING
             self.running.append(req)
@@ -257,14 +266,23 @@ class Scheduler:
             _metrics.registry().counter(
                 "serving_starvation_promotions_total").inc()
 
+    def _release(self, req):
+        """The request's blocks and its state slot go home."""
+        if req.block_table:
+            self.pool.free(req.block_table)
+            req.block_table = []
+        if req.state_slot is not None:
+            self.pool.free_slot(req.state_slot)
+            req.state_slot = None
+
     def preempt(self, req):
-        """Evict: free every block now, requeue at the FRONT; the prefix
-        (prompt + generated so far) re-prefills on readmission."""
+        """Evict: free every block and the state slot now, requeue at the
+        FRONT; the prefix (prompt + generated so far) re-prefills on
+        readmission, a recurrent state from zero."""
         from ..observability import metrics as _metrics
         _metrics.registry().counter(
             "serving_requests_preempted_total").inc()
-        self.pool.free(req.block_table)
-        req.block_table = []
+        self._release(req)
         req.ctx = 0
         req.preemptions += 1
         req.state = PREEMPTED
@@ -274,9 +292,7 @@ class Scheduler:
         self.waiting.appendleft(req)
 
     def finish(self, req, reason):
-        if req.block_table:
-            self.pool.free(req.block_table)
-            req.block_table = []
+        self._release(req)
         if reason in ("eos", "length"):
             req.state = FINISHED
         elif reason == "error" or reason == "cancelled":

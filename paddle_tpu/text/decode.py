@@ -12,6 +12,8 @@ which is what lets XLA compile the loop.
 """
 from __future__ import annotations
 
+import collections
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -70,6 +72,14 @@ def kv_cache_planes(cfg):
     hd = cfg.hidden_size // cfg.num_heads
     hkv = getattr(cfg, "num_kv_heads", None) or cfg.num_heads
     return [{"k": (hkv, hd), "v": (hkv, hd)}] * cfg.num_layers
+
+
+class StatePlane(collections.namedtuple("StatePlane", "shape dtype")):
+    """A plane a layer caches per REQUEST, not per token (a recurrent
+    state, a convolution's tail): its shape, and its dtype where that is
+    not the pool's (None = the pool's).  `cache_planes()` names it where
+    a per-token plane gives its trailing shape."""
+    __slots__ = ()
 
 
 def _update_paged_cache(cache, k, v):

@@ -120,6 +120,40 @@ def test_dropless_experts_compile_as_grouped_products(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < 40 * rows * 2048
 
 
+def test_kda_decode_step_compiles_and_updates_the_pool_in_place(one_chip):
+    """The delta-rule step at the hybrid cell's widths (64 rows, 64 heads
+    of 128 x 128 float32 state, 64 slots): a Mosaic kernel whose state
+    output aliases the donated pool, with no temporary the size of it."""
+    from paddle_tpu.ops.pallas import kda
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    f32 = jnp.float32
+    vec, pool = s((64, 64, 128), f32), s((64, 64, 128, 128), f32)
+    assert kda.supports(vec.shape, pool.shape)
+    compiled = jax.jit(kda.kda_decode_step, donate_argnums=(5,)).lower(
+        vec, vec, vec, vec, s((64, 64), f32), pool, s((64,), jnp.int32),
+        s((64,), jnp.bool_)).compile()
+    assert KERNEL in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 64 * 64 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 * 64 * 128 * 4 * 8
+
+
+@pytest.mark.parametrize("rows", [512, 4096], ids=["decode", "chunk"])
+def test_a_share_of_the_experts_compiles_as_grouped_products(one_chip, rows):
+    """`moe_dropless(first=0)` over 40 held experts of 4096 x 1280 under a
+    router of 320, 8 a token: the rows no group covers are masked, the
+    products stay grouped."""
+    from paddle_tpu.incubate.nn import moe_dropless
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    n = rows // 8
+    compiled = jax.jit(functools.partial(moe_dropless, first=0)).lower(
+        s((n, 4096), bf16), s((n, 8), jnp.int32), s((n, 8), jnp.float32),
+        s((40, 4096, 1280), bf16), s((40, 4096, 1280), bf16),
+        s((40, 1280, 4096), bf16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 40 * rows * 4096
+
+
 def test_supports_refuses_what_the_compiler_refuses(one_chip):
     """float16: Mosaic has no f16 vector load on this chip — supports()
     must say so, for both kernels, and the compiler must agree."""
