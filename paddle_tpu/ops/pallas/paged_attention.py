@@ -23,19 +23,31 @@ engine's spans).  On the v5e a 16-token block of 16 heads costs 0.27 us
 in a chunk of 8 against 0.41 us copied alone (PERF.md, PR 26).
 
 Shapes: the pool keeps heads in the second-minor position, so a block
-`(bs, Hkv, D)` lands in VMEM as it lies in the pool (token on the
-leading axis, head on sublanes, D on lanes) and no block is transposed.
-With one query token there is no matmul worth the MXU: scores and the
-p·v sum are broadcast-multiply-reduce on the VPU in float32, block by
-block, the running max, sum and accumulator carried in registers.
+`(bs, Hkv, D)` is the matrix [(token, kv head), D] as it lies in the
+pool (the wrapper's reshape is a bitcast), lands in VMEM so, D on
+lanes, and no block is transposed.  A copied chunk, the matrix [(block,
+token, kv head), D], is reduced whole by two matrix products on the MXU
+with ALL H query heads, `[H, D] . [D, cols]` and `[2 H, cols] . [cols,
+D]`, block-diagonal over the kv heads: a column of another head's kv
+head is masked to -inf with the positions past the row's length, so the
+MXU does Hkv times the useful multiplies and nothing is re-laid.  bf16
+x bf16 products are summed in float32; p stays float32 in effect (its
+bfloat16 rounding and the remainder ride the one product against V as
+2 H rows), and a float32 pool takes its products at the highest
+precision.  The body this replaced in PR 33 scored a block a query head
+at a time with two broadcast-multiply-reduces on the VPU; on the v5e,
+kernel alone, ms a layer then / now (PERF.md, PR 33): 64 heads over 8
+bfloat16, 64 rows, ~6,200 live blocks (the hybrid cell) 2.63 / 0.71;
+32 over 8, 32 rows, ~760 blocks 0.245 / 0.194; 16 over 16 (the dense
+cells) 0.224 / 0.201; only a float32 pool at ONE query head a kv head,
+which no cell serves, lost (0.293 / 0.314).
 
 Decode-only (q seq len 1) and lane-aligned head dims only (D % 128 ==
 0; the pool is the replica's whole KV memory, so in-call padding would
 copy it per layer per step): prefill chunks and other head dims keep
 the XLA gather fallback, whose masked-sdpa math is the parity
-reference.  GQA: q head h reads kv head h // (H // Hkv); q is laid out
-[B, g, Hkv, D] for the kernel (a transpose of the one q token, never
-of the pool) so each group member is one (Hkv, D) tile.
+reference.  GQA: q head h reads kv head h // (H // Hkv); q goes in
+as [B, H, D], the model's own head order.
 """
 from __future__ import annotations
 
@@ -57,9 +69,11 @@ def chunk_blocks(table_cols, block_size, kv_heads, head_dim, dtype):
     """Pool blocks the kernel copies per step of its walk (`C`): as
     many as make one operand's copy about half a MiB, so that a step's
     DMAs are worth their set-up, while K and V, double-buffered, stay a
-    quarter of the 16 MiB of VMEM a kernel may scope.  In VMEM the head
-    axis is padded to whole sublane tiles, which binds where GQA or an
-    `mp` shard leaves few kv heads."""
+    quarter of the 16 MiB of VMEM a kernel may scope.  The second bound
+    counts the head axis padded to whole sublane tiles, as a block
+    `(bs, Hkv, D)` lay in VMEM until PR 33; as the matrix [(token, kv
+    head), D] it is padded only where bs x Hkv is short of a tile, so
+    the bound is kept and spare."""
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * 4 // itemsize
     row = block_size * head_dim * itemsize
@@ -116,11 +130,25 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     length = visible(b)
     n_chunks = pl.cdiv(blocks(b), chunk)
-    hkv, d = q_ref.shape[2:]
-    qs = [q_ref[0, gi].astype(jnp.float32) for gi in range(g)]  # (Hkv, D)
+
+    # k_buf: (2, chunk, bs * Hkv, D), a chunk the matrix [(block, token,
+    # kv head), D]; q: (H, D).  `q . K^T` scores every query head
+    # against every kv head's rows; the columns of another head's kv
+    # head, and those past the row's length, are -inf before the running
+    # max, so their p is exactly 0 and `p . V` sums a head's own rows
+    heads, d = q_ref.shape[1:]
+    hkv = heads // g
+    per_block = bs * hkv
+    width = chunk * per_block
+    q = q_ref[0]
+    exact = k_buf.dtype == jnp.float32
+    precision = lax.Precision.HIGHEST if exact else None
+    col = lax.broadcasted_iota(jnp.int32, (heads, width), 1)
+    own = col % hkv == lax.broadcasted_iota(
+        jnp.int32, (heads, width), 0) // g  # column (.., kv head) of a head
 
     def reduce_chunk(i, carry):
-        slot, state = carry
+        slot, m_prev, l_prev, acc = carry
         # the next chunk flies while this one is reduced: this row's,
         # or after its last the next row's first
         last = i + 1 == n_chunks
@@ -132,33 +160,48 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         n = copies(b, i, slot, lambda dma: dma.wait())
 
-        def reduce_block(c, state):
-            k = k_buf[slot, c].astype(jnp.float32)         # (bs, Hkv, D)
-            v = v_buf[slot, c].astype(jnp.float32)
-            pos = (i * chunk + c) * bs + lax.broadcasted_iota(
-                jnp.int32, (bs, hkv, 1), 0)
-            live = pos < length                            # (bs, Hkv, 1)
-            out = []
-            for q, (m_prev, l_prev, acc) in zip(qs, state):
-                s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
-                s = jnp.where(live, s, _NEG_INF)           # (bs, Hkv, 1)
-                m_new = jnp.maximum(m_prev, s.max(axis=0))  # (Hkv, 1)
-                p = jnp.exp(s - m_new[None])               # masked -> 0
-                corr = jnp.exp(m_prev - m_new)
-                out.append((m_new, l_prev * corr + p.sum(axis=0),
-                            acc * corr + jnp.sum(p * v, axis=0)))
-            return tuple(out)
+        # blocks of the chunk that no copy wrote hold what the scratch
+        # held: their p is 0, and 0 x NaN is NaN in a product
+        @pl.when(n < chunk)
+        def _clear():
+            def one(c, _):
+                v_buf[slot, c] = jnp.zeros((per_block, d), v_buf.dtype)
+                return _
+            lax.fori_loop(n, chunk, one, 0)
 
-        return 1 - slot, lax.fori_loop(0, n, reduce_block, state)
+        k = k_buf[slot].reshape(width, d)
+        v = v_buf[slot].reshape(width, d)
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            precision=precision,
+                            preferred_element_type=jnp.float32) * scale
+        live = own & (col < (length - i * chunk * bs) * hkv)
+        s = jnp.where(live, s, _NEG_INF)                    # (H, width)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)                              # masked -> 0
+        corr = jnp.exp(m_prev - m_new)
+        if exact:
+            pv = jnp.dot(p, v, precision=precision,
+                         preferred_element_type=jnp.float32)
+        else:
+            # p keeps 16 bits of its mantissa: its bfloat16 rounding and
+            # the rounding's remainder ride ONE product against V as 2 H
+            # rows, and the halves are added
+            hi = p.astype(v.dtype)
+            lo = (p - hi.astype(jnp.float32)).astype(v.dtype)
+            pv = jnp.dot(jnp.concatenate([hi, lo], axis=0), v,
+                         preferred_element_type=jnp.float32)
+            pv = pv[:heads] + pv[heads:]
+        return (1 - slot, m_new,
+                l_prev * corr + p.sum(axis=1, keepdims=True),
+                acc * corr + pv)
 
-    init = tuple((jnp.full((hkv, 1), _NEG_INF, jnp.float32),
-                  jnp.zeros((hkv, 1), jnp.float32),
-                  jnp.zeros((hkv, d), jnp.float32)) for _ in qs)
-    slot, state = lax.fori_loop(0, n_chunks, reduce_chunk,
-                                (slot_s[0], init))
+    slot, _, l, acc = lax.fori_loop(
+        0, n_chunks, reduce_chunk,
+        (slot_s[0], jnp.full((heads, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((heads, 1), jnp.float32),
+         jnp.zeros((heads, d), jnp.float32)))
     slot_s[0] = slot
-    for gi, (_, l, acc) in enumerate(state):
-        o_ref[0, gi] = (acc / l).astype(o_ref.dtype)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lens, scale=None,
@@ -193,14 +236,18 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
     N, bs, Hkv, _ = k_pool.shape
     M = tables.shape[1]
     g = H // Hkv
-    # head h = hk * g + gi  ->  [B, g, Hkv, D]
-    qb = q.reshape(B, Hkv, g, D).swapaxes(1, 2)
     chunk = chunk_blocks(M, bs, Hkv, D, k_pool.dtype)
+    # a block as the matrix [(token, kv head), D] it already is in
+    # memory, and q in the model's own head order
+    qb = q[:, 0]
+    k_pool = k_pool.reshape(N, bs * Hkv, D)
+    v_pool = v_pool.reshape(N, bs * Hkv, D)
+    block = (bs * Hkv, D)
 
     kernel = functools.partial(_decode_kernel, bs=bs, chunk=chunk, g=g,
                                scale=scale)
     q_spec = pl.BlockSpec(
-        (1, g, Hkv, D), lambda b, tables_ref, lens_ref: (b, 0, 0, 0))
+        (1, H, D), lambda b, tables_ref, lens_ref: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -208,8 +255,8 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
         in_specs=[q_spec, pool_spec, pool_spec],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((2, chunk, bs, Hkv, D), k_pool.dtype),
-            pltpu.VMEM((2, chunk, bs, Hkv, D), v_pool.dtype),
+            pltpu.VMEM((2, chunk) + block, k_pool.dtype),
+            pltpu.VMEM((2, chunk) + block, v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ],
@@ -217,7 +264,7 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, g, Hkv, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qb.shape, q.dtype),
         # a row hands the next its first chunk in flight: rows in order
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -225,7 +272,7 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
         name="paged_decode_attention",
     )(tables.astype(jnp.int32), lens.astype(jnp.int32),
       qb, k_pool, v_pool)
-    return out.swapaxes(1, 2).reshape(B, 1, H, D)
+    return out.reshape(B, 1, H, D)
 
 
 def supports(q_shape, pool_shape, dtype, mp=1):

@@ -67,16 +67,21 @@ def test_flash_fwd_bwd_compiles(one_chip, H, Hkv):
 
 @pytest.mark.parametrize("dtype,H,Hkv,B,N,M", [
     (jnp.bfloat16, 16, 16, 8, 280, 34), (jnp.float32, 16, 16, 8, 280, 34),
-    (jnp.bfloat16, 12, 2, 8, 280, 34),
+    (jnp.bfloat16, 12, 2, 8, 280, 34), (jnp.bfloat16, 6, 1, 8, 280, 34),
     (jnp.bfloat16, 16, 16, 32, 2600, 128),
     (jnp.float32, 16, 16, 32, 2600, 128),
-    (jnp.bfloat16, 32, 8, 32, 2600, 128)],
+    (jnp.bfloat16, 32, 8, 32, 2600, 128),
+    (jnp.bfloat16, 64, 8, 64, 16700, 512)],
     ids=["gpt1.3B-bf16", "gpt1.3B-f32", "gqa12over2-bf16",
-         "cell-bf16", "cell-f32", "cell-gqa32over8-bf16"])
+         "gqa6over1-bf16", "cell-bf16", "cell-f32", "cell-gqa32over8-bf16",
+         "solar-cell-gqa64over8-bf16"])
 def test_paged_decode_compiles(one_chip, dtype, H, Hkv, B, N, M):
     """gpt3-1.3B serving shapes, 16 heads x 128 in 16-token blocks: 8
     slots, and the benchmark's cells (32 slots, a table of 128 columns
-    over a pool of 2,600 blocks)."""
+    over a pool of 2,600 blocks; the hybrid cell's one GQA layer, 64
+    slots of 64 heads over 8, a table of 512 columns over 16,700
+    blocks); 6 heads over ONE kv head is 12 over 2 cut by `mp` 2: a
+    block of 16 rows, one 16-bit tile."""
     s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     q = s((B, 1, H, 128), dtype)
     pool = s((N, 16, Hkv, 128), dtype)

@@ -860,17 +860,25 @@ def _ragged_case(rng, M, bs, Hkv, g, dtype, chunk):
     return q, k, v, tables, np.asarray(lens, np.int32), poison
 
 
-@pytest.mark.parametrize("g", [1, 4])
+@pytest.mark.parametrize("g", [1, 4, 8])
 @pytest.mark.parametrize("M,bs,Hkv,dtype,chunk", [
     (4, 8, 2, "float32", 4),        # a table shorter than a chunk
     (7, 16, 16, "float32", 4),      # a table that ends inside a chunk
     (7, 16, 16, "bfloat16", 7),
     (128, 16, 16, "bfloat16", 8),   # the benchmark's cells
     (128, 16, 16, "float32", 4),
-    (128, 16, 8, "bfloat16", 16),   # ... under mp 2
+    (128, 16, 8, "bfloat16", 16),   # ... under mp 2; the hybrid cell's
+    (40, 16, 8, "bfloat16", 16),    # ends inside a chunk of 16
+    (34, 16, 8, "float32", 8),
+    (9, 16, 16, "float32", 4),      # a last chunk of one block
+    (34, 16, 16, "bfloat16", 8),
+    (34, 8, 4, "float32", 32),      # kv heads short of a sublane tile
+    (34, 16, 1, "bfloat16", 16),    # one kv head: a shard of few
 ])
 def test_paged_kernel_walks_ragged_rows_like_the_fallback(
         M, bs, Hkv, dtype, chunk, g):
+    """In interpret mode a scratch row that no copy wrote reads as NaN:
+    a product that takes one in shows."""
     import jax.numpy as jnp
     from paddle_tpu.ops.nn_kernels import paged_attention_k
     from paddle_tpu.ops.pallas import paged_attention as pa
@@ -892,6 +900,98 @@ def test_paged_kernel_walks_ragged_rows_like_the_fallback(
         else dict(rtol=1e-2, atol=1e-2)     # the output's own rounding
     np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), ref,
                                **tol)
+
+
+@pytest.mark.parametrize("H,Hkv", [(64, 8), (16, 16), (16, 8)])
+def test_paged_kernel_keeps_16_bits_of_p_in_a_bfloat16_pool(H, Hkv):
+    """A long row of flat scores under one peak: every p but the peak's
+    is the same number c = 0.50131, which bfloat16 rounds to 0.5, 0.26%
+    low, always the same way.  With every value 1 the answer is 1; a
+    body that rounds p to bfloat16 before `p . v` while it sums p in
+    float32 gives 0.9974, which the output's own rounding takes to
+    0.99609."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    bs, M, D, n = 16, 64, 128, 1000
+    N = M + 1
+    q = np.zeros((1, 1, H, D), np.float32)
+    q[..., 0] = 8.0
+    k = np.zeros((N, bs, Hkv, D), np.float32)
+    k[3, 5, :, 0] = 0.9765625       # the peak: 8 x 0.9765625 / sqrt(128)
+    v = np.ones((N, bs, Hkv, D), np.float32)
+    tables = np.arange(1, N, dtype=np.int32)[None]
+    out = pa.paged_decode_attention(
+        jnp.asarray(q, jnp.bfloat16), jnp.asarray(k, jnp.bfloat16),
+        jnp.asarray(v, jnp.bfloat16), jnp.asarray(tables),
+        jnp.asarray([n], jnp.int32), interpret=True)
+    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)), 1.0,
+                               atol=1e-3)
+
+
+@pytest.mark.parametrize("H,Hkv,dtype", [
+    (64, 8, "bfloat16"),        # the hybrid cell's GQA layer
+    (32, 8, "bfloat16"),
+    (16, 16, "bfloat16"),       # the gpt3-1.3b cells
+    (16, 16, "float32"),
+])
+def test_a_chunk_is_two_products_whatever_the_heads(H, Hkv, dtype):
+    """One body for every call: the kernel's program holds the scores'
+    product and the one product against V (p's two halves stacked as
+    2 H rows in a 16-bit pool), and no second pass over V."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    s = jax.ShapeDtypeStruct
+    q, pool = (3, 1, H, 128), (12, 8, Hkv, 128)
+    jaxpr = str(jax.make_jaxpr(
+        lambda *a: pa.paged_decode_attention(*a, interpret=True))(
+        s(q, dtype), s(pool, dtype), s(pool, dtype),
+        s((3, 4), jnp.int32), s((3,), jnp.int32)))
+    assert jaxpr.count(" dot_general[") == 2
+    rows = H if dtype == "float32" else 2 * H
+    assert f"f32[{rows},128] = dot_general[" in jaxpr
+
+
+@pytest.mark.parametrize("heads,kv_heads", [(2, 2), (4, 2), (8, 1)],
+                         ids=["dense", "gqa-2", "mqa-8"])
+def test_six_requests_served_through_the_kernel_as_through_the_gather(
+        monkeypatch, heads, kv_heads):
+    """What the benchmark's `served_logit_gap` compares, request by
+    request, in float32 where rounding hides nothing: the same six
+    requests through the gather and through the kernel (interpreted)
+    emit the same tokens, every served position's logits agree, and no
+    served token's logit lies below the gather's best."""
+    pt.seed(0)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=64, hidden_size=128 * heads, num_layers=2,
+        num_heads=heads, num_kv_heads=kv_heads, intermediate_size=64,
+        max_position_embeddings=64, tensor_parallel=False))
+    rng = np.random.RandomState(heads)
+    prompts = [rng.randint(0, 64, size=n).tolist()
+               for n in (5, 19, 9, 1, 33, 16)]
+
+    def serve():
+        eng = LLMEngine(model, num_blocks=40, block_size=8, max_running=4,
+                        prefill_chunk=16)
+        rows, emit = {}, eng._emit
+
+        def keep(req, row, now):
+            rows.setdefault(req.id, []).append(np.array(row))
+            return emit(req, row, now)
+
+        eng._emit = keep
+        reqs = [eng.add_request(p, max_new_tokens=k)
+                for p, k in zip(prompts, (6, 3, 8, 12, 4, 7))]
+        eng.run()
+        return [(r.generated, np.stack(rows[r.id])) for r in reqs]
+
+    gathered = serve()
+    monkeypatch.setenv("PADDLE_TPU_PALLAS", "interpret")
+    for (tokens, ref), (got, logits) in zip(gathered, serve()):
+        assert got == tokens
+        np.testing.assert_allclose(logits, ref, rtol=2e-4, atol=2e-5)
+        took = ref[np.arange(len(got)), got]
+        assert float((ref.max(axis=1) - took).max()) == 0.0
 
 
 def test_walked_blocks_are_the_blocks_the_kernel_touches():
