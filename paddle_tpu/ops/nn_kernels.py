@@ -386,8 +386,23 @@ def paged_write_k(pool, val, tables, pos, limit, block_size=16):
     return pool.at[blk, off].set(val.astype(pool.dtype), mode="drop")
 
 
+# The most float32 scores [heads, chunk, table positions] of ONE row that
+# the XLA form below holds at once; a chunk over it is attended a block
+# of query rows at a time.  Why 1 GiB, and `>`: it is the largest such
+# array this form has run with on a 16 GB chip beside a deployment's
+# weights and pool (64 heads x 512 rows x 8,192 positions x 4 B, exactly
+# 1 GiB, in a serving cell since PR 32), so every program that ran
+# before the rule stays byte for byte what it was, and the first shape
+# that cannot fit (48 x 1,024 x 16,384 x 4 B = 3.2 GB beside 10.3 GB
+# resident) splits in four.  The free memory is not read here: the op
+# is traced where the pool's size is unknown.  ROADMAP R3 (i)'s prefill
+# kernel replaces both paths and this constant.
+_PAGED_SCORE_BYTES = 1 << 30
+
+
 @register("paged_attention", amp="allow")
-def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None):
+def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None,
+                      window=None):
     """Decode/prefill attention over the paged KV pool — the jnp `take`
     reference implementation (the pallas TPU kernel in
     ops/pallas/paged_attention.py overrides this at import).
@@ -396,20 +411,58 @@ def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None):
     and runs the exact `sdpa_k` math under the paged length mask
     (q row i of a request at context offset pos attends absolute
     positions <= pos + i), so CPU tier-1 numerics are bit-identical to
-    the dense-cache path."""
+    the dense-cache path.
+
+    With `window` (a host number) row i sees the positions in
+    ``(pos + i - window, pos + i]`` alone, and only the table columns a
+    chunk can see are gathered: ``cdiv(window - 1 + s, bs) + 1`` of them
+    from the block that holds position ``pos - (window - 1)``.  The
+    columns before it are never read, so their entries may hold any id
+    (the serving pool hands those blocks back)."""
     b, s = q.shape[0], q.shape[1]
     bs = k_pool.shape[1]
     m = tables.shape[1]
-    flat = tables.astype(jnp.int32).reshape(-1)
+    tables = tables.astype(jnp.int32)
+    pos = pos.astype(jnp.int32)
+    first = 0
+    if window is not None:
+        window = int(window)
+        n = min(m, -(-(window - 1 + s) // bs) + 1)
+        first = (jnp.maximum(pos - (window - 1), 0) // bs)[:, None]  # [b, 1]
+        # a column past the table repeats its last: those positions lie
+        # past every row's own and are masked
+        tables = jnp.take_along_axis(
+            tables, jnp.minimum(first + jnp.arange(n, dtype=jnp.int32),
+                                m - 1), axis=1)
+        m = n
+    flat = tables.reshape(-1)
     K = jnp.take(k_pool, flat, axis=0).reshape(
         (b, m * bs) + k_pool.shape[2:])
     V = jnp.take(v_pool, flat, axis=0).reshape(
         (b, m * bs) + v_pool.shape[2:])
     cols = jnp.arange(m * bs, dtype=jnp.int32)[None, None, :]
-    rows = (pos.astype(jnp.int32)[:, None, None]
+    rows = (pos[:, None, None]
             + jnp.arange(s, dtype=jnp.int32)[None, :, None])
-    mask = (cols <= rows)[:, None, :, :]                 # [b, 1, s, M*bs]
-    return sdpa_k(q, K, V, mask=mask, scale=scale)
+    if window is None:
+        mask = cols <= rows
+    else:
+        cols = cols + first[:, :, None] * bs
+        mask = (cols <= rows) & (cols > rows - window)
+    mask = mask[:, None, :, :]
+    # a long chunk over a long table: the float32 scores of all its rows
+    # at once would not fit beside a pool, so the rows go a block at a
+    # time (no block changes a number: a row's softmax is its own)
+    parts = 1
+    while q.shape[2] * (s // parts) * m * bs * 4 > _PAGED_SCORE_BYTES \
+            and (s // parts) % 2 == 0:
+        parts *= 2
+    if parts == 1:
+        return sdpa_k(q, K, V, mask=mask, scale=scale)
+    out = lax.map(
+        lambda qm: sdpa_k(qm[0], K, V, mask=qm[1], scale=scale),
+        (jnp.moveaxis(q.reshape((b, parts, s // parts) + q.shape[2:]), 1, 0),
+         jnp.moveaxis(mask.reshape(b, 1, parts, s // parts, m * bs), 2, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(q.shape[:3] + out.shape[-1:])
 
 
 @register("paged_gather")

@@ -148,7 +148,8 @@ def _paged_kernel(q_shape, pool_shape, dtype):
     return mode, split
 
 
-def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None):
+def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None,
+                                window=None):
     """Serving decode steps stream blocks through the pallas kernel;
     prefill chunks (s > 1) and unsupported shapes keep the XLA gather
     fallback, which is also the parity reference.  Under a mesh the
@@ -161,24 +162,30 @@ def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None):
         def kernel(q, k_pool, v_pool, tables, pos):
             return _pa.paged_decode_attention(
                 q, k_pool, v_pool, tables, pos + 1, scale=scale,
-                interpret=(mode == "interpret"))
+                interpret=(mode == "interpret"), window=window)
 
         heads = (None, None, "mp", None)
         return _over_mesh(split, kernel, (q, k_pool, v_pool, tables, pos),
                           (heads, heads, heads, (None, None), (None,)),
                           heads)
-    return _xla_paged_attention(q, k_pool, v_pool, tables, pos, scale=scale)
+    return _xla_paged_attention(q, k_pool, v_pool, tables, pos, scale=scale,
+                                window=window)
 
 
-def paged_blocks_read(lens, table_cols, q_shape, pool_shape, dtype):
+def paged_blocks_read(lens, table_cols, q_shape, pool_shape, dtype,
+                      window=None):
     """Pool blocks one `paged_attention` call reads for rows of visible
     lengths `lens` (host numbers), by the path that serves those shapes:
     the kernel's ragged walk (`walked_blocks`), or every column of every
-    row's table where the XLA fallback gathers.  The gate is the one the
-    call itself takes; nothing is read back from the device."""
+    row's table where the XLA fallback gathers (under a `window`: the
+    columns one token can see).  The gate is the one the call itself
+    takes; nothing is read back from the device."""
     if _paged_kernel(q_shape, pool_shape, dtype) is None:
+        if window is not None:      # the columns one token can see
+            table_cols = min(table_cols,
+                             _pa.band_blocks(window + 1, pool_shape[1]))
         return len(lens) * table_cols
-    return _pa.walked_blocks(lens, table_cols, pool_shape[1])
+    return _pa.walked_blocks(lens, table_cols, pool_shape[1], window)
 
 
 override("paged_attention", paged_attention_with_pallas)
@@ -242,15 +249,17 @@ _BLOCKS_READ = {"paged_attention": paged_blocks_read,
                 "latent_paged_attention": latent_blocks_read}
 
 
-def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype):
+def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype,
+                     window=None):
     """Pool blocks one layer of a decode program of `rows` slots reads
     for visible lengths `lens`, by the registered op `op` that the model
     says reads its planes (`plane_shapes`: {name: one layer's array
     shape}, the planes of one op alike; the last axis is the width a
-    query meets)."""
+    query meets).  `window`: the layer's band, where its op takes one."""
     shape = next(iter(plane_shapes.values()))
+    band = {} if window is None else {"window": window}
     return _BLOCKS_READ[op](lens, table_cols, (rows, 1, heads, shape[-1]),
-                            shape, dtype)
+                            shape, dtype, **band)
 
 
 _xla_grouped_matmul = get("grouped_matmul").fn

@@ -65,7 +65,13 @@ _CHUNK_BYTES = 512 * 1024       # one operand's chunk, as it lies in HBM
 _CHUNK_VMEM = 1024 * 1024       # ... and at most, as it lies in VMEM
 
 
-def chunk_blocks(table_cols, block_size, kv_heads, head_dim, dtype):
+def band_blocks(window, block_size):
+    """The most pool blocks a band of `window` positions lies in."""
+    return -(-(int(window) - 1) // block_size) + 1
+
+
+def chunk_blocks(table_cols, block_size, kv_heads, head_dim, dtype,
+                 window=None):
     """Pool blocks the kernel copies per step of its walk (`C`): as
     many as make one operand's copy about half a MiB, so that a step's
     DMAs are worth their set-up, while K and V, double-buffered, stay a
@@ -73,7 +79,10 @@ def chunk_blocks(table_cols, block_size, kv_heads, head_dim, dtype):
     counts the head axis padded to whole sublane tiles, as a block
     `(bs, Hkv, D)` lay in VMEM until PR 33; as the matrix [(token, kv
     head), D] it is padded only where bs x Hkv is short of a tile, so
-    the bound is kept and spare."""
+    the bound is kept and spare.  Under a `window` no row walks more
+    than the band's blocks, whatever its table holds."""
+    if window is not None:
+        table_cols = min(table_cols, band_blocks(window, block_size))
     itemsize = jnp.dtype(dtype).itemsize
     sublanes = 8 * 4 // itemsize
     row = block_size * head_dim * itemsize
@@ -82,32 +91,46 @@ def chunk_blocks(table_cols, block_size, kv_heads, head_dim, dtype):
                       _CHUNK_VMEM // in_vmem))
 
 
-def walked_blocks(lens, table_cols, block_size):
+def walked_blocks(lens, table_cols, block_size, window=None):
     """Pool blocks the kernel copies and reduces for rows of visible
     lengths `lens` (host numbers): a row's walk ends with the block
     that holds its last position (of its last chunk only the blocks it
     lives in are copied, so the chunk does not enter), a dead slot
-    (length 1) walks one block, and no row walks past its table."""
-    return sum(min(-(-max(int(n), 1) // block_size), table_cols)
-               for n in lens)
+    (length 1) walks one block, and no row walks past its table.  Under
+    a `window` the walk STARTS at the block that holds the first visible
+    position, ``max(0, len - window) // block_size``."""
+    total = 0
+    for n in lens:
+        n = max(int(n), 1)
+        first = 0 if window is None else max(n - window, 0) // block_size
+        total += max(min(-(-n // block_size), table_cols) - first, 0)
+    return total
 
 
 def _decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, sems, slot_s, *, bs, chunk, g, scale):
+                   k_buf, v_buf, sems, slot_s, *, bs, chunk, g, scale,
+                   window):
     b = pl.program_id(0)
     rows, cols = tables_ref.shape
 
     def visible(row):
         return jnp.maximum(lens_ref[row], 1)
 
+    def first_block(row):       # the block of the first visible position
+        return jnp.maximum(visible(row) - window, 0) // bs
+
     def blocks(row):            # the rule `walked_blocks` states
-        return jnp.minimum(pl.cdiv(visible(row), bs), cols)
+        n = jnp.minimum(pl.cdiv(visible(row), bs), cols)
+        return n if window is None \
+            else jnp.maximum(n - first_block(row), 0)
 
     def copies(row, i, slot, act):
         """`act` on the copy of every block of the row's i-th chunk
         that the row lives in; returns how many those are."""
         first = i * chunk
         n = jnp.minimum(blocks(row) - first, chunk)
+        if window is not None:
+            first += first_block(row)
 
         def one(c, _):
             blk = tables_ref[row, first + c]
@@ -174,7 +197,13 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             precision=precision,
                             preferred_element_type=jnp.float32) * scale
-        live = own & (col < (length - i * chunk * bs) * hkv)
+        # the chunk's first column is position `base` of the context
+        base = i * chunk * bs
+        if window is not None:
+            base += first_block(b) * bs
+        live = own & (col < (length - base) * hkv)
+        if window is not None:      # the band: positions >= len - window
+            live &= col >= (length - window - base) * hkv
         s = jnp.where(live, s, _NEG_INF)                    # (H, width)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)                              # masked -> 0
@@ -205,13 +234,17 @@ def _decode_kernel(tables_ref, lens_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, lens, scale=None,
-                           interpret=False):
+                           interpret=False, window=None):
     """One-token paged attention.  q: [B, 1, H, D]; pools:
     [N, bs, Hkv, D]; tables: [B, M] int32 block ids; lens: [B] int32
     visible context length, INCLUDING the token just written, so at
     least 1: a row of length 0 is walked as a dead slot is, over
     position 0 of its first block, and its output means nothing.
     `scale` is a host number (None: 1 / sqrt(D)), fixed at trace time.
+    `window` (a host number): the row sees its last `window` positions
+    alone, and its walk starts at the block that holds the first of
+    them; the kernel then carries the name
+    ``paged_window_decode_attention`` in a device trace.
     Returns [B, 1, H, D] in the q dtype."""
     B, s, H, D = q.shape
     if s != 1:
@@ -224,19 +257,24 @@ def paged_decode_attention(q, k_pool, v_pool, tables, lens, scale=None,
             f"paged_decode_attention needs head_dim % {_LANES} == 0 "
             f"(got {D}); the XLA fallback serves other head dims")
     scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
+    if window is not None and int(window) < 1:
+        raise ValueError(f"window={window} is no band")
     return _paged_decode(q, k_pool, v_pool, tables, lens, scale=scale,
-                         interpret=bool(interpret))
+                         interpret=bool(interpret),
+                         window=None if window is None else int(window))
 
 
 # jitted, so that a model's layers trace and lower ONE kernel: the walk
 # with its loops and copies costs 0.05 s a call site to trace
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
+def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret,
+                  window):
     B, _, H, D = q.shape
     N, bs, Hkv, _ = k_pool.shape
     M = tables.shape[1]
     g = H // Hkv
-    chunk = chunk_blocks(M, bs, Hkv, D, k_pool.dtype)
+    chunk = chunk_blocks(M, bs, Hkv, D, k_pool.dtype, window)
     # a block as the matrix [(token, kv head), D] it already is in
     # memory, and q in the model's own head order
     qb = q[:, 0]
@@ -245,7 +283,7 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
     block = (bs * Hkv, D)
 
     kernel = functools.partial(_decode_kernel, bs=bs, chunk=chunk, g=g,
-                               scale=scale)
+                               scale=scale, window=window)
     q_spec = pl.BlockSpec(
         (1, H, D), lambda b, tables_ref, lens_ref: (b, 0, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
@@ -269,7 +307,8 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret):
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_decode_attention",
+        name="paged_decode_attention" if window is None
+        else "paged_window_decode_attention",
     )(tables.astype(jnp.int32), lens.astype(jnp.int32),
       qb, k_pool, v_pool)
     return out.reshape(B, 1, H, D)

@@ -6,12 +6,24 @@ planes with their trailing shape per token -- `k` and `v` of
 [Hkv, D] for the K/V models, ONE latent row for a latent-attention
 model -- each a [num_blocks, block_size, *trailing] array allocated
 ONCE and carved into fixed-size token blocks handed to requests through
-a host-side free list with reference counts.  A block id means the same
-block in every plane of every layer, so the allocator, the tables, the
-refcounts and the scheduler never see the layout.  Freed requests return
+a host-side free list with reference counts.  Freed requests return
 their blocks immediately (refcount 0 -> back on the free list), so pool
 pressure is a pure function of live context tokens — the scheduler
 admits, evicts and preempts against `free_blocks`.
+
+Blocks come in GROUPS, one per lifetime (`text.decode.LayerPlanes`): a
+group is the layers whose blocks live equally long, and has its own
+number of blocks, free list and refcounts; a request holds one table a
+group, and a block id means the same block in every plane of every layer
+OF ITS GROUP, so the allocator, the tables and the scheduler never see
+the layout.  The layers that keep the whole context are one group (a
+model of one kind is one group, and the pool is what it was).  Layers
+that read their last `window` positions alone are another: its table is
+still indexed by position (``table[p // block_size]``), but an entry goes
+home as soon as it lies wholly behind the band (`Scheduler.trim`), so a
+request holds blocks for its window and the chunk in flight, not for its
+context; the entry behind the band may then hold any id, which the paged
+ops never read.
 
 Layers of different KINDS cache different planes, side by side in the
 one pool: a plane exists for the layers that name it and is None for the
@@ -41,53 +53,91 @@ class PoolExhausted(RuntimeError):
     """A single request needs more blocks than the whole pool holds."""
 
 
+def band_blocks(window, n_tokens, block_size):
+    """The most blocks a request holds at a time in a group of `window`
+    while one program writes `n_tokens`: the band behind the context
+    (with its one position of slack: `Scheduler.trim`) and the run, which
+    may start and end inside a block."""
+    return -(-(int(window) + int(n_tokens) - 1) // int(block_size)) + 1
+
+
+class BlockGroup:
+    """The blocks of the layers of one lifetime: how many, which are
+    free (LIFO), how often each is referenced."""
+
+    def __init__(self, name, window, num_blocks):
+        self.name, self.window = name, window
+        self.num_blocks = int(num_blocks)
+        self.layers = []        # the layers whose planes these blocks are
+        self.free = list(range(self.num_blocks - 1, -1, -1))
+        self.refs = [0] * self.num_blocks
+
+
 class BlockPool:
     def __init__(self, num_layers, num_blocks, block_size, planes,
-                 dtype="float32", slots=0):
+                 dtype="float32", slots=0, window_blocks=None):
         """`planes`: {name: trailing shape per token}, the same for every
         layer (a K/V model's: `text.decode.kv_cache_planes`), or one such
         dict per layer, in which a `StatePlane` names a plane held per
-        request: those get `slots` entries each."""
+        request: those get `slots` entries each.  A layer's dict may be a
+        `LayerPlanes` that names a `window`: the layers of one window are
+        a group of `window_blocks` blocks ({window: blocks}; `num_blocks`
+        where it names none), beside the `num_blocks` of the layers that
+        keep the whole context."""
         from ..text.decode import StatePlane
         self.num_layers = int(num_layers)
-        self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.dtype = dtype
         per_layer = [planes] * self.num_layers if isinstance(planes, dict) \
             else list(planes)
+        # groups by lifetime, the whole-context one first: `num_blocks`,
+        # `free_blocks`, `allocate` ... without a group mean group 0
+        windows = [getattr(layer, "window", None) for layer in per_layer]
+        self.groups, self.group_of = [], []
+        for w in sorted(set(windows), key=lambda w: (w is not None, w)):
+            first = per_layer[windows.index(w)]
+            n = num_blocks if w is None or not self.groups \
+                else (window_blocks or {}).get(w, num_blocks)
+            self.groups.append(BlockGroup(
+                getattr(first, "kind", "full"), w, n))
+        for i, w in enumerate(windows):
+            g = next(k for k, grp in enumerate(self.groups)
+                     if grp.window == w)
+            self.groups[g].layers.append(i)
+            self.group_of.append(g)
         stateful = any(isinstance(spec, StatePlane)
                        for layer in per_layer for spec in layer.values())
         self.slots = int(slots) if stateful else 0
         if stateful and self.slots < 1:
             raise ValueError("layers that cache per request need slots")
 
-        def plane(spec):
+        def plane(spec):    # of `blocks` blocks, the layer's group's
             if spec is None:
                 return None
             if isinstance(spec, StatePlane):
                 return jnp.zeros((self.slots,) + tuple(spec.shape),
                                  dtype=spec.dtype or dtype)
-            return jnp.zeros((self.num_blocks, self.block_size)
+            return jnp.zeros((blocks, self.block_size)
                              + tuple(int(n) for n in spec), dtype=dtype)
 
         names = list(dict.fromkeys(n for layer in per_layer for n in layer))
         # {name: one array a layer, None where the layer has no such plane}
-        self.planes = {name: [plane(layer.get(name)) for layer in per_layer]
-                       for name in names}
+        self.planes = {name: [] for name in names}
+        for i, layer in enumerate(per_layer):
+            blocks = self.groups[self.group_of[i]].num_blocks
+            for name in names:
+                self.planes[name].append(plane(layer.get(name)))
         self.state_names = frozenset(
             n for layer in per_layer for n, spec in layer.items()
             if isinstance(spec, StatePlane))
         self._state_bytes = sum(
             a.nbytes // self.slots for name in self.state_names
             for a in self.planes[name] if a is not None)
-        # host-side allocators: LIFO free lists; per-block refcounts
-        self._free = list(range(self.num_blocks - 1, -1, -1))
-        self._refs = [0] * self.num_blocks
         self._free_slots = list(range(self.slots - 1, -1, -1))
 
     @classmethod
     def for_model(cls, model, num_blocks, block_size=16, dtype=None,
-                  slots=0):
+                  slots=0, window_blocks=None):
         """Size the pool from what the model declares it caches
         (`cache_planes()`: one {name: trailing shape | StatePlane} per
         layer); `slots` entries of every per-request plane."""
@@ -95,13 +145,27 @@ class BlockPool:
         if dtype is None:
             dtype = next(iter(model.parameters()))._array.dtype
         return cls(len(per_layer), num_blocks, block_size, per_layer,
-                   dtype=dtype, slots=slots)
+                   dtype=dtype, slots=slots, window_blocks=window_blocks)
 
-    def plane_shapes(self):
-        """{name: one layer's array shape} of the planes held per token."""
-        return {name: next(a for a in arrays if a is not None).shape
+    @property
+    def num_blocks(self):
+        return self.groups[0].num_blocks
+
+    # the first group's books under the names they had before the
+    # groups: read by two older test lines alone, to go with them at the
+    # next simplicity pass
+    _free = property(lambda self: self.groups[0].free)
+    _refs = property(lambda self: self.groups[0].refs)
+
+    def plane_shapes(self, group=0):
+        """{name: one layer's array shape} of the planes the layers of
+        `group` hold per token."""
+        layers = self.groups[group].layers
+        return {name: next(arrays[i] for i in layers
+                           if arrays[i] is not None).shape
                 for name, arrays in self.planes.items()
-                if name not in self.state_names}
+                if name not in self.state_names
+                and any(arrays[i] is not None for i in layers)}
 
     def state_bytes(self):
         """Bytes one request's slot holds over all layers."""
@@ -112,54 +176,70 @@ class BlockPool:
         self.planes = {}
 
     # ------------------------------------------------------------ allocator
+    # every call takes the group it means; without one, the first
     @property
     def free_blocks(self):
-        return len(self._free)
+        return len(self.groups[0].free)
 
     @property
     def used_blocks(self):
-        return self.num_blocks - len(self._free)
+        return self.num_blocks - self.free_blocks
+
+    def used_in(self, group):
+        grp = self.groups[group]
+        return grp.num_blocks - len(grp.free)
 
     def blocks_for(self, n_tokens):
         """Blocks needed to hold n_tokens."""
         return -(-int(n_tokens) // self.block_size)
 
-    def allocate(self, n):
-        """n block ids at refcount 1, or None when the pool can't serve
-        them right now (the scheduler's preemption trigger).  The
+    def band_blocks(self, group, n_tokens):
+        """`band_blocks` of `group`; None for the group that keeps the
+        whole context."""
+        window = self.groups[group].window
+        return None if window is None \
+            else band_blocks(window, n_tokens, self.block_size)
+
+    def allocate(self, n, group=0):
+        """n block ids of `group` at refcount 1, or None when it can't
+        serve them right now (the scheduler's preemption trigger).  The
         `serving.pool_exhausted` chaos site simulates that exhaustion."""
-        n = int(n)
-        if n > self.num_blocks:
+        n, grp = int(n), self.groups[group]
+        if n > grp.num_blocks:
             raise PoolExhausted(
-                f"request needs {n} blocks but the whole pool is only "
-                f"{self.num_blocks}; grow num_blocks or cap request "
-                f"lengths")
-        if chaos.fire("serving.pool_exhausted") or n > len(self._free):
+                f"request needs {n} {grp.name} blocks but the whole pool "
+                f"has only {grp.num_blocks} of that kind; grow num_blocks "
+                f"or cap request lengths")
+        if chaos.fire("serving.pool_exhausted") or n > len(grp.free):
             from ..observability import metrics as _metrics
-            _metrics.registry().counter(
-                "serving_pool_exhausted_total").inc()
+            # the total its readers know, and the same by kind
+            for labels in ({}, {"kind": grp.name}):
+                _metrics.registry().counter(
+                    "serving_pool_exhausted_total", **labels).inc()
             return None
-        out = [self._free.pop() for _ in range(n)]
+        out = [grp.free.pop() for _ in range(n)]
         for b in out:
-            self._refs[b] = 1
+            grp.refs[b] = 1
         return out
 
-    def ref(self, ids):
+    def ref(self, ids, group=0):
+        refs = self.groups[group].refs
         for b in ids:
-            if self._refs[b] <= 0:
+            if refs[b] <= 0:
                 raise ValueError(f"ref of unallocated block {b}")
-            self._refs[b] += 1
+            refs[b] += 1
 
-    def free(self, ids):
+    def free(self, ids, group=0):
         """Drop one reference per id; blocks at refcount 0 return to the
         free list immediately."""
+        grp = self.groups[group]
         for b in ids:
-            r = self._refs[b] - 1
+            r = grp.refs[b] - 1
             if r < 0:
-                raise ValueError(f"double free of block {b}")
-            self._refs[b] = r
+                raise ValueError(f"double free of {grp.name} block {b}")
+            grp.refs[b] = r
             if r == 0:
-                self._free.append(b)
+                grp.free.append(b)
 
     # ------------------------------------------------------ slot allocator
     @property
@@ -178,13 +258,16 @@ class BlockPool:
 
     def check_leaks(self):
         """(leaked_blocks, bad_refcounts) — both empty when every block
-        and every slot is home (a slot still out shows among the leaked
-        as ``("slot", n)``).  The chaos drill asserts this after an
-        overload run."""
-        leaked = [b for b, r in enumerate(self._refs) if r > 0]
+        of every group and every slot is home (a block of a further group
+        shows as ``(its kind, n)``, a slot still out as ``("slot", n)``).
+        The chaos drill asserts this after an overload run."""
+        leaked, bad = [], []
+        for g, grp in enumerate(self.groups):
+            tag = (lambda b: b) if g == 0 else (lambda b: (grp.name, b))
+            leaked += [tag(b) for b, r in enumerate(grp.refs) if r > 0]
+            bad += [tag(b) for b, r in enumerate(grp.refs) if r < 0]
         leaked += [("slot", n) for n in range(self.slots)
                    if n not in self._free_slots]
-        bad = [b for b, r in enumerate(self._refs) if r < 0]
         return leaked, bad
 
     # ------------------------------------------------------------- sharding
@@ -198,9 +281,12 @@ class BlockPool:
         import jax
         sh = mesh_mod.sharding(None, None, "mp", None)
         done = False
-        for name, shape in self.plane_shapes().items():
+        for name, arrays in self.planes.items():
+            if name in self.state_names:
+                continue
+            shape = next(a for a in arrays if a is not None).shape
             if len(shape) == 4 and shape[2] % mesh_mod.degree("mp") == 0:
                 self.planes[name] = [a if a is None else jax.device_put(a, sh)
-                                     for a in self.planes[name]]
+                                     for a in arrays]
                 done = True
         return done

@@ -52,7 +52,7 @@ from ..ops.pallas import pool_blocks_read
 from ..resilience import chaos
 from ..tensor import Tensor
 from ..text.generation import BucketPolicy
-from .block_pool import BlockPool, PoolExhausted
+from .block_pool import BlockPool, PoolExhausted, band_blocks
 from .scheduler import RUNNING, Request, Scheduler, clock
 
 
@@ -76,18 +76,32 @@ class LLMEngine:
                  prefill_chunk=64, buckets=None, max_model_len=None,
                  dtype=None, shed_queue_depth=None, shed_free_blocks=None,
                  promote_after=4):
-        if getattr(getattr(model, "cfg", None), "sliding_window", None):
+        """`num_blocks` sizes the pool's first block group (the layers
+        that keep the whole context, where the model has any).  A window
+        group beside it gets what `max_running` requests hold at most,
+        each its band and one chunk: the scheduler admits against that
+        count, so such a group cannot run dry."""
+        windows = {getattr(layer, "window", None)
+                   for layer in model.cache_planes()} - {None}
+        if getattr(getattr(model, "cfg", None), "sliding_window", None) \
+                and not windows:
             raise NotImplementedError(
-                "sliding_window models cannot serve from the paged pool "
-                "yet (the pool keeps the full context)")
+                "this model has a sliding_window and its cache_planes() "
+                "name no layer's window: the pool would keep, and the "
+                "paged ops read, the full context")
         self.model = model
         model.eval()
-        self.pool = BlockPool.for_model(model, num_blocks,
-                                        block_size=block_size, dtype=dtype,
-                                        slots=max_running)
+        self.pool = BlockPool.for_model(
+            model, num_blocks, block_size=block_size, dtype=dtype,
+            slots=max_running,
+            window_blocks={w: int(max_running) * band_blocks(
+                w, prefill_chunk, block_size) for w in windows})
+        self._window_groups = [g for g, grp in enumerate(self.pool.groups)
+                               if grp.window is not None]
         sharded = self.pool.shard_()
         self.scheduler = Scheduler(self.pool, max_running=max_running,
-                                   promote_after=promote_after)
+                                   promote_after=promote_after,
+                                   run_tokens=prefill_chunk)
         self.max_running = int(max_running)
         # admission-control watermarks (None = never shed): overload
         # must degrade to fast structured refusals, not unbounded p99
@@ -109,11 +123,12 @@ class LLMEngine:
         # pool blocks a layer of the decode program reads: by the op the
         # model says reads its planes, one query token a slot (for
         # step()'s counts)
-        self._blocks_read = functools.partial(
+        self._blocks_read = [functools.partial(
             pool_blocks_read, model.cache_op, table_cols=self.table_cols,
-            plane_shapes=self.pool.plane_shapes(),
+            plane_shapes=self.pool.plane_shapes(g),
             rows=self.max_running, heads=model.cfg.num_heads,
-            dtype=next(iter(model.parameters()))._array.dtype)
+            dtype=next(iter(model.parameters()))._array.dtype,
+            window=grp.window) for g, grp in enumerate(self.pool.groups)]
 
         self._pn, self._p_arrays, self._bn, self._b_arrays = \
             FB.split_state(model)
@@ -171,10 +186,12 @@ class LLMEngine:
             raise ValueError(
                 f"request needs {total} positions but the replica serves "
                 f"max_model_len={self.max_model_len}")
-        if self.pool.blocks_for(total) > self.pool.num_blocks:
-            raise PoolExhausted(
-                f"request needs {self.pool.blocks_for(total)} blocks; "
-                f"pool has {self.pool.num_blocks} total")
+        for g, grp in enumerate(self.pool.groups):
+            need = self.scheduler.most_blocks(g, total)
+            if need > grp.num_blocks:
+                raise PoolExhausted(
+                    f"request needs {need} {grp.name} blocks; pool has "
+                    f"{grp.num_blocks} total")
         if resume_tokens and len(resume_tokens) >= int(max_new_tokens):
             raise ValueError(
                 f"resume_tokens already holds {len(resume_tokens)} of "
@@ -286,14 +303,20 @@ class LLMEngine:
 
             # ---- prefill lane: a bounded token budget per step
             budget = self.prefill_chunk
-            prefilled = 0
+            prefilled = freed = 0
             for req in list(sched.running):
                 if budget <= 0:
                     break
                 if not req.needs_prefill:
                     continue
                 n = min(budget, req.feed_len - 1 - req.ctx)
+                # a window group hands out the chunk's blocks now; a
+                # group that is dry keeps the chunk for a later step
+                if req.state != RUNNING or \
+                        not sched.reserve(req, req.ctx + n):
+                    continue
                 self._prefill(req, n, root.sid)
+                freed += sched.trim(req)
                 if req.prefill_done_t is None and not req.needs_prefill:
                     req.prefill_done_t = clock()
                 budget -= n
@@ -327,19 +350,12 @@ class LLMEngine:
             # ready ⊆ running and admit() caps running at max_running, so
             # the static decode program always has a slot for every row
             assert len(ready) <= self.max_running
-            live = walked = chained = 0
+            chained = 0
+            blocks = self._block_counts(ready)
             if ready:
-                # how far the decode program's attention follows the
-                # traffic: the blocks the rows live in, and the blocks a
-                # layer reads for all slots (a dead slot shows the length
-                # 1) on the path that serves the program: the kernel's
-                # ragged walk, or the fallback's gather of whole tables
-                lens = [r.ctx + 1 for r in ready]
-                live = sum(self.pool.blocks_for(n) for n in lens)
-                walked = self._blocks_read(
-                    lens + [1] * (self.max_running - len(ready)))
                 chained = sum(1 for r in ready if r.in_flight)
                 self._flight = self._dispatch(ready, root.sid)
+                freed += sum(sched.trim(r) for r in ready)
             self._land(behind, root.sid, landed)
             if in_place:
                 self._land(self._flight, root.sid, landed)
@@ -351,8 +367,11 @@ class LLMEngine:
                 self.pool.free_blocks)
             emitted = landed.pop("emitted")
             root.counts.update(decode_rows=len(ready), rows_chained=chained,
-                               kv_blocks_live=live, kv_blocks_walked=walked,
-                               **landed)
+                               **blocks, **landed)
+            if self._window_groups:
+                root.counts.update(window_blocks_freed=freed)
+                self._reg.gauge("serving_window_blocks_in_use").set(sum(
+                    self.pool.used_in(g) for g in self._window_groups))
             if self.pool.slots:
                 # the decode rows' recurrent state, read and written once
                 # by this step's program
@@ -365,6 +384,36 @@ class LLMEngine:
                 "emitted": emitted, "prefilled": prefilled,
                 "running": len(sched.running),
                 "waiting": sched.queue_depth}
+
+    def _block_counts(self, ready):
+        """How far the decode program's attention follows the traffic,
+        for the step's root: the blocks the rows live in, and the blocks
+        a layer reads for all slots (a dead slot shows the length 1) on
+        the path that serves the program: the kernel's ragged walk, or
+        the fallback's gather of whole tables.  A window group counts
+        under its own names: the blocks its rows HOLD, what one full
+        table would hold more, and the blocks that hold a position a
+        row's query sees (the least any sound walk reads)."""
+        counts = collections.Counter(kv_blocks_live=0, kv_blocks_walked=0)
+        if not ready:
+            return counts
+        pool = self.pool
+        lens = [r.ctx + 1 for r in ready]
+        dead = [1] * (self.max_running - len(ready))
+        whole = sum(pool.blocks_for(n) for n in lens)
+        for g, grp in enumerate(pool.groups):
+            walked = self._blocks_read[g](lens + dead)
+            if grp.window is None:
+                counts["kv_blocks_live"] += whole
+                counts["kv_blocks_walked"] += walked
+                continue
+            held = sum(len(r.block_tables[g]) - r.behind[g] for r in ready)
+            counts["window_blocks_live"] += held
+            counts["window_blocks_walked"] += walked
+            counts["window_blocks_saved"] += whole - held
+            counts["window_blocks_band"] += whole - sum(
+                max(n - grp.window, 0) // pool.block_size for n in lens)
+        return counts
 
     def _expire(self, now):
         """Deadline sweep: queue-wait and TTL expiry are CLEAN finishes
@@ -470,17 +519,22 @@ class LLMEngine:
         non-donating build."""
         return jax.default_backend() != "cpu"
 
-    def _caches(self, planes, tables, pos, limit, slots=()):
+    def _caches(self, planes, tables, pos, limit, more=()):
         """One cache dict per layer over the planes that layer has, as
-        the models' paged branches read them: the rows' block tables
-        and, where the pool holds per-request planes, their slots."""
-        shared = dict(table=Tensor._from_array(tables),
-                      pos=Tensor._from_array(pos),
-                      limit=Tensor._from_array(limit),
-                      **{"slot": Tensor._from_array(a) for a in slots})
+        the models' paged branches read them: the rows' block tables (a
+        layer gets its group's) and, where the pool holds per-request
+        planes, their slots.  `more`: the slots, where there are any,
+        then the tables of the groups after the first."""
+        more = list(more)
+        shared = dict(pos=Tensor._from_array(pos),
+                      limit=Tensor._from_array(limit))
+        if self.pool.slots:
+            shared["slot"] = Tensor._from_array(more.pop(0))
+        tables = [Tensor._from_array(a) for a in [tables] + more]
         return [dict({name: Tensor._from_array(arrays[i])
                       for name, arrays in planes.items()
-                      if arrays[i] is not None}, **shared)
+                      if arrays[i] is not None},
+                     table=tables[self.pool.group_of[i]], **shared)
                 for i in range(self.pool.num_layers)]
 
     @staticmethod
@@ -499,13 +553,13 @@ class LLMEngine:
         model, pn, bn = self.model, self._pn, self._bn
 
         def pure(p_arrays, b_arrays, planes, tables, pos, tokens, limit,
-                 prev_ids, src, *slots):
+                 prev_ids, src, *more):
             # a chained row's token is the pick of row `src` of the
             # decode program before this one, read where it lies: the
             # host has not seen it yet.  src < 0: the host's `tokens`
             tokens = jnp.where(src >= 0, prev_ids[jnp.maximum(src, 0)],
                                tokens)
-            caches = self._caches(planes, tables, pos, limit, slots)
+            caches = self._caches(planes, tables, pos, limit, more)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
                     logits = model(Tensor._from_array(tokens[:, None]),
@@ -526,8 +580,8 @@ class LLMEngine:
         model, pn, bn = self.model, self._pn, self._bn
 
         def pure(p_arrays, b_arrays, planes, table, pos, tokens, limit,
-                 *slots):
-            caches = self._caches(planes, table, pos, limit, slots)
+                 *more):
+            caches = self._caches(planes, table, pos, limit, more)
             with FB._swapped(model, pn, p_arrays, bn, b_arrays):
                 with _autograd.no_grad():
                     model(Tensor._from_array(tokens), caches=caches)
@@ -578,18 +632,20 @@ class LLMEngine:
                          for a in arrays]
                   for name, arrays in self.pool.planes.items()}
         i32 = np.int32
-        # a pool with per-request planes: the rows' slots ride last
-        slots = lambda rows: (s((rows,), i32),) if self.pool.slots else ()
+        # last ride the rows' slots, where the pool has per-request
+        # planes, and the tables of the block groups after the first
+        more = lambda rows: ((s((rows,), i32),) if self.pool.slots else ()) \
+            + (s((rows, self.table_cols), i32),) * (len(self.pool.groups) - 1)
         if key[0] == "decode":
             R, M = self.max_running, self.table_cols
             return functools.partial(self._build_decode, donate=False), (
                 p, b, planes, s((R, M), i32), s((R,), i32), s((R,), i32),
-                s((R,), i32), s((R,), i32), s((R,), i32)) + slots(R)
+                s((R,), i32), s((R,), i32), s((R,), i32)) + more(R)
         if key[0] == "prefill":
             Lb = int(key[1])
             return functools.partial(self._build_prefill, donate=False), (
                 p, b, planes, s((1, self.table_cols), i32), s((1,), i32),
-                s((1, Lb), i32), s((1,), i32)) + slots(1)
+                s((1, Lb), i32), s((1,), i32)) + more(1)
         raise KeyError(f"unknown serving program key {key!r}")
 
     # ------------------------------------------------------------- prefill
@@ -602,8 +658,7 @@ class LLMEngine:
             chunk = feed[req.ctx:req.ctx + n]
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :n] = chunk
-            table = np.zeros((1, self.table_cols), np.int32)
-            table[0, :len(req.block_table)] = req.block_table
+            tables = self._tables([req])
             pos = np.asarray([req.ctx], np.int32)
             limit = np.asarray([req.ctx + n], np.int32)
             slots = () if req.state_slot is None \
@@ -611,7 +666,7 @@ class LLMEngine:
             self.pool.planes, *load = self._run_program(
                 ("prefill", bucket), self._build_prefill,
                 self._p_arrays, self._b_arrays, self.pool.planes,
-                table, pos, tokens, limit, *slots)
+                tables[0], pos, tokens, limit, *slots, *tables[1:])
             for a in load:
                 # read when a decode's fetch has next waited for the
                 # device (step()): a chunk never waits for its own
@@ -619,6 +674,16 @@ class LLMEngine:
                 self._chunk_loads.append((a, n))
         req.ctx += n
         self._reg.counter("serving_prefill_tokens_total").inc(n)
+
+    def _tables(self, reqs, rows=None):
+        """The requests' block tables as a program takes them: one
+        [rows, table_cols] array a block group, a request a row."""
+        out = [np.zeros((rows or len(reqs), self.table_cols), np.int32)
+               for _ in self.pool.groups]
+        for i, req in enumerate(reqs):
+            for table, ids in zip(out, req.block_tables):
+                table[i, :len(ids)] = ids
+        return out
 
     # -------------------------------------------------------------- decode
     def _dispatch(self, ready, parent=None):
@@ -628,16 +693,15 @@ class LLMEngine:
         its token from that pick on the device (`src` names its row in
         the program before); the host sends the token of every other
         row."""
-        R, M = self.max_running, self.table_cols
+        R = self.max_running
         with _trace.traced("serving.decode.prepare", parent=parent,
                            cat="serving"):
-            tables = np.zeros((R, M), np.int32)
+            tables = self._tables(ready, R)
             pos = np.zeros(R, np.int32)
             tokens = np.zeros(R, np.int32)
             limit = np.zeros(R, np.int32)   # 0 = dead slot, writes dropped
             src = np.full(R, -1, np.int32)  # -1 = the host's token
             for i, req in enumerate(ready):
-                tables[i, :len(req.block_table)] = req.block_table
                 pos[i] = req.ctx
                 limit[i] = req.ctx + 1
                 if req.in_flight:
@@ -661,7 +725,8 @@ class LLMEngine:
             logits, ids, finite, self.pool.planes, *load = self._run_program(
                 ("decode",), self._build_decode,
                 self._p_arrays, self._b_arrays, self.pool.planes,
-                tables, pos, tokens, limit, self._prev_ids, src, *slots)
+                tables[0], pos, tokens, limit, self._prev_ids, src, *slots,
+                *tables[1:])
             self._prev_ids = ids
             # the copies are queued behind the program at once: left to
             # start after a wait has returned they cost the step 0.2 ms
@@ -721,8 +786,10 @@ class LLMEngine:
                     req.in_flight -= 1
                     # a recurrent state cannot step back: it is built
                     # again from the first position
-                    req.ctx = 0 if req.state_slot is not None \
-                        else req.ctx - 1
+                    if req.state_slot is not None:
+                        self.scheduler.rewind(req)
+                    else:
+                        req.ctx -= 1
         if flight.host is not None:
             landed["logit_rows_fetched"] += self.max_running
         else:

@@ -5,9 +5,12 @@ Policy (vLLM-style, adapted to the static-slot decode program):
 * **Admission** is FCFS from the waiting deque: a request is admitted
   when a decode slot is open and the pool can hand it blocks for its
   whole current prefix (prompt + any tokens generated before a
-  preemption) plus the first decode token.  Preempted requests rejoin
-  the FRONT of the queue, so an eviction never costs a request its
-  place in line.
+  preemption) plus the first decode token; in a group of window
+  layers, whose blocks come with the context and go home behind the
+  band, when the group has room for what every running request and this
+  one hold at most (a count: band and one program's run each).
+  Preempted requests rejoin the FRONT of the queue, so an eviction never
+  costs a request its place in line.
 * **Preemption** is LIFO — when a running request needs one more block
   and the pool is dry, the YOUNGEST other running request is evicted
   (recompute-style: its blocks are freed now, its prefix re-prefills on
@@ -89,7 +92,12 @@ class Request:
         # emitted token trims the whole overlap away), so the replica-
         # local TTFT observation is still suppressed
         self.resumed = resume_tokens is not None
-        self.block_table = []       # pool block ids, position-ordered
+        # pool block ids, position-ordered: one table a block group of
+        # the pool (`Scheduler.admit` makes them); `behind[g]` leading
+        # entries of table g lie behind that group's window, have gone
+        # home and hold no block any more
+        self.block_tables = [[]]
+        self.behind = [0]
         # the request's entry in the pool's per-request planes (a
         # recurrent state), where the model has any
         self.state_slot = None
@@ -128,6 +136,14 @@ class Request:
     # (`in_flight`) has advanced `ctx` at its dispatch and reaches
     # `generated` when it is emitted, a step later: until then
     # ctx == feed_len - 1 + in_flight.
+    @property
+    def block_table(self):
+        """The table of the pool's first group (the whole context, where
+        any layer keeps it).  An alias from before the groups that two
+        older test lines read: to go with them at the next simplicity
+        pass."""
+        return self.block_tables[0]
+
     @property
     def feed_len(self):
         return len(self.prompt) + len(self.generated)
@@ -175,9 +191,13 @@ class Request:
 class Scheduler:
     """Admission / eviction / preemption against the block pool."""
 
-    def __init__(self, pool, max_running=8, promote_after=4):
+    def __init__(self, pool, max_running=8, promote_after=4, run_tokens=1):
         self.pool = pool
         self.max_running = int(max_running)
+        # the most tokens one program writes for a request (the engine's
+        # prefill chunk): with its window, what a request holds at most
+        # in a window's group
+        self.run_tokens = int(run_tokens)
         # skips (preemptions + head-blocked admit passes) before a
         # request is promoted out of the victim pool; 0/None disables
         self.promote_after = int(promote_after or 0)
@@ -205,18 +225,23 @@ class Scheduler:
                     "serving_state_slot_waits_total").inc()
                 break
             # blocks for the whole prefix to re/prefill plus one decode
-            # token, so admission can't strand a request mid-prefill
-            need = self.pool.blocks_for(req.feed_len + 1)
-            blocks = self.pool.allocate(need)
-            if blocks is None:
+            # token, so admission can't strand a request mid-prefill.  A
+            # window's group hands blocks out as the context reaches them
+            # (`reserve`) and takes them back behind the band (`trim`):
+            # there admission COUNTS what the running requests and this
+            # one hold at most, so the group cannot run dry under them
+            req.block_tables = [[] for _ in self.pool.groups]
+            req.behind = [0] * len(self.pool.groups)
+            if not (self._windows_hold(req) and self._extend(
+                    req, req.feed_len + 1, windows=False)):
                 # head-of-line blocks: stay FCFS, but count the skip —
                 # a head stuck behind LIFO-resumed work ages toward
                 # promotion just like a preemption victim
+                self._release(req)
                 req.admit_skips += 1
                 self._maybe_promote(req)
                 break
             self.waiting.popleft()
-            req.block_table = blocks
             req.state_slot = self.pool.allocate_slot()
             req.ctx = 0
             req.state = RUNNING
@@ -224,24 +249,80 @@ class Scheduler:
             admitted.append(req)
         return admitted
 
-    def grow(self, req):
-        """Ensure `req` has a block for the position its next decode
-        row writes (`ctx`: feed_len - 1, one further with a pick in
-        flight); preempts the youngest OTHER running request when the
-        pool is dry.  Returns False when no space could be made (req
-        should retry next step)."""
-        need_blocks = self.pool.blocks_for(req.ctx + 1)
-        while len(req.block_table) < need_blocks:
-            got = self.pool.allocate(1)
-            if got is not None:
-                req.block_table.extend(got)
+    def most_blocks(self, group, n_tokens):
+        """The most blocks of `group` a request of `n_tokens` positions
+        holds at a time: its whole table, or in a window's group its band
+        and one program's run."""
+        whole = self.pool.blocks_for(n_tokens)
+        band = self.pool.band_blocks(group, self.run_tokens)
+        return whole if band is None else min(whole, band)
+
+    def _windows_hold(self, req):
+        """Every window's group has room for what the running requests
+        and `req` hold at most, each at once."""
+        held = self.running + [req]
+        return all(
+            sum(self.most_blocks(g, len(r.prompt) + r.max_new_tokens)
+                for r in held) <= grp.num_blocks
+            for g, grp in enumerate(self.pool.groups)
+            if grp.window is not None)
+
+    def _extend(self, req, n_tokens, windows=True):
+        """Blocks in every group's table (a window's only with
+        `windows`) up to position `n_tokens`, or False when a group
+        cannot give them now.  What was got stays in the tables."""
+        need = self.pool.blocks_for(n_tokens)
+        for g, table in enumerate(req.block_tables):
+            if len(table) >= need or not (
+                    windows or self.pool.groups[g].window is None):
                 continue
+            got = self.pool.allocate(need - len(table), g)
+            if got is None:
+                return False
+            table.extend(got)
+        return True
+
+    def reserve(self, req, n_tokens):
+        """Ensure `req` has, in every group, the blocks of the positions
+        before `n_tokens` that a program is about to write; preempts the
+        youngest OTHER running request when a group is dry.  Returns
+        False when no space could be made (req should retry next
+        step)."""
+        while not self._extend(req, n_tokens):
             victim = self._pick_victim(exclude=req,
                                        allow_promoted=req.promoted)
             if victim is None:
                 return False
             self.preempt(victim)
         return True
+
+    def grow(self, req):
+        """`reserve` for the position the request's next decode row
+        writes (`ctx`: feed_len - 1, one further with a pick in
+        flight)."""
+        return self.reserve(req, req.ctx + 1)
+
+    def trim(self, req):
+        """Hand back the blocks of `req` that lie wholly behind their
+        group's window, by the context of the program last DISPATCHED:
+        a block is reused only by a program dispatched later, and
+        programs run in dispatch order.  The band keeps one position of
+        slack, so that a row the host feeds again (``ctx - 1``: its pick
+        was not the program's) still finds every key it sees.  Returns
+        the blocks freed."""
+        freed = 0
+        for g, grp in enumerate(self.pool.groups):
+            if grp.window is None:
+                continue
+            table, done = req.block_tables[g], req.behind[g]
+            upto = min(max(req.ctx - grp.window, 0) // self.pool.block_size,
+                       len(table))
+            if upto > done:
+                self.pool.free(table[done:upto], g)
+                table[done:upto] = [0] * (upto - done)
+                req.behind[g] = upto
+                freed += upto - done
+        return freed
 
     def _pick_victim(self, exclude, allow_promoted=False):
         """Youngest running request that isn't `exclude` and isn't
@@ -266,11 +347,23 @@ class Scheduler:
             _metrics.registry().counter(
                 "serving_starvation_promotions_total").inc()
 
+    def rewind(self, req):
+        """The request's context is built again from its first position
+        (a cache that cannot step back): the tables of the window groups,
+        whose early blocks have gone home, start empty again."""
+        for g, grp in enumerate(self.pool.groups):
+            if grp.window is not None:
+                self.pool.free(req.block_tables[g][req.behind[g]:], g)
+                req.block_tables[g], req.behind[g] = [], 0
+        req.ctx = 0
+
     def _release(self, req):
-        """The request's blocks and its state slot go home."""
-        if req.block_table:
-            self.pool.free(req.block_table)
-            req.block_table = []
+        """The request's blocks of every group and its state slot go
+        home."""
+        for g, table in enumerate(req.block_tables):
+            self.pool.free(table[req.behind[g]:], g)
+        req.block_tables = [[] for _ in req.block_tables]
+        req.behind = [0] * len(req.behind)
         if req.state_slot is not None:
             self.pool.free_slot(req.state_slot)
             req.state_slot = None

@@ -74,6 +74,21 @@ def kv_cache_planes(cfg):
     return [{"k": (hkv, hd), "v": (hkv, hd)}] * cfg.num_layers
 
 
+class LayerPlanes(dict):
+    """One layer's planes ({name: trailing shape per token | StatePlane})
+    with the KIND of lifetime its blocks have: `window` None, the whole
+    context stays (what a plain dict means too); a number, the layer
+    reads the last `window` positions alone, and the serving pool gives
+    the layers of that kind blocks, a free list and a table a request of
+    their own and takes a block back once it lies wholly behind the
+    band.  `kind` names the group in counters and refusals."""
+
+    def __init__(self, planes, kind=None, window=None):
+        super().__init__(planes)
+        self.window = None if window is None else int(window)
+        self.kind = kind or ("full" if window is None else "window")
+
+
 class StatePlane(collections.namedtuple("StatePlane", "shape dtype")):
     """A plane a layer caches per REQUEST, not per token (a recurrent
     state, a convolution's tail): its shape, and its dtype where that is

@@ -161,17 +161,14 @@ class LlamaAttention(nn.Layer):
             # block-paged pool (serving engine): write-then-attend via
             # the paged attention op; GQA kv heads stay unrepeated (the
             # pallas kernel groups via its kv index map, the fallback
-            # repeats inside sdpa_k)
-            if W:
-                raise NotImplementedError(
-                    "sliding_window does not compose with the paged "
-                    "serving cache (the pool keeps the full context); "
-                    "serve this model without paged attention")
+            # repeats inside sdpa_k).  A sliding window is the op's band:
+            # `cache_planes()` names it, and the pool takes back the
+            # blocks behind it
             from .decode import _update_paged_cache
             from ..ops import call as ops_call
             kp, vp = _update_paged_cache(cache, k, v)
             out = ops_call("paged_attention", q, kp, vp, cache["table"],
-                           cache["pos"])
+                           cache["pos"], window=W or None)
             return self.o_proj(out.reshape([b, s, -1]))
         if prealloc:
             from .decode import _update_prealloc_cache
@@ -284,8 +281,15 @@ class LlamaForCausalLM(nn.Layer):
     cache_op = "paged_attention"            # the op that reads the planes
 
     def cache_planes(self):
-        from .decode import kv_cache_planes
-        return kv_cache_planes(self.cfg)
+        """`k` and `v` per token a layer; under a sliding window every
+        layer is of the window kind, one block group whose blocks go
+        home behind the band."""
+        from .decode import LayerPlanes, kv_cache_planes
+        planes = kv_cache_planes(self.cfg)
+        if self.cfg.sliding_window:
+            planes = [LayerPlanes(p, window=self.cfg.sliding_window)
+                      for p in planes]
+        return planes
 
     def new_caches(self, batch_size, dtype="float32", max_length=None):
         from .. import tensor_api as T

@@ -91,6 +91,26 @@ def test_paged_decode_compiles(one_chip, dtype, H, Hkv, B, N, M):
     assert KERNEL in text
 
 
+@pytest.mark.parametrize("H,window,N", [
+    (72, 512, 3104), (48, None, 28000), (72, 8, 3104)],
+    ids=["window-72over8", "full-48over8", "window8-72over8"])
+def test_paged_decode_of_two_kinds_compiles(one_chip, H, window, N):
+    """The window-and-full cell's two decode shapes, 32 slots over a
+    table of 1,024 columns: 72 query heads over 8 kv heads under a band
+    of 512 (groups of 9; 72 rows of bfloat16 are no whole 16-row tiles)
+    from the window group's 3,104 blocks, and 48 over 8 (groups of 6)
+    over the whole context.  The banded kernel has a name of its own."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = s((32, 1, H, 128), jnp.bfloat16)
+    pool = s((N, 16, 8, 128), jnp.bfloat16)
+    assert pa.supports(q.shape, pool.shape, jnp.bfloat16)
+    text = _compiled_text(
+        functools.partial(pa.paged_decode_attention, window=window),
+        q, pool, pool, s((32, 1024), jnp.int32), s((32,), jnp.int32))
+    assert KERNEL in text
+    assert ("paged_window_decode_attention" in text) == (window is not None)
+
+
 @pytest.mark.parametrize("dtype,B,N,M", [
     (jnp.bfloat16, 32, 32000, 1024), (jnp.float32, 8, 512, 64)],
     ids=["cell-bf16", "small-f32"])
