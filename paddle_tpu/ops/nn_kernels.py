@@ -386,26 +386,13 @@ def paged_write_k(pool, val, tables, pos, limit, block_size=16):
     return pool.at[blk, off].set(val.astype(pool.dtype), mode="drop")
 
 
-# The most float32 scores [heads, chunk, table positions] of ONE row that
-# the XLA form below holds at once; a chunk over it is attended a block
-# of query rows at a time.  Why 1 GiB, and `>`: it is the largest such
-# array this form has run with on a 16 GB chip beside a deployment's
-# weights and pool (64 heads x 512 rows x 8,192 positions x 4 B, exactly
-# 1 GiB, in a serving cell since PR 32), so every program that ran
-# before the rule stays byte for byte what it was, and the first shape
-# that cannot fit (48 x 1,024 x 16,384 x 4 B = 3.2 GB beside 10.3 GB
-# resident) splits in four.  The free memory is not read here: the op
-# is traced where the pool's size is unknown.  ROADMAP R3 (i)'s prefill
-# kernel replaces both paths and this constant.
-_PAGED_SCORE_BYTES = 1 << 30
-
-
 @register("paged_attention", amp="allow")
 def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None,
                       window=None):
     """Decode/prefill attention over the paged KV pool — the jnp `take`
-    reference implementation (the pallas TPU kernel in
-    ops/pallas/paged_attention.py overrides this at import).
+    reference implementation (the pallas TPU kernels in
+    ops/pallas/paged_attention.py, one for a decode step and one for a
+    prefill chunk, override this at import).
 
     Gathers each row's blocks into a contiguous [b, M*bs, Hkv, D] window
     and runs the exact `sdpa_k` math under the paged length mask
@@ -448,21 +435,7 @@ def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None,
     else:
         cols = cols + first[:, :, None] * bs
         mask = (cols <= rows) & (cols > rows - window)
-    mask = mask[:, None, :, :]
-    # a long chunk over a long table: the float32 scores of all its rows
-    # at once would not fit beside a pool, so the rows go a block at a
-    # time (no block changes a number: a row's softmax is its own)
-    parts = 1
-    while q.shape[2] * (s // parts) * m * bs * 4 > _PAGED_SCORE_BYTES \
-            and (s // parts) % 2 == 0:
-        parts *= 2
-    if parts == 1:
-        return sdpa_k(q, K, V, mask=mask, scale=scale)
-    out = lax.map(
-        lambda qm: sdpa_k(qm[0], K, V, mask=qm[1], scale=scale),
-        (jnp.moveaxis(q.reshape((b, parts, s // parts) + q.shape[2:]), 1, 0),
-         jnp.moveaxis(mask.reshape(b, 1, parts, s // parts, m * bs), 2, 0)))
-    return jnp.moveaxis(out, 0, 1).reshape(q.shape[:3] + out.shape[-1:])
+    return sdpa_k(q, K, V, mask=mask[:, None, :, :], scale=scale)
 
 
 @register("paged_gather")

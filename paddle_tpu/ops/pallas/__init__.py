@@ -150,8 +150,10 @@ def _paged_kernel(q_shape, pool_shape, dtype):
 
 def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None,
                                 window=None):
-    """Serving decode steps stream blocks through the pallas kernel;
-    prefill chunks (s > 1) and unsupported shapes keep the XLA gather
+    """Serving programs walk a row's live blocks through a pallas
+    kernel: a decode step (one query row a request) through the decode
+    kernel, a prefill chunk (s > 1) through the prefill kernel; the
+    query's shape chooses.  Unsupported shapes keep the XLA gather
     fallback, which is also the parity reference.  Under a mesh the
     kernel sees its replica's local heads: q and the pool shard on "mp"
     (`BlockPool.shard_`), tables and positions are replicated."""
@@ -160,6 +162,10 @@ def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None,
         mode, split = served
 
         def kernel(q, k_pool, v_pool, tables, pos):
+            if q.shape[1] > 1:
+                return _pa.paged_prefill_attention(
+                    q, k_pool, v_pool, tables, pos, scale=scale,
+                    interpret=(mode == "interpret"), window=window)
             return _pa.paged_decode_attention(
                 q, k_pool, v_pool, tables, pos + 1, scale=scale,
                 interpret=(mode == "interpret"), window=window)
@@ -174,18 +180,21 @@ def paged_attention_with_pallas(q, k_pool, v_pool, tables, pos, scale=None,
 
 def paged_blocks_read(lens, table_cols, q_shape, pool_shape, dtype,
                       window=None):
-    """Pool blocks one `paged_attention` call reads for rows of visible
-    lengths `lens` (host numbers), by the path that serves those shapes:
-    the kernel's ragged walk (`walked_blocks`), or every column of every
-    row's table where the XLA fallback gathers (under a `window`: the
-    columns one token can see).  The gate is the one the call itself
-    takes; nothing is read back from the device."""
+    """Pool blocks one `paged_attention` call reads for rows whose last
+    query sees `lens` positions (host numbers; `q_shape[1]` queries a
+    row), by the path that serves those shapes: a kernel's ragged walk
+    (`walked_blocks`), or every column of every row's table where the
+    XLA fallback gathers (under a `window`: the columns the row's
+    queries can see).  The gate is the one the call itself takes;
+    nothing is read back from the device."""
+    queries = q_shape[1]
     if _paged_kernel(q_shape, pool_shape, dtype) is None:
-        if window is not None:      # the columns one token can see
-            table_cols = min(table_cols,
-                             _pa.band_blocks(window + 1, pool_shape[1]))
+        if window is not None:      # the columns the queries can see
+            table_cols = min(table_cols, _pa.band_blocks(
+                window + queries, pool_shape[1]))
         return len(lens) * table_cols
-    return _pa.walked_blocks(lens, table_cols, pool_shape[1], window)
+    return _pa.walked_blocks(lens, table_cols, pool_shape[1], window,
+                             queries)
 
 
 override("paged_attention", paged_attention_with_pallas)
@@ -250,16 +259,19 @@ _BLOCKS_READ = {"paged_attention": paged_blocks_read,
 
 
 def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype,
-                     window=None):
-    """Pool blocks one layer of a decode program of `rows` slots reads
-    for visible lengths `lens`, by the registered op `op` that the model
-    says reads its planes (`plane_shapes`: {name: one layer's array
-    shape}, the planes of one op alike; the last axis is the width a
-    query meets).  `window`: the layer's band, where its op takes one."""
+                     window=None, queries=1):
+    """Pool blocks one layer of a serving program of `rows` slots,
+    `queries` query tokens a slot (a decode program: 1; a prefill
+    program: its bucket), reads for rows whose last query sees `lens`
+    positions, by the registered op `op` that the model says reads its
+    planes (`plane_shapes`: {name: one layer's array shape}, the planes
+    of one op alike; the last axis is the width a query meets).
+    `window`: the layer's band, where its op takes one."""
     shape = next(iter(plane_shapes.values()))
     band = {} if window is None else {"window": window}
-    return _BLOCKS_READ[op](lens, table_cols, (rows, 1, heads, shape[-1]),
-                            shape, dtype, **band)
+    return _BLOCKS_READ[op](lens, table_cols,
+                            (rows, queries, heads, shape[-1]), shape, dtype,
+                            **band)
 
 
 _xla_grouped_matmul = get("grouped_matmul").fn

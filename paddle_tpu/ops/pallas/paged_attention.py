@@ -1,4 +1,5 @@
-"""Pallas TPU paged decode-attention — the serving hot path.
+"""Pallas TPU paged attention — the serving hot path: a decode step's
+kernel and a prefill chunk's.
 
 One decode step attends a request's whole context through its block
 table: the KV pool lives as [num_blocks, block_size, Hkv, D] arrays and
@@ -42,12 +43,40 @@ bfloat16, 64 rows, ~6,200 live blocks (the hybrid cell) 2.63 / 0.71;
 cells) 0.224 / 0.201; only a float32 pool at ONE query head a kv head,
 which no cell serves, lost (0.293 / 0.314).
 
-Decode-only (q seq len 1) and lane-aligned head dims only (D % 128 ==
-0; the pool is the replica's whole KV memory, so in-call padding would
-copy it per layer per step): prefill chunks and other head dims keep
-the XLA gather fallback, whose masked-sdpa math is the parity
-reference.  GQA: q head h reads kv head h // (H // Hkv); q goes in
-as [B, H, D], the model's own head order.
+Lane-aligned head dims only (D % 128 == 0; the pool is the replica's
+whole KV memory, so in-call padding would copy it per layer per step):
+other head dims keep the XLA gather fallback, whose masked-sdpa math is
+the parity reference.  GQA: q head h reads kv head h // (H // Hkv); q
+goes in as [B, H, D], the model's own head order.
+
+A PREFILL CHUNK (more than one query row a request) has a kernel of its
+own below, `paged_prefill_attention`: the same walk's rule (the first
+block under a band, `chunk_blocks`, `walked_blocks`) and another body,
+because the needs conflict.  A decode step is bound by the pool's
+bytes: few rows, all heads at once, Hkv times the useful multiplies for
+free.  A chunk is bound by its products (1,024 rows x 48 heads over
+11k positions are 290 GFLOP): a program takes a TILE of query positions
+(`prefill_tile`), walks the blocks that tile sees (whole key chunks
+above the causal diagonal or left of the band are skipped, not masked)
+and reduces a copied chunk A KV HEAD AT A TIME: the g query heads of a
+kv head ride as tq x g rows of one left operand against that head's
+keys, `[tq g, D] . [D, keys]` and `[tq g, keys] . [keys, D]`, online
+softmax in float32 scratch: ONE batched product over the kv heads, so
+that a process traces and lowers one step whatever the heads (written
+out head by head the kernel read 5% faster and took eight times the
+operations; a serving process traces a kernel for every bucket and
+layer kind before its first request, and set-up time is an end-to-end
+metric).  A kv head's rows lie Hkv apart in the copied chunk;
+Mosaic loads 32-bit rows with a stride and refuses 16-bit ones, and
+refuses a DMA of one kv head of a block (`k_hbm.at[blk, :, h]`: "Slice
+shape along dimension 2 must be aligned to tiling (8), but is 1"), so a
+16-bit pool's heads come apart in PAIRS through a 32-bit view of the
+buffer: the even head is the low half of each word, the odd one the
+high half, and a half moved to the top of the word IS that bfloat16 as
+a float32 (shift or mask, bitcast, convert: exact, ~5% of the body's
+vector work).  Bodies timed on the v5e at the window-and-full cell's
+full layer (PERF.md, PR 35).  The shape chooses the kernel (`supports`):
+nothing else does.
 """
 from __future__ import annotations
 
@@ -91,18 +120,23 @@ def chunk_blocks(table_cols, block_size, kv_heads, head_dim, dtype,
                       _CHUNK_VMEM // in_vmem))
 
 
-def walked_blocks(lens, table_cols, block_size, window=None):
-    """Pool blocks the kernel copies and reduces for rows of visible
-    lengths `lens` (host numbers): a row's walk ends with the block
-    that holds its last position (of its last chunk only the blocks it
-    lives in are copied, so the chunk does not enter), a dead slot
-    (length 1) walks one block, and no row walks past its table.  Under
-    a `window` the walk STARTS at the block that holds the first visible
-    position, ``max(0, len - window) // block_size``."""
+def walked_blocks(lens, table_cols, block_size, window=None, queries=1):
+    """Pool blocks a kernel copies and reduces for rows whose LAST query
+    sees `lens` positions (host numbers; a decode row has one query, a
+    prefill chunk `queries` of them, the first at ``len - queries``): a
+    row's walk ends with the block that holds its last position (of its
+    last chunk only the blocks it lives in are copied, so the chunk does
+    not enter), a dead slot (length 1) walks one block, and no row walks
+    past its table.  Under a `window` the walk STARTS at the block that
+    holds the first position its first query sees,
+    ``max(0, len - queries + 1 - window) // block_size``.  (The prefill
+    kernel walks a tile of query rows at a time and so meets a block
+    once a tile that sees it: this counts each block once.)"""
     total = 0
     for n in lens:
         n = max(int(n), 1)
-        first = 0 if window is None else max(n - window, 0) // block_size
+        first = 0 if window is None \
+            else max(n - queries + 1 - window, 0) // block_size
         total += max(min(-(-n // block_size), table_cols) - first, 0)
     return total
 
@@ -314,16 +348,255 @@ def _paged_decode(q, k_pool, v_pool, tables, lens, *, scale, interpret,
     return out.reshape(B, 1, H, D)
 
 
+# ---------------------------------------------------------------- prefill
+_TILE_ROWS = 12288          # (query position, head) rows one program holds
+_PREFILL_VMEM = 96 << 20    # of the v5e's 128 MiB; the default scope is 16
+
+
+def prefill_tile(s, heads):
+    """Query positions one program of the prefill kernel attends: the
+    chunk halved until its rows, positions x heads, are `_TILE_ROWS` at
+    most (and while the halves stay whole 16-row tiles)."""
+    tq = int(s)
+    while tq * heads > _TILE_ROWS and tq % 32 == 0:
+        tq //= 2
+    return tq
+
+
+def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
+                    kv_buf, sems, m_ref, l_ref, acc_ref, *,
+                    bs, chunk, g, tq, scale, window):
+    """The body is written in `lax` where `jnp` would do, and reduces
+    all kv heads in one batched product: a serving process traces and
+    lowers this kernel for every bucket and layer kind before its first
+    request, a `jnp` call costs a trace several times a primitive's,
+    and set-up time is an end-to-end metric."""
+    b, t = pl.program_id(0), pl.program_id(1)
+    cols = tables_ref.shape[1]
+    hkv, rows, _ = q_ref.shape[1:]              # rows: (position, head of g)
+    per_block = bs * hkv
+    keys = chunk * bs
+    dtype = kv_buf.dtype
+    exact = dtype == jnp.float32
+    precision = lax.Precision.HIGHEST if exact else None
+    f32 = jnp.float32
+
+    # the tile's query rows stand at positions p0 .. p1 of the context;
+    # its walk is the decode kernel's rule for a row whose first query
+    # sees p0 + 1 positions and whose last sees p1 + 1
+    p0 = pos_ref[b] + t * tq
+    p1 = p0 + tq - 1
+    first = 0 if window is None \
+        else jnp.maximum(p0 - (window - 1), 0) // bs
+    n_blocks = jnp.maximum(
+        jnp.minimum(pl.cdiv(p1 + 1, bs), cols) - first, 0)
+    n_chunks = pl.cdiv(n_blocks, chunk)
+
+    def copies(i, slot, start):
+        """Starts (or waits for) the copy of every block of the tile's
+        i-th chunk that the tile sees, K and V into one buffer."""
+        at = first + i * chunk
+
+        def one(c, _):
+            blk = tables_ref[b, at + c]
+            dst = pl.ds(pl.multiple_of(c * per_block, per_block), per_block)
+            for kv, hbm in enumerate((k_hbm, v_hbm)):
+                dma = pltpu.make_async_copy(
+                    hbm.at[blk], kv_buf.at[slot, kv, dst], sems.at[kv, slot])
+                dma.start() if start else dma.wait()
+            return _
+
+        lax.fori_loop(0, jnp.minimum(n_blocks - i * chunk, chunk), one, 0)
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, f32)
+    l_ref[...] = jnp.zeros(l_ref.shape, f32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
+
+    # the position of a query row, and of a chunk's column
+    q_pos = p0 + lax.broadcasted_iota(jnp.int32, (rows, keys), 0) // g
+    col = lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
+    score = (hkv, rows, keys)
+
+    def walk(i, slot):
+        # chunk i + 1 flies while chunk i is reduced (i = -1: the first)
+        @pl.when(i + 1 < n_chunks)
+        def _prefetch():
+            copies(i + 1, 1 - slot, True)
+
+        @pl.when(i >= 0)
+        def _reduce():
+            copies(i, slot, False)
+            reduce_chunk(i, slot)
+
+        return 1 - slot
+
+    def reduce_chunk(i, slot):
+        # a masked column's p is 0, and 0 x NaN is NaN in a product:
+        # what no copy wrote, and what the pool holds under a bucket's
+        # padding rows, is cleared in V before anything multiplies it
+        v = kv_buf[slot, 1]
+        sound = lax.le(lax.abs(lax.convert_element_type(v, f32)),
+                       jnp.finfo(f32).max)
+        kv_buf[slot, 1] = lax.select(sound, v, lax.full_like(v, 0))
+        # The chunk is the matrix [(block, token, kv head), D]: a kv
+        # head's rows lie hkv apart.  Rows of 32 bits are loaded with a
+        # stride (from a host number, so the heads come apart here, one
+        # by one); two kv heads share a 32-bit row of a 16-bit pool, the
+        # even one its low half: each half, moved to the top of the
+        # word, is that bfloat16 as a float32.  K and V go together.
+        ref = kv_buf.at[slot]
+        if hkv == 1:
+            heads = [ref[...]]
+        elif exact:
+            heads = [ref[:, pl.ds(h, keys, stride=hkv), :]
+                     for h in range(hkv)]
+        else:
+            words = ref.bitcast(jnp.uint32)
+            heads = []
+            for pair in range(hkv // 2):
+                w = words[:, pl.ds(pair, keys, stride=hkv // 2), :]
+                heads += [pltpu.bitcast(bits, f32) for bits in (
+                    lax.shift_left(w, jnp.uint32(16)),
+                    lax.bitwise_and(w, jnp.uint32(0xFFFF0000)))]
+        # [K | V, kv head, keys, D]
+        kv = lax.convert_element_type(
+            lax.concatenate([lax.expand_dims(h, (1,)) for h in heads], 1),
+            dtype)
+        # the chunk's columns are positions base .. base + keys - 1
+        at = lax.add(col, (first + i * chunk) * bs)
+        live = lax.le(at, q_pos)
+        if window is not None:
+            live = lax.bitwise_and(live, lax.gt(at, lax.sub(q_pos, window)))
+        # one step of the online softmax, every kv head's products in
+        # one batched product: nothing is written out head by head (a
+        # loop over the heads rolled two at a time read 57% slower than
+        # this on the v5e, written out 5% faster at eight times the
+        # operations to trace and lower)
+        s = lax.dot_general(q_ref[0], kv[0], (((2,), (2,)), ((0,), (0,))),
+                            precision=precision, preferred_element_type=f32)
+        s = lax.select(lax.broadcast_in_dim(live, score, (1, 2)),
+                       lax.mul(s, f32(scale)), lax.full(score, _NEG_INF, f32))
+        m_prev = m_ref[...]
+        m_new = lax.max(m_prev,
+                        lax.expand_dims(lax.reduce_max(s, (2,)), (2,)))
+        # a row that has seen nothing yet: exp(-inf - 0) is 0
+        m_at = lax.select(lax.eq(m_new, _NEG_INF),
+                          lax.full_like(m_new, 0), m_new)
+        p = lax.exp(lax.sub(s, lax.broadcast_in_dim(m_at, score, (0, 1, 2))))
+        corr = lax.exp(lax.sub(m_prev, m_at))               # masked p: 0
+        l_ref[...] = lax.add(lax.mul(l_ref[...], corr),
+                             lax.expand_dims(lax.reduce_sum(p, (2,)), (2,)))
+        acc_ref[...] = lax.add(
+            lax.mul(acc_ref[...],
+                    lax.broadcast_in_dim(corr, acc_ref.shape, (0, 1, 2))),
+            lax.dot_general(lax.convert_element_type(p, dtype), kv[1],
+                            (((2,), (1,)), ((0,), (0,))),
+                            precision=precision, preferred_element_type=f32))
+        m_ref[...] = m_new
+
+    lax.fori_loop(-1, n_chunks, walk, 1)
+
+    o_ref[0] = lax.convert_element_type(
+        lax.div(acc_ref[...],
+                lax.broadcast_in_dim(l_ref[...], acc_ref.shape, (0, 1, 2))),
+        o_ref.dtype)
+
+
+def paged_prefill_attention(q, k_pool, v_pool, tables, pos, scale=None,
+                            interpret=False, window=None):
+    """Paged attention of a chunk of `s` query rows a request.  q:
+    [B, s, H, D]; pools: [N, bs, Hkv, D]; tables: [B, M] int32 block
+    ids; pos: [B] int32, the context offset of a row's FIRST query: row
+    i sees the absolute positions ``<= pos + i`` (under `window`, a host
+    number, those in ``(pos + i - window, pos + i]``), and the chunk's
+    own K/V are in the pool already.  A request's walk reads the blocks
+    ``max(pos - (window - 1), 0) // bs`` (0 without a window) ..
+    ``cdiv(pos + s, bs) - 1`` of its table, clipped to its columns, and
+    nothing else: entries before the band may hold any id.  The kernel
+    carries the name ``paged_prefill_attention`` (under a window
+    ``paged_window_prefill_attention``) in a device trace.  Returns
+    [B, s, H, D] in the q dtype."""
+    D = q.shape[-1]
+    if not supports(q.shape, k_pool.shape, q.dtype):
+        raise ValueError(
+            f"paged_prefill_attention does not serve q {q.shape} over a "
+            f"pool {k_pool.shape} of {q.dtype}; the XLA fallback does")
+    scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
+    if window is not None and int(window) < 1:
+        raise ValueError(f"window={window} is no band")
+    return _paged_prefill(q, k_pool, v_pool, tables, pos, scale=scale,
+                          interpret=bool(interpret),
+                          window=None if window is None else int(window))
+
+
+# jitted as `_paged_decode` is: a model's layers of one kind and bucket
+# trace and lower ONE kernel
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
+def _paged_prefill(q, k_pool, v_pool, tables, pos, *, scale, interpret,
+                   window):
+    B, s, H, D = q.shape
+    N, bs, Hkv, _ = k_pool.shape
+    M = tables.shape[1]
+    g = H // Hkv
+    tq = prefill_tile(s, H)
+    # a tile under a band sees `window - 1 + tq` positions at most
+    chunk = chunk_blocks(M, bs, Hkv, D, k_pool.dtype,
+                         None if window is None else window - 1 + tq)
+    # the g query heads of a kv head ride as rows (position, head) of
+    # one left operand: [B, Hkv, s * g, D]
+    qh = q.reshape(B, s, Hkv, g, D).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, Hkv, s * g, D)
+    k_pool = k_pool.reshape(N, bs * Hkv, D)
+    v_pool = v_pool.reshape(N, bs * Hkv, D)
+    rows = tq * g
+
+    kernel = functools.partial(_prefill_kernel, bs=bs, chunk=chunk, g=g,
+                               tq=tq, scale=scale, window=window)
+    q_spec = pl.BlockSpec(
+        (1, Hkv, rows, D), lambda b, t, tables_ref, pos_ref: (b, 0, t, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, s // tq),
+        in_specs=[q_spec, pool_spec, pool_spec],
+        out_specs=q_spec,
+        scratch_shapes=[
+            # two slots of a chunk's K and V as they lie in the pool
+            pltpu.VMEM((2, 2, chunk * bs * Hkv, D), k_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+            pltpu.VMEM((Hkv, rows, D), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_PREFILL_VMEM),
+        interpret=interpret,
+        name="paged_prefill_attention" if window is None
+        else "paged_window_prefill_attention",
+    )(tables.astype(jnp.int32), pos.astype(jnp.int32), qh, k_pool, v_pool)
+    return out.reshape(B, Hkv, s, g, D).transpose(0, 2, 1, 3, 4) \
+        .reshape(B, s, H, D)
+
+
 def supports(q_shape, pool_shape, dtype, mp=1):
     """Shape/dtype gate for the pallas paged path; anything else keeps
     the jnp gather fallback (which is also the numerics reference).
     `mp` is the number of head shards the fleet mesh cuts the call into
-    (kv heads must divide, so every GQA group stays on one shard)."""
-    if len(q_shape) != 4 or q_shape[1] != 1:
-        return False        # decode-only: prefill chunks use the fallback
+    (kv heads must divide, so every GQA group stays on one shard).  One
+    query row a request (`q_shape[1] == 1`) is the decode kernel's, more
+    are the prefill kernel's: the shape chooses, nothing else does."""
+    if len(q_shape) != 4 or q_shape[1] < 1:
+        return False
     if dtype not in (jnp.float32, jnp.bfloat16):
         return False        # Mosaic: "Invalid vector type for load" (f16)
-    H, D = q_shape[2], q_shape[3]
+    s, H, D = q_shape[1], q_shape[2], q_shape[3]
     Hkv = pool_shape[2]
     if Hkv == 0 or H % Hkv or Hkv % mp:
         return False
@@ -332,4 +605,13 @@ def supports(q_shape, pool_shape, dtype, mp=1):
         # qwen2-7b ...): padding the POOL per call would copy the whole
         # KV memory every step, so other dims keep the gather fallback
         return False
+    if s > 1:
+        # a 16-bit pool's kv heads come apart in pairs (or there is
+        # one), and a chunk that cannot be halved into tiles has to fit
+        # the kernel's VMEM whole (twice `_TILE_ROWS` rows compile)
+        local = Hkv // mp
+        if dtype != jnp.float32 and local > 1 and local % 2:
+            return False
+        if prefill_tile(s, H // mp) * (H // mp) > 2 * _TILE_ROWS:
+            return False
     return True

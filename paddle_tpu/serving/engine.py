@@ -653,7 +653,8 @@ class LLMEngine:
         bucket = self.policy.bucket(n)
         with _trace.traced("serving.prefill", parent=parent, rid=req.id,
                            cat="serving") as span:
-            span.counts.update(tokens=n, ctx=req.ctx)
+            span.counts.update(tokens=n, ctx=req.ctx,
+                               **self._chunk_block_counts(req.ctx, n, bucket))
             feed = req.feed_tokens()
             chunk = feed[req.ctx:req.ctx + n]
             tokens = np.zeros((1, bucket), np.int32)
@@ -674,6 +675,23 @@ class LLMEngine:
                 self._chunk_loads.append((a, n))
         req.ctx += n
         self._reg.counter("serving_prefill_tokens_total").inc(n)
+
+    def _chunk_block_counts(self, ctx, n, bucket):
+        """How far a prefill program's attention follows its chunk, for
+        the chunk's span, summed over the pool's layer kinds: the blocks
+        that hold a position one of the chunk's `n` queries sees, and
+        the blocks a layer reads for the program's `bucket` query rows
+        on the path that serves it (a kernel's walk, or the fallback's
+        gather of a whole table or band)."""
+        bs = self.pool.block_size
+        live = walked = 0
+        for g, grp in enumerate(self.pool.groups):
+            behind = 0 if grp.window is None \
+                else max(ctx - (grp.window - 1), 0) // bs
+            live += self.pool.blocks_for(ctx + n) - behind
+            walked += self._blocks_read[g]([ctx + bucket], rows=1,
+                                           queries=bucket)
+        return dict(kv_blocks_live=live, kv_blocks_walked=walked)
 
     def _tables(self, reqs, rows=None):
         """The requests' block tables as a program takes them: one

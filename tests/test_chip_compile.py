@@ -111,6 +111,102 @@ def test_paged_decode_of_two_kinds_compiles(one_chip, H, window, N):
     assert ("paged_window_decode_attention" in text) == (window is not None)
 
 
+@pytest.mark.parametrize("S,H,Hkv,dtype,N,M,window", [
+    (1024, 48, 8, jnp.bfloat16, 28000, 1024, None),
+    (1024, 72, 8, jnp.bfloat16, 3104, 1024, 512),
+    (512, 64, 8, jnp.bfloat16, 16700, 512, None),
+    (512, 16, 16, jnp.bfloat16, 2600, 128, None),
+    (512, 16, 16, jnp.float32, 2600, 128, None),
+    (32, 72, 8, jnp.bfloat16, 3104, 1024, 512),
+    (32, 6, 1, jnp.bfloat16, 280, 34, None)],
+    ids=["laguna-full-48over8", "laguna-window-72over8",
+         "solar-gqa64over8", "gpt1.3B-bf16", "gpt1.3B-f32",
+         "smallest-bucket-window", "mqa6over1-smallest-bucket"])
+def test_paged_prefill_compiles(one_chip, S, H, Hkv, dtype, N, M, window):
+    """The prefill kernel at the cells' chunk shapes, one request a
+    program: the window-and-full cell's 1,024 rows of 48 heads over 8
+    over a table of 1,024 columns and of 72 over 8 under the band of
+    512, the hybrid cell's 512 rows of 64 over 8, the dense cells' 512
+    rows of 16 heads (a 16-bit pool's kv heads come apart in pairs
+    through a 32-bit view, a float32 pool's by a strided load), the
+    ladder's smallest bucket, and one kv head (a shard of few).  The
+    names are the prefill kernel's own: a decode reader's pattern that
+    matched one would divide decode work by prefill time."""
+    import re
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = s((1, S, H, 128), dtype)
+    pool = s((N, 16, Hkv, 128), dtype)
+    assert pa.supports(q.shape, pool.shape, dtype)
+    text = _compiled_text(
+        functools.partial(pa.paged_prefill_attention, window=window),
+        q, pool, pool, s((1, M), jnp.int32), s((1,), jnp.int32))
+    assert KERNEL in text
+    name = "paged_prefill_attention" if window is None \
+        else "paged_window_prefill_attention"
+    assert name in text
+    for pattern in (r"paged_decode|paged_attention",
+                    r"(?<!latent_)paged_decode_attention",
+                    r"paged_window_decode_attention",
+                    r"latent_paged_decode_attention"):
+        assert not re.search(pattern, name)
+
+
+def test_paged_prefill_gate_refuses_what_the_compiler_refuses(one_chip):
+    """A 16-bit pool of an odd number (> 1) of kv heads: two rows share
+    a 32-bit word and Mosaic loads 32-bit rows alone with a stride, so
+    the body takes kv heads apart in pairs and an odd one has no
+    partner; `supports` sends it to the XLA gather.  The same heads in
+    float32 compile, and so does a chunk of any length (3 rows)."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    shape = (1, 64, 6, 128), (280, 16, 3, 128)
+    assert not pa.supports(*shape, jnp.bfloat16)
+    with pytest.raises(ValueError, match="XLA fallback"):
+        pa.paged_prefill_attention(
+            s(shape[0], jnp.bfloat16), s(shape[1], jnp.bfloat16),
+            s(shape[1], jnp.bfloat16), s((1, 34), jnp.int32),
+            s((1,), jnp.int32))
+    assert pa.supports(*shape, jnp.float32)
+    q, pool = s(shape[0], jnp.float32), s(shape[1], jnp.float32)
+    assert KERNEL in _compiled_text(
+        pa.paged_prefill_attention, q, pool, pool, s((1, 34), jnp.int32),
+        s((1,), jnp.int32))
+    assert KERNEL in _compiled_text(
+        pa.paged_prefill_attention, s((1, 3, 6, 128), jnp.float32), pool,
+        pool, s((1, 34), jnp.int32), s((1,), jnp.int32))
+
+
+def test_a_decode_call_through_the_gate_is_the_decode_kernel_alone(
+        one_chip, monkeypatch):
+    """One query row a request takes the decode kernel and nothing of
+    the prefill path: the override's program for a decode step of the
+    window-and-full cell is, to the byte of its traced form, the decode
+    kernel's own on `pos + 1` (which this PR left the parent's:
+    CHANGES.md)."""
+    from paddle_tpu.ops import pallas as plo
+    monkeypatch.setattr(plo, "_mode", lambda: "tpu")
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q = s((32, 1, 48, 128), jnp.bfloat16)
+    pool = s((28000, 16, 8, 128), jnp.bfloat16)
+    tables, pos = s((32, 1024), jnp.int32), s((32,), jnp.int32)
+    through = _compiled_text(
+        lambda q, k, v, t, p: plo.paged_attention_with_pallas(q, k, v, t, p),
+        q, pool, pool, tables, pos)
+    assert "paged_decode_attention" in through
+    assert "prefill" not in through
+    # (a compiled text names source lines; the traced programs do not)
+    traced = [str(jax.make_jaxpr(f)(q, pool, pool, tables, pos)) for f in (
+        lambda q, k, v, t, p: plo.paged_attention_with_pallas(q, k, v, t, p),
+        lambda q, k, v, t, p: pa.paged_decode_attention(q, k, v, t, p + 1))]
+    assert traced[0] == traced[1]
+    # ... and more rows a request take the prefill kernel, no threshold
+    chunk = _compiled_text(
+        lambda q, k, v, t, p: plo.paged_attention_with_pallas(q, k, v, t, p),
+        s((1, 32, 48, 128), jnp.bfloat16), pool, pool,
+        s((1, 1024), jnp.int32), s((1,), jnp.int32))
+    assert "paged_prefill_attention" in chunk
+    assert "paged_decode_attention" not in chunk
+
+
 @pytest.mark.parametrize("dtype,B,N,M", [
     (jnp.bfloat16, 32, 32000, 1024), (jnp.float32, 8, 512, 64)],
     ids=["cell-bf16", "small-f32"])

@@ -1069,7 +1069,17 @@ def test_paged_attention_supports_gate():
     from paddle_tpu.ops.pallas import paged_attention as pa
     ok = ((3, 1, 4, 128), (12, 8, 2, 128))
     assert pa.supports(*ok, jnp.float32)
-    assert not pa.supports((3, 2, 4, 128), ok[1], jnp.float32)  # prefill
+    # more query rows a request are the prefill kernel's, any number of
+    # them; a 16-bit pool's kv heads come apart in pairs (or there is
+    # one), and a chunk that cannot be cut in tiles has to fit whole
+    assert pa.supports((3, 2, 4, 128), ok[1], jnp.float32)
+    assert pa.supports((1, 1000, 24, 128), (12, 8, 8, 128), jnp.bfloat16)
+    assert not pa.supports((1, 1032, 24, 128), (12, 8, 8, 128),
+                           jnp.bfloat16)        # 1,032 = 8 x 129 rows
+    assert pa.supports((1, 64, 6, 128), (12, 8, 3, 128), jnp.float32)
+    assert not pa.supports((1, 64, 6, 128), (12, 8, 3, 128), jnp.bfloat16)
+    assert pa.supports((1, 64, 6, 128), (12, 8, 1, 128), jnp.bfloat16)
+    assert pa.supports((1, 64, 8, 128), (12, 8, 4, 128), jnp.bfloat16, mp=2)
     assert pa.supports(ok[0], (12, 6, 2, 128), jnp.float32)  # any bs
     assert not pa.supports(ok[0], ok[1], jnp.float16)  # Mosaic refuses
     assert not pa.supports(ok[0], (12, 8, 3, 128), jnp.float32)  # H % Hkv

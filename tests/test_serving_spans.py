@@ -135,11 +135,17 @@ def test_the_root_counts_the_rows_it_decoded_and_a_prefill_its_chunk(
             <= served["slots"] * served["table_cols"]
         assert counts["kv_blocks_walked"] \
             == (served["slots"] * served["table_cols"] if rows else 0)
-        # a prefill counts its chunk's tokens and the context it started
-        # at; the other phases count nothing
+        # a prefill counts its chunk's tokens, the context it started
+        # at, the blocks (of 8) its queries see and the blocks its
+        # program reads (on the CPU the gather: the whole table); the
+        # other phases count nothing
         chunks = [c[F["counts"]] for c in kids
                   if c[F["name"]] == "serving.prefill"]
-        assert all(sorted(c) == ["ctx", "tokens"] for c in chunks)
+        assert all(sorted(c) == ["ctx", "kv_blocks_live", "kv_blocks_walked",
+                                 "tokens"] for c in chunks)
+        assert all(c["kv_blocks_live"] == -(-(c["ctx"] + c["tokens"]) // 8)
+                   and c["kv_blocks_walked"] == served["table_cols"]
+                   for c in chunks)
         assert sum(c["tokens"] for c in chunks) == summary["prefilled"]
         assert all(c[F["counts"]] == {} for c in kids
                    if c[F["name"]] != "serving.prefill")
