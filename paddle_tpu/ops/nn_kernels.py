@@ -438,11 +438,10 @@ def paged_attention_k(q, k_pool, v_pool, tables, pos, scale=None,
     return sdpa_k(q, K, V, mask=mask[:, None, :, :], scale=scale)
 
 
-@register("paged_gather")
 def paged_gather_k(pool, tables):
     """A row's blocks as one contiguous window: pool [N, bs, ...],
-    tables [b, M] -> [b, M * bs, ...] (what `paged_attention` attends;
-    a prefill chunk over a latent pool expands K and V from it)."""
+    tables [b, M] -> [b, M * bs, ...] (what the latent gather reference
+    attends)."""
     b, m = tables.shape
     rows = jnp.take(pool, tables.astype(jnp.int32).reshape(-1), axis=0)
     return rows.reshape((b, m * pool.shape[1]) + pool.shape[2:])
@@ -460,7 +459,7 @@ def paged_visible(s, length, pos):
 def latent_paged_attention_k(q, pool, tables, pos, value_dim, scale=None):
     """Attention of ABSORBED queries over a latent paged pool -- the jnp
     gather reference (ops/pallas/latent_paged_attention.py overrides it
-    for decode steps on TPU).
+    on TPU: a decode step's kernel and a prefill chunk's).
 
     One cached row serves every head as key (all of its `W` columns) and
     as value (its first `value_dim` columns): q [b, s, H, W], pool

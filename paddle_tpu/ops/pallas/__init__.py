@@ -218,16 +218,22 @@ def _latent_kernel(q_shape, pool_shape, value_dim, dtype):
 
 def latent_paged_attention_with_pallas(q, pool, tables, pos, value_dim,
                                        scale=None):
-    """Decode steps walk the latent pool through the pallas kernel;
-    chunks of more than one token and unsupported shapes keep the XLA
-    gather, which is also the parity reference.  Under a mesh the
-    queries' heads shard on "mp"; the pool's rows serve every head and
-    are replicated, as are tables and positions."""
+    """Serving programs walk a row's live blocks of the latent pool
+    through a pallas kernel: a decode step (one query row a request)
+    through the decode kernel, a prefill chunk (s > 1) through the
+    prefill kernel; the query's shape chooses.  Unsupported shapes keep
+    the XLA gather, which is also the parity reference.  Under a mesh
+    the queries' heads shard on "mp"; the pool's rows serve every head
+    and are replicated, as are tables and positions."""
     served = _latent_kernel(q.shape, pool.shape, value_dim, q.dtype)
     if served is not None:
         mode, split = served
 
         def kernel(q, pool, tables, pos):
+            if q.shape[1] > 1:
+                return _la.latent_paged_prefill_attention(
+                    q, pool, tables, pos, value_dim, scale=scale,
+                    interpret=(mode == "interpret"))
             return _la.latent_paged_decode_attention(
                 q, pool, tables, pos + 1, value_dim, scale=scale,
                 interpret=(mode == "interpret"))
@@ -242,13 +248,15 @@ def latent_paged_attention_with_pallas(q, pool, tables, pos, value_dim,
 
 def latent_blocks_read(lens, table_cols, q_shape, pool_shape, dtype):
     """As `paged_blocks_read`, for a `latent_paged_attention` call: the
-    latent kernel's own walk (`latent_paged_attention.walked_blocks`), or
-    every column of every row's table where the XLA fallback gathers.
-    The values are the leading lanes of a row: for the gate, any whole
-    lane tiles of it, so the row's own width stands in."""
+    latent kernels' walk (`walked_blocks`, the decode kernel's for one
+    query a row, the prefill kernel's for more), or every column of
+    every row's table where the XLA fallback gathers.  The values are
+    the leading lanes of a row: for the gate, any whole lane tiles of
+    it, so the row's own width stands in."""
     if _latent_kernel(q_shape, pool_shape, pool_shape[2], dtype) is None:
         return len(lens) * table_cols
-    return _la.walked_blocks(lens, table_cols, pool_shape[1])
+    return _la.walked_blocks(lens, table_cols, pool_shape[1],
+                             queries=q_shape[1])
 
 
 override("latent_paged_attention", latent_paged_attention_with_pallas)

@@ -76,7 +76,9 @@ high half, and a half moved to the top of the word IS that bfloat16 as
 a float32 (shift or mask, bitcast, convert: exact, ~5% of the body's
 vector work).  Bodies timed on the v5e at the window-and-full cell's
 full layer (PERF.md, PR 35).  The shape chooses the kernel (`supports`):
-nothing else does.
+nothing else does.  The tile's walk (`PrefillWalk`) is shared with the
+latent pool's prefill kernel (`latent_paged_attention.py`), whose body
+is its own.
 """
 from __future__ import annotations
 
@@ -353,14 +355,72 @@ _TILE_ROWS = 12288          # (query position, head) rows one program holds
 _PREFILL_VMEM = 96 << 20    # of the v5e's 128 MiB; the default scope is 16
 
 
-def prefill_tile(s, heads):
+def prefill_tile(s, heads, rows=None):
     """Query positions one program of the prefill kernel attends: the
-    chunk halved until its rows, positions x heads, are `_TILE_ROWS` at
-    most (and while the halves stay whole 16-row tiles)."""
+    chunk halved until its rows, positions x heads, are `rows` at most
+    (`_TILE_ROWS` unless a kernel of wider rows says less), and while
+    the halves stay whole 16-row tiles."""
+    rows = _TILE_ROWS if rows is None else rows
     tq = int(s)
-    while tq * heads > _TILE_ROWS and tq % 32 == 0:
+    while tq * heads > rows and tq % 32 == 0:
         tq //= 2
     return tq
+
+
+class PrefillWalk:
+    """The walk of one program of a prefill kernel, shared by the K/V
+    kernel below and the latent pool's (`latent_paged_attention.py`):
+    the program (b, t) holds query positions ``p0 .. p1`` of request b
+    (tile t of `tq`), and walks the blocks ``first .. first + n_blocks
+    - 1`` of its table -- the decode kernel's rule for a row whose first
+    query sees p0 + 1 positions and whose last sees p1 + 1 -- in chunks
+    of `chunk` blocks, two buffers deep.  What a block's copy is and how
+    a copied chunk is reduced are the body's (`run`)."""
+
+    def __init__(self, tables_ref, pos_ref, *, tq, bs, chunk, window):
+        self.tables_ref, self.chunk = tables_ref, chunk
+        self.b, t = pl.program_id(0), pl.program_id(1)
+        cols = tables_ref.shape[1]
+        self.p0 = pos_ref[self.b] + t * tq
+        p1 = self.p0 + tq - 1
+        self.first = 0 if window is None \
+            else jnp.maximum(self.p0 - (window - 1), 0) // bs
+        self.n_blocks = jnp.maximum(
+            jnp.minimum(pl.cdiv(p1 + 1, bs), cols) - self.first, 0)
+        self.n_chunks = pl.cdiv(self.n_blocks, chunk)
+
+    def run(self, copy_block, reduce_chunk):
+        """`copy_block(blk, c, slot, start)` starts (or waits for) the
+        copy of pool block `blk` into place `c` of buffer `slot`;
+        `reduce_chunk(i, slot)` reduces the tile's i-th chunk, copied
+        whole into buffer `slot` (of its last chunk only the blocks the
+        tile sees: the rest of the buffer holds what it held)."""
+        chunk, n_blocks, n_chunks = self.chunk, self.n_blocks, self.n_chunks
+
+        def copies(i, slot, start):
+            at = self.first + i * chunk
+
+            def one(c, _):
+                copy_block(self.tables_ref[self.b, at + c], c, slot, start)
+                return _
+
+            lax.fori_loop(0, jnp.minimum(n_blocks - i * chunk, chunk),
+                          one, 0)
+
+        def walk(i, slot):
+            # chunk i + 1 flies while chunk i is reduced (i = -1: the first)
+            @pl.when(i + 1 < n_chunks)
+            def _prefetch():
+                copies(i + 1, 1 - slot, True)
+
+            @pl.when(i >= 0)
+            def _reduce():
+                copies(i, slot, False)
+                reduce_chunk(i, slot)
+
+            return 1 - slot
+
+        lax.fori_loop(-1, n_chunks, walk, 1)
 
 
 def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
@@ -371,8 +431,8 @@ def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     lowers this kernel for every bucket and layer kind before its first
     request, a `jnp` call costs a trace several times a primitive's,
     and set-up time is an end-to-end metric."""
-    b, t = pl.program_id(0), pl.program_id(1)
-    cols = tables_ref.shape[1]
+    walker = PrefillWalk(tables_ref, pos_ref, tq=tq, bs=bs, chunk=chunk,
+                         window=window)
     hkv, rows, _ = q_ref.shape[1:]              # rows: (position, head of g)
     per_block = bs * hkv
     keys = chunk * bs
@@ -380,55 +440,24 @@ def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
     exact = dtype == jnp.float32
     precision = lax.Precision.HIGHEST if exact else None
     f32 = jnp.float32
+    first = walker.first
 
-    # the tile's query rows stand at positions p0 .. p1 of the context;
-    # its walk is the decode kernel's rule for a row whose first query
-    # sees p0 + 1 positions and whose last sees p1 + 1
-    p0 = pos_ref[b] + t * tq
-    p1 = p0 + tq - 1
-    first = 0 if window is None \
-        else jnp.maximum(p0 - (window - 1), 0) // bs
-    n_blocks = jnp.maximum(
-        jnp.minimum(pl.cdiv(p1 + 1, bs), cols) - first, 0)
-    n_chunks = pl.cdiv(n_blocks, chunk)
-
-    def copies(i, slot, start):
-        """Starts (or waits for) the copy of every block of the tile's
-        i-th chunk that the tile sees, K and V into one buffer."""
-        at = first + i * chunk
-
-        def one(c, _):
-            blk = tables_ref[b, at + c]
-            dst = pl.ds(pl.multiple_of(c * per_block, per_block), per_block)
-            for kv, hbm in enumerate((k_hbm, v_hbm)):
-                dma = pltpu.make_async_copy(
-                    hbm.at[blk], kv_buf.at[slot, kv, dst], sems.at[kv, slot])
-                dma.start() if start else dma.wait()
-            return _
-
-        lax.fori_loop(0, jnp.minimum(n_blocks - i * chunk, chunk), one, 0)
+    def copy_block(blk, c, slot, start):
+        # K and V of a block into one buffer
+        dst = pl.ds(pl.multiple_of(c * per_block, per_block), per_block)
+        for kv, hbm in enumerate((k_hbm, v_hbm)):
+            dma = pltpu.make_async_copy(
+                hbm.at[blk], kv_buf.at[slot, kv, dst], sems.at[kv, slot])
+            dma.start() if start else dma.wait()
 
     m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, f32)
     l_ref[...] = jnp.zeros(l_ref.shape, f32)
     acc_ref[...] = jnp.zeros(acc_ref.shape, f32)
 
     # the position of a query row, and of a chunk's column
-    q_pos = p0 + lax.broadcasted_iota(jnp.int32, (rows, keys), 0) // g
+    q_pos = walker.p0 + lax.broadcasted_iota(jnp.int32, (rows, keys), 0) // g
     col = lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
     score = (hkv, rows, keys)
-
-    def walk(i, slot):
-        # chunk i + 1 flies while chunk i is reduced (i = -1: the first)
-        @pl.when(i + 1 < n_chunks)
-        def _prefetch():
-            copies(i + 1, 1 - slot, True)
-
-        @pl.when(i >= 0)
-        def _reduce():
-            copies(i, slot, False)
-            reduce_chunk(i, slot)
-
-        return 1 - slot
 
     def reduce_chunk(i, slot):
         # a masked column's p is 0, and 0 x NaN is NaN in a product:
@@ -494,7 +523,7 @@ def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                             precision=precision, preferred_element_type=f32))
         m_ref[...] = m_new
 
-    lax.fori_loop(-1, n_chunks, walk, 1)
+    walker.run(copy_block, reduce_chunk)
 
     o_ref[0] = lax.convert_element_type(
         lax.div(acc_ref[...],

@@ -9,13 +9,14 @@ the TPU's tiled memory anyway, and what lets a decode kernel take a pool
 block as one aligned tile (`cache_width`).  Two forms of the same
 mathematics, the published split:
 
-* **expanded** (plain forward, and every chunk of more than one token):
+* **expanded** (plain forward, and the preallocated and growing caches):
   K and V of the visible context are materialised from the latent rows
   through ``W_kvb`` and attended by `sdpa`;
-* **absorbed** (one new token a row, the decode step): ``W_kvb``'s key
-  half moves into the query and its value half behind the softmax, so
-  the scores and the weighted sum run on the latent rows themselves
-  (op `latent_paged_attention` over the serving pool).
+* **absorbed** (everything over the serving pool: a decode step's one
+  token a row and a prefill chunk's many): ``W_kvb``'s key half moves
+  into the query and its value half behind the softmax, so the scores
+  and the weighted sum run on the latent rows themselves (op
+  `latent_paged_attention`, whose kernels walk a row's live blocks).
 
 RoPE turns interleaved pairs ``(2i, 2i + 1)`` (as `text/llama.py`).
 `q_lora_rank` (a low-rank query) is not implemented: the published
@@ -195,8 +196,9 @@ class DeepseekV3Attention(nn.Layer):
         return out.reshape([b, q.shape[1], heads * cfg.v_head_dim])
 
     def _absorbed(self, q, pool, table, pos):
-        """One token a row against the latent pool: W_kvb's key half goes
-        into the query, its value half behind the weighted sum."""
+        """A row's tokens (s of them from context offset `pos`) against
+        the latent pool: W_kvb's key half goes into the query, its value
+        half behind the weighted sum."""
         cfg = self.cfg
         heads, nope, vd = cfg.num_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
         lora, width = cfg.kv_lora_rank, cfg.cache_width
@@ -240,14 +242,7 @@ class DeepseekV3Attention(nn.Layer):
             cache["kv"] = ops_call(
                 "paged_write", cache["kv"], self._latent(x, positions),
                 cache["table"], pos, cache["limit"], block_size=bs)
-            if s == 1:
-                out = self._absorbed(q, cache["kv"], cache["table"], pos)
-            else:
-                rows = ops_call("paged_gather", cache["kv"], cache["table"])
-                mask = engine.apply(
-                    "paged_mask", lambda p, s_, n: _paged_mask(s_, n, p),
-                    [pos], {"s_": s, "n": rows.shape[1]})
-                out = self._expanded(q, rows, mask=mask)
+            out = self._absorbed(q, cache["kv"], cache["table"], pos)
             return self.o_proj(out)
         if "pos" in cache:
             # preallocated rows (jitted decode): write at the offset,

@@ -224,6 +224,67 @@ def test_latent_paged_decode_compiles(one_chip, dtype, B, N, M):
     assert KERNEL in text
 
 
+@pytest.mark.parametrize("S,dtype", [
+    (1024, jnp.bfloat16), (32, jnp.bfloat16), (512, jnp.float32)],
+    ids=["cell-largest-bucket", "cell-smallest-bucket", "f32"])
+def test_latent_paged_prefill_compiles_through_the_gate(
+        one_chip, monkeypatch, S, dtype):
+    """The latent cell's chunk shapes, one request a program: a bucket
+    of S rows of 16 heads over rows of [c 512 | k_rope 64 | 64 zeros]
+    from a pool of 33,000 blocks, a table of 1,024 columns.  Through the
+    op's override, as the model calls it: more than one query row a
+    request is the prefill kernel, under a name of its own that the
+    decode reader's pattern does not match; a float32 pool takes a
+    smaller tile (`prefill_tile`), within the same VMEM."""
+    import re
+    from paddle_tpu.ops import pallas as plo
+    monkeypatch.setattr(plo, "_mode", lambda: "tpu")
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    q, pool = s((1, S, 16, 640), dtype), s((33000, 16, 640), dtype)
+    assert la.supports(q.shape, pool.shape, 512, dtype)
+    text = _compiled_text(
+        lambda q, pool, t, p: plo.latent_paged_attention_with_pallas(
+            q, pool, t, p, 512, scale=192 ** -0.5),
+        q, pool, s((1, 1024), jnp.int32), s((1,), jnp.int32))
+    assert KERNEL in text
+    assert "latent_paged_prefill_attention" in text
+    assert "latent_paged_decode_attention" not in text
+    from benchmark.metrics import latent_paged_roofline
+    assert not re.search(latent_paged_roofline.PATTERN,
+                         "latent_paged_prefill_attention")
+
+
+# what `paged_prefill_attention` traced to before its walk was shared
+# with the latent kernel (sha256 of the jaxpr's text, first 16 digits;
+# a jaxpr names no source file, so any checkout hashes the same under
+# the same jax)
+_PAGED_PREFILL_JAXPR = {
+    "gpt1.3B": ((512, 16, 16, 2600, 128, None), "cb29921960d3b86b"),
+    "solar-gqa64over8": ((512, 64, 8, 16700, 512, None), "caefe38b4372ff57"),
+    "laguna-full-48over8": ((1024, 48, 8, 28000, 1024, None),
+                            "642e9b2b4d5bb0fa"),
+    "laguna-window-72over8": ((1024, 72, 8, 3104, 1024, 512),
+                              "903f5eae2cae1f04"),
+}
+
+
+@pytest.mark.parametrize("case", list(_PAGED_PREFILL_JAXPR))
+def test_the_shared_walk_leaves_the_kv_prefill_kernel_as_it_was(case):
+    """The K/V prefill kernel at the dense, hybrid and window-and-full
+    cells' chunk shapes traces to the program it traced to before the
+    walk became `PrefillWalk`, to the byte: the cells that run it run
+    what they ran."""
+    import hashlib
+    (S, H, Hkv, N, M, window), want = _PAGED_PREFILL_JAXPR[case]
+    s = jax.ShapeDtypeStruct
+    q, pool = s((1, S, H, 128), jnp.bfloat16), s((N, 16, Hkv, 128),
+                                                  jnp.bfloat16)
+    text = str(jax.make_jaxpr(functools.partial(
+        pa.paged_prefill_attention, window=window))(
+            q, pool, pool, s((1, M), jnp.int32), s((1,), jnp.int32)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
 @pytest.mark.parametrize("rows", [192, 6144], ids=["decode", "chunk"])
 def test_dropless_experts_compile_as_grouped_products(one_chip, rows):
     """`moe_dropless` at the latent cell's widths (64 experts of 2048 x

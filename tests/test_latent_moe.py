@@ -106,9 +106,9 @@ def test_generate_follows_the_reference_argmax(small, use_jit):
     (SMALL, None, 4), (LANED, "interpret", 8)], ids=["gather", "kernel"])
 def test_served_logits_equal_the_references_full_forward(
         cfg, pallas, block, monkeypatch):
-    """Prefill in chunks (expanded form) + decode from the latent pool
-    (absorbed form; the XLA gather, or the pallas kernel interpreted) =
-    the reference's full forward at every served position."""
+    """Prefill in chunks + decode from the latent pool (absorbed form;
+    the XLA gather, or the pallas kernels interpreted) = the reference's
+    full forward at every served position."""
     if pallas:
         monkeypatch.setenv("PADDLE_TPU_PALLAS", pallas)
     model, weights = build(cfg)
@@ -223,7 +223,7 @@ def test_the_kernels_walk_and_gate():
     bf16, f32 = jnp.bfloat16, jnp.float32
     real = ((32, 1, 16, 640), (32000, 16, 640), 512)
     assert la.supports(*real, bf16) and la.supports(*real, f32)
-    assert not la.supports((1, 8, 16, 640), real[1], 512, bf16)   # prefill
+    assert la.supports((1, 8, 16, 640), real[1], 512, bf16)   # a chunk
     assert not la.supports(real[0], real[1], 512, jnp.float16)
     assert not la.supports((32, 1, 16, 576), (32000, 16, 576), 512, bf16)
     assert not la.supports(real[0], real[1], 576, bf16)     # c unaligned
