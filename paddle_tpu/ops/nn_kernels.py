@@ -477,6 +477,52 @@ def latent_paged_attention_k(q, pool, tables, pos, value_dim, scale=None):
     return jnp.einsum("bhsl,blv->bshv", probs, rows[..., :value_dim])
 
 
+def indexer_scores(q_idx, w_idx, keys):
+    """A lightning indexer's scores, float32: q_idx [b, s, h, W], w_idx
+    [b, s, h], keys [b, L, W] (one key head) -> [b, s, L] with
+    ``I[t, l] = sum_j w[t, j] * relu(q[t, j] . k[l])``."""
+    f32 = jnp.float32
+    dots = jnp.einsum("bshw,blw->bshl", q_idx.astype(f32), keys.astype(f32))
+    return jnp.einsum("bshl,bsh->bsl", jnp.maximum(dots, 0.0),
+                      w_idx.astype(f32))
+
+
+def sparse_select(scores, topk):
+    """[b, s, L] bool: the `topk` positions of the largest scores in each
+    query's row (`lax.top_k`'s, the lower position first on a tie); a
+    position a query cannot see carries -inf, and is picked only where
+    the row holds fewer than `topk` it can see (the caller masks those)."""
+    b, s, length = scores.shape
+    top = jax.lax.top_k(scores, min(int(topk), length))[1]
+    return jnp.zeros(scores.shape, bool).at[
+        jnp.arange(b)[:, None, None], jnp.arange(s)[None, :, None],
+        top].set(True)
+
+
+@register("sparse_paged_attention", amp="allow")
+def sparse_paged_attention_k(q, k_pool, v_pool, ik_pool, q_idx, w_idx,
+                             tables, pos, topk, scale=None):
+    """Attention of each query over the `topk` cached positions a learned
+    indexer picks (DeepSeek-V3.2's sparse attention over a GQA pool) --
+    the jnp gather reference (ops/pallas/sparse_attention.py overrides
+    it on TPU).
+
+    q [b, s, H, D] over K/V pools [N, bs, Hkv, D]; the indexer's queries
+    q_idx [b, s, h, W] and weights w_idx [b, s, h] over its key pool
+    ik_pool [N, bs, W]; tables [b, M]; query row i of a request at
+    context offset pos sees positions <= pos + i, and attends the `topk`
+    of them with the largest indexer scores (`sparse_select`).  Every
+    head of a query shares its selection."""
+    s = q.shape[1]
+    keys = paged_gather_k(ik_pool, tables)                    # [b, L, W]
+    seen = paged_visible(s, keys.shape[1], pos)
+    scores = jnp.where(seen, indexer_scores(q_idx, w_idx, keys), -jnp.inf)
+    mask = seen & sparse_select(scores, topk)
+    K = paged_gather_k(k_pool, tables)
+    V = paged_gather_k(v_pool, tables)
+    return sdpa_k(q, K, V, mask=mask[:, None], scale=scale)
+
+
 # ------------------------------------------------- grouped products (MoE)
 @register("grouped_matmul")
 def grouped_matmul_k(rows, weights, group_sizes):

@@ -22,6 +22,7 @@ from . import flash_attention as _fa
 from . import kda as _kda
 from . import latent_paged_attention as _la
 from . import paged_attention as _pa
+from . import sparse_attention as _sa
 
 
 def _mode():
@@ -262,8 +263,126 @@ def latent_blocks_read(lens, table_cols, q_shape, pool_shape, dtype):
 override("latent_paged_attention", latent_paged_attention_with_pallas)
 
 
+_xla_sparse_paged_attention = get("sparse_paged_attention").fn
+
+
+def _sparse_kernel(q_shape, pool_shape, ik_shape, dtype):
+    """The kernels' mode where they serve a `sparse_paged_attention` call
+    of these shapes, here and now; None where the XLA form does (CPU,
+    PADDLE_TPU_PALLAS=0, a fleet mesh, shapes the kernels do not take)."""
+    mode = _mode()
+    if mode is None or _mesh_split() is not None \
+            or not _sa.supports(q_shape, pool_shape, ik_shape, dtype) \
+            or not _pa.supports(q_shape, pool_shape, dtype):
+        return None
+    return mode
+
+
+def sparse_paged_attention_with_pallas(q, k_pool, v_pool, ik_pool, q_idx,
+                                       w_idx, tables, pos, topk, scale=None):
+    """On TPU the indexer's kernel scores the positions a query sees
+    (`sparse_attention.indexer_scores`) and the attention reads the
+    picks: a decode step XLA's top `topk` positions' K and V rows alone
+    (`sparse_attention.picked_attention`), a prefill chunk the prefill kernel's
+    walk with each query's threshold, its `topk`-th largest score
+    (`sparse_attention.topk_threshold`; `paged_prefill_attention(
+    picks=)`).  Anything else keeps the XLA
+    form, which is also the parity reference."""
+    mode = _sparse_kernel(q.shape, k_pool.shape, ik_pool.shape, q.dtype)
+    if mode is None:
+        return _xla_sparse_paged_attention(q, k_pool, v_pool, ik_pool, q_idx,
+                                           w_idx, tables, pos, topk,
+                                           scale=scale)
+    interpret = mode == "interpret"
+    scores = _sa.indexer_scores(q_idx, w_idx, ik_pool, tables, pos,
+                                interpret=interpret)
+    bs, cols = k_pool.shape[1], tables.shape[1]
+    B, s = q.shape[:2]
+    if s > 1:
+        # the walk's last chunk may pass the scored columns
+        walk = _pa.chunk_blocks(cols, bs, k_pool.shape[2], k_pool.shape[3],
+                                k_pool.dtype) * bs
+        short = -(-cols * bs // walk) * walk - scores.shape[-1]
+        if short > 0:
+            scores = jnp.pad(scores, ((0, 0), (0, 0), (0, short)),
+                             constant_values=-jnp.inf)
+        L = scores.shape[-1]
+        # a tile's last query sees its `tile` more positions than the last
+        tile = _sa.THRESHOLD_ROWS
+        seen = pos[:, None] + tile * jnp.arange(1, s // tile + 1)
+        tau = _sa.topk_threshold(
+            scores.reshape(B * s, L), jnp.minimum(seen, L).reshape(-1),
+            min(int(topk), L), interpret=interpret).reshape(B, s, 1)
+        return _pa.paged_prefill_attention(
+            q, k_pool, v_pool, tables, pos, scale=scale, interpret=interpret,
+            picks=(scores, tau))
+    # a decode step's few rows: XLA's top-k (0.8 ms for 12 rows of
+    # 51,200 on a v5e) beats the threshold and a list of the positions
+    # over it (4.4 ms)
+    k = min(int(topk), scores.shape[-1])
+    picked = jax.lax.top_k(scores[:, 0], k)[1]
+    rows = jnp.take_along_axis(tables.astype(jnp.int32),
+                               jnp.minimum(picked // bs, cols - 1), axis=1) \
+        * bs + picked % bs
+    return _sa.picked_attention(q, k_pool, v_pool, rows,
+                                jnp.minimum(pos + 1, k), scale=scale)
+
+
+def _sparse_gate(plane_shapes, rows, queries, heads, dtype):
+    k = plane_shapes["k"]
+    return _sparse_kernel((rows, queries, heads, k[-1]), k,
+                          plane_shapes["ik"], dtype)
+
+
+def sparse_blocks_read(lens, table_cols, plane_shapes, rows, heads, dtype,
+                       queries=1):
+    """As `paged_blocks_read`, for a `sparse_paged_attention` call: the
+    blocks the indexer's walk reads of the `ik` plane (a prefill chunk's
+    attention walks the same blocks of K and V; a decode step's reads
+    positions, not blocks: `sparse_positions_read`), or every column of
+    every row's table where the XLA form gathers."""
+    if _sparse_gate(plane_shapes, rows, queries, heads, dtype) is None:
+        return len(lens) * table_cols
+    return _pa.walked_blocks(lens, table_cols, plane_shapes["k"][1],
+                             queries=queries)
+
+
+def sparse_positions_read(lens, table_cols, plane_shapes, rows, heads, dtype,
+                          queries=1, real=1, topk=None):
+    """What the indexer scores and the attention reads, in positions, for
+    rows whose last query sees `lens` positions, `real` real queries a
+    row.  A decode step (`queries` 1), summed over its rows:
+    `indexer_positions` (scored: what a query sees) and
+    `selected_positions` (the K/V positions the path that serves reads:
+    the picks, at most `topk` a row, or every table position where the
+    XLA form gathers).  A prefill chunk, over (query, position) pairs:
+    `scored_pairs`, `selected_pairs` (at most `topk` a query) and
+    `attended_pairs` (what its attention computes over: every visible
+    pair in the kernel's walk, every table position in the XLA form)."""
+    kernel = _sparse_gate(plane_shapes, rows, queries, heads,
+                          dtype) is not None
+    table = table_cols * plane_shapes["k"][1]
+    scored = selected = attended = 0
+    for n in lens:
+        seen = range(int(n) - real + 1, int(n) + 1)
+        scored += sum(seen)
+        selected += sum(min(m, topk) for m in seen)
+        attended += len(seen) * table
+    if queries == 1:
+        return dict(indexer_positions=scored,
+                    selected_positions=selected if kernel else attended)
+    return dict(scored_pairs=scored, selected_pairs=selected,
+                attended_pairs=scored if kernel else attended)
+
+
+override("sparse_paged_attention", sparse_paged_attention_with_pallas)
+
+
 _BLOCKS_READ = {"paged_attention": paged_blocks_read,
                 "latent_paged_attention": latent_blocks_read}
+# the readers of ops whose planes differ: each takes them by name
+_PLANES_READ = {"sparse_paged_attention": sparse_blocks_read}
+_POSITIONS_READ = {"sparse_paged_attention": sparse_positions_read}
 
 
 def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype,
@@ -272,14 +391,37 @@ def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype,
     `queries` query tokens a slot (a decode program: 1; a prefill
     program: its bucket), reads for rows whose last query sees `lens`
     positions, by the registered op `op` that the model says reads its
-    planes (`plane_shapes`: {name: one layer's array shape}, the planes
-    of one op alike; the last axis is the width a query meets).
-    `window`: the layer's band, where its op takes one."""
-    shape = next(iter(plane_shapes.values()))
+    planes (`plane_shapes`: {name: one layer's array shape}).
+    `window`: the layer's band, where its op takes one.  An op of
+    `_BLOCKS_READ` reads planes that are alike, so that one stands for
+    all (its last axis the width a query meets); an op whose planes
+    differ needs a reader of `_PLANES_READ`, which takes them by name."""
+    if op in _PLANES_READ:
+        return _PLANES_READ[op](lens, table_cols, plane_shapes, rows, heads,
+                                dtype, queries=queries)
+    shapes = set(plane_shapes.values())
+    if len(shapes) != 1:
+        raise ValueError(
+            f"the planes {plane_shapes} of {op!r} differ and no reader of "
+            f"_PLANES_READ says how it reads them")
+    shape = shapes.pop()
     band = {} if window is None else {"window": window}
     return _BLOCKS_READ[op](lens, table_cols,
                             (rows, queries, heads, shape[-1]), shape, dtype,
                             **band)
+
+
+def pool_positions_read(op, lens, table_cols, plane_shapes, rows, heads,
+                        dtype, queries=1, real=1, **op_args):
+    """The positions counts of an op that picks what it reads
+    (`_POSITIONS_READ`, e.g. `sparse_positions_read`), by the path that
+    serves the shapes; {} for every other op.  `op_args`: what the model
+    says the op's counts need (`cache_op_args`)."""
+    reader = _POSITIONS_READ.get(op)
+    if reader is None:
+        return {}
+    return reader(lens, table_cols, plane_shapes, rows, heads, dtype,
+                  queries=queries, real=real, **op_args)
 
 
 _xla_grouped_matmul = get("grouped_matmul").fn

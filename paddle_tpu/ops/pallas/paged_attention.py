@@ -379,9 +379,9 @@ class PrefillWalk:
 
     def __init__(self, tables_ref, pos_ref, *, tq, bs, chunk, window):
         self.tables_ref, self.chunk = tables_ref, chunk
-        self.b, t = pl.program_id(0), pl.program_id(1)
+        self.b, self.t = pl.program_id(0), pl.program_id(1)
         cols = tables_ref.shape[1]
-        self.p0 = pos_ref[self.b] + t * tq
+        self.p0 = pos_ref[self.b] + self.t * tq
         p1 = self.p0 + tq - 1
         self.first = 0 if window is None \
             else jnp.maximum(self.p0 - (window - 1), 0) // bs
@@ -389,15 +389,19 @@ class PrefillWalk:
             jnp.minimum(pl.cdiv(p1 + 1, bs), cols) - self.first, 0)
         self.n_chunks = pl.cdiv(self.n_blocks, chunk)
 
-    def run(self, copy_block, reduce_chunk):
+    def run(self, copy_block, reduce_chunk, copy_chunk=None):
         """`copy_block(blk, c, slot, start)` starts (or waits for) the
         copy of pool block `blk` into place `c` of buffer `slot`;
         `reduce_chunk(i, slot)` reduces the tile's i-th chunk, copied
         whole into buffer `slot` (of its last chunk only the blocks the
-        tile sees: the rest of the buffer holds what it held)."""
+        tile sees: the rest of the buffer holds what it held).
+        `copy_chunk(i, slot, start)`, where given, starts (or waits for)
+        a copy of the chunk's own beside its blocks'."""
         chunk, n_blocks, n_chunks = self.chunk, self.n_blocks, self.n_chunks
 
         def copies(i, slot, start):
+            if copy_chunk is not None:
+                copy_chunk(i, slot, start)
             at = self.first + i * chunk
 
             def one(c, _):
@@ -423,14 +427,21 @@ class PrefillWalk:
         lax.fori_loop(-1, n_chunks, walk, 1)
 
 
-def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
-                    kv_buf, sems, m_ref, l_ref, acc_ref, *,
-                    bs, chunk, g, tq, scale, window):
+def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, *refs,
+                    bs, chunk, g, tq, scale, window, picks=False):
     """The body is written in `lax` where `jnp` would do, and reduces
     all kv heads in one batched product: a serving process traces and
     lowers this kernel for every bucket and layer kind before its first
     request, a `jnp` call costs a trace several times a primitive's,
-    and set-up time is an end-to-end metric."""
+    and set-up time is an end-to-end metric.  With `picks` a query
+    attends only the positions whose score (`sc_hbm` [B, s, L] in HBM,
+    copied a chunk at a time beside the chunk's blocks) reaches its
+    threshold (`tau_ref`, [tq, 1] of the tile)."""
+    if picks:
+        (sc_hbm, tau_ref, o_ref, kv_buf, sems, m_ref, l_ref, acc_ref,
+         sc_buf, sc_sems) = refs
+    else:
+        o_ref, kv_buf, sems, m_ref, l_ref, acc_ref = refs
     walker = PrefillWalk(tables_ref, pos_ref, tq=tq, bs=bs, chunk=chunk,
                          window=window)
     hkv, rows, _ = q_ref.shape[1:]              # rows: (position, head of g)
@@ -496,6 +507,14 @@ def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
         live = lax.le(at, q_pos)
         if window is not None:
             live = lax.bitwise_and(live, lax.gt(at, lax.sub(q_pos, window)))
+        if picks:
+            # a position's pick, as a row (position, head of g) meets it
+            picked = lax.convert_element_type(
+                lax.ge(sc_buf[slot], tau_ref[0]), f32)
+            picked = lax.reshape(
+                lax.broadcast_in_dim(picked, (tq, g, keys), (0, 2)),
+                (rows, keys))
+            live = lax.bitwise_and(live, lax.gt(picked, f32(0.5)))
         # one step of the online softmax, every kv head's products in
         # one batched product: nothing is written out head by head (a
         # loop over the heads rolled two at a time read 57% slower than
@@ -523,7 +542,15 @@ def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
                             precision=precision, preferred_element_type=f32))
         m_ref[...] = m_new
 
-    walker.run(copy_block, reduce_chunk)
+    def copy_chunk(i, slot, start):
+        dma = pltpu.make_async_copy(
+            sc_hbm.at[walker.b, pl.ds(walker.t * tq, tq),
+                      pl.ds(pl.multiple_of((first + i * chunk) * bs, keys),
+                            keys)],
+            sc_buf.at[slot], sc_sems.at[slot])
+        dma.start() if start else dma.wait()
+
+    walker.run(copy_block, reduce_chunk, copy_chunk if picks else None)
 
     o_ref[0] = lax.convert_element_type(
         lax.div(acc_ref[...],
@@ -532,7 +559,7 @@ def _prefill_kernel(tables_ref, pos_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_prefill_attention(q, k_pool, v_pool, tables, pos, scale=None,
-                            interpret=False, window=None):
+                            interpret=False, window=None, picks=None):
     """Paged attention of a chunk of `s` query rows a request.  q:
     [B, s, H, D]; pools: [N, bs, Hkv, D]; tables: [B, M] int32 block
     ids; pos: [B] int32, the context offset of a row's FIRST query: row
@@ -543,8 +570,12 @@ def paged_prefill_attention(q, k_pool, v_pool, tables, pos, scale=None,
     ``cdiv(pos + s, bs) - 1`` of its table, clipped to its columns, and
     nothing else: entries before the band may hold any id.  The kernel
     carries the name ``paged_prefill_attention`` (under a window
-    ``paged_window_prefill_attention``) in a device trace.  Returns
-    [B, s, H, D] in the q dtype."""
+    ``paged_window_prefill_attention``) in a device trace.  `picks`
+    (scores [B, s, L] float32, L whole chunks of the walk and at least
+    the table's positions; thresholds [B, s, 1]): row i attends only
+    the positions whose score reaches its threshold, and the kernel is
+    named ``sparse_prefill_attention``.  Returns [B, s, H, D] in the q
+    dtype."""
     D = q.shape[-1]
     if not supports(q.shape, k_pool.shape, q.dtype):
         raise ValueError(
@@ -553,8 +584,8 @@ def paged_prefill_attention(q, k_pool, v_pool, tables, pos, scale=None,
     scale = float(scale) if scale is not None else 1.0 / (D ** 0.5)
     if window is not None and int(window) < 1:
         raise ValueError(f"window={window} is no band")
-    return _paged_prefill(q, k_pool, v_pool, tables, pos, scale=scale,
-                          interpret=bool(interpret),
+    return _paged_prefill(q, k_pool, v_pool, tables, pos, picks,
+                          scale=scale, interpret=bool(interpret),
                           window=None if window is None else int(window))
 
 
@@ -562,8 +593,8 @@ def paged_prefill_attention(q, k_pool, v_pool, tables, pos, scale=None,
 # trace and lower ONE kernel
 @functools.partial(jax.jit,
                    static_argnames=("scale", "interpret", "window"))
-def _paged_prefill(q, k_pool, v_pool, tables, pos, *, scale, interpret,
-                   window):
+def _paged_prefill(q, k_pool, v_pool, tables, pos, picks=None, *, scale,
+                   interpret, window):
     B, s, H, D = q.shape
     N, bs, Hkv, _ = k_pool.shape
     M = tables.shape[1]
@@ -581,23 +612,34 @@ def _paged_prefill(q, k_pool, v_pool, tables, pos, *, scale, interpret,
     rows = tq * g
 
     kernel = functools.partial(_prefill_kernel, bs=bs, chunk=chunk, g=g,
-                               tq=tq, scale=scale, window=window)
+                               tq=tq, scale=scale, window=window,
+                               picks=picks is not None)
     q_spec = pl.BlockSpec(
         (1, Hkv, rows, D), lambda b, t, tables_ref, pos_ref: (b, 0, t, 0))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, pool_spec, pool_spec]
+    scratch = [
+        # two slots of a chunk's K and V as they lie in the pool
+        pltpu.VMEM((2, 2, chunk * bs * Hkv, D), k_pool.dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+        pltpu.VMEM((Hkv, rows, 1), jnp.float32),
+        pltpu.VMEM((Hkv, rows, D), jnp.float32),
+    ]
+    extra, name = (), ("paged_prefill_attention" if window is None
+                       else "paged_window_prefill_attention")
+    if picks is not None:
+        extra, name = picks, "sparse_prefill_attention"
+        in_specs += [pool_spec, pl.BlockSpec(
+            (1, tq, 1), lambda b, t, tables_ref, pos_ref: (b, t, 0))]
+        scratch += [pltpu.VMEM((2, tq, chunk * bs), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2,))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, s // tq),
-        in_specs=[q_spec, pool_spec, pool_spec],
+        in_specs=in_specs,
         out_specs=q_spec,
-        scratch_shapes=[
-            # two slots of a chunk's K and V as they lie in the pool
-            pltpu.VMEM((2, 2, chunk * bs * Hkv, D), k_pool.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
-            pltpu.VMEM((Hkv, rows, 1), jnp.float32),
-            pltpu.VMEM((Hkv, rows, D), jnp.float32),
-        ],
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
@@ -607,9 +649,9 @@ def _paged_prefill(q, k_pool, v_pool, tables, pos, *, scale, interpret,
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_PREFILL_VMEM),
         interpret=interpret,
-        name="paged_prefill_attention" if window is None
-        else "paged_window_prefill_attention",
-    )(tables.astype(jnp.int32), pos.astype(jnp.int32), qh, k_pool, v_pool)
+        name=name,
+    )(tables.astype(jnp.int32), pos.astype(jnp.int32), qh, k_pool, v_pool,
+      *extra)
     return out.reshape(B, Hkv, s, g, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, s, H, D)
 

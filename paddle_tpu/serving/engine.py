@@ -49,7 +49,7 @@ from ..jit import functional_bridge as FB
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
 from ..observability.compile_tracker import building as _building
-from ..ops.pallas import pool_blocks_read
+from ..ops.pallas import pool_blocks_read, pool_positions_read
 from ..resilience import chaos
 from ..tensor import Tensor
 from ..text.generation import BucketPolicy
@@ -124,12 +124,19 @@ class LLMEngine:
         # pool blocks a layer of the decode program reads: by the op the
         # model says reads its planes, one query token a slot (for
         # step()'s counts)
+        gate = dict(table_cols=self.table_cols, rows=self.max_running,
+                    heads=model.cfg.num_heads,
+                    dtype=next(iter(model.parameters()))._array.dtype)
         self._blocks_read = [functools.partial(
-            pool_blocks_read, model.cache_op, table_cols=self.table_cols,
-            plane_shapes=self.pool.plane_shapes(g),
-            rows=self.max_running, heads=model.cfg.num_heads,
-            dtype=next(iter(model.parameters()))._array.dtype,
-            window=grp.window) for g, grp in enumerate(self.pool.groups)]
+            pool_blocks_read, model.cache_op,
+            plane_shapes=self.pool.plane_shapes(g), window=grp.window,
+            **gate) for g, grp in enumerate(self.pool.groups)]
+        # ... and the positions it scores and reads, where the op picks
+        # what it reads (nothing for the others)
+        self._positions_read = functools.partial(
+            pool_positions_read, model.cache_op,
+            plane_shapes=self.pool.plane_shapes(0), **gate,
+            **getattr(model, "cache_op_args", {}))
 
         self._pn, self._p_arrays, self._bn, self._b_arrays = \
             FB.split_state(model)
@@ -394,12 +401,15 @@ class LLMEngine:
         the fallback's gather of whole tables.  A window group counts
         under its own names: the blocks its rows HOLD, what one full
         table would hold more, and the blocks that hold a position a
-        row's query sees (the least any sound walk reads)."""
+        row's query sees (the least any sound walk reads).  An op that
+        picks what it reads adds the positions it scores and reads
+        (`pool_positions_read`)."""
         counts = collections.Counter(kv_blocks_live=0, kv_blocks_walked=0)
+        lens = [r.ctx + 1 for r in ready]
+        counts.update(self._positions_read(lens))
         if not ready:
             return counts
         pool = self.pool
-        lens = [r.ctx + 1 for r in ready]
         dead = [1] * (self.max_running - len(ready))
         whole = sum(pool.blocks_for(n) for n in lens)
         for g, grp in enumerate(pool.groups):
@@ -691,7 +701,8 @@ class LLMEngine:
         that hold a position one of the chunk's `n` queries sees, and
         the blocks a layer reads for the program's `bucket` query rows
         on the path that serves it (a kernel's walk, or the fallback's
-        gather of a whole table or band)."""
+        gather of a whole table or band); and where the op picks what it
+        reads, the (query, position) pairs it scores, picks and attends."""
         bs = self.pool.block_size
         live = walked = 0
         for g, grp in enumerate(self.pool.groups):
@@ -700,7 +711,9 @@ class LLMEngine:
             live += self.pool.blocks_for(ctx + n) - behind
             walked += self._blocks_read[g]([ctx + bucket], rows=1,
                                            queries=bucket)
-        return dict(kv_blocks_live=live, kv_blocks_walked=walked)
+        return dict(kv_blocks_live=live, kv_blocks_walked=walked,
+                    **self._positions_read([ctx + n], rows=1,
+                                           queries=bucket, real=n))
 
     def _tables(self, reqs, rows=None):
         """The requests' block tables as a program takes them: one

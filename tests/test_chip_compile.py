@@ -399,3 +399,53 @@ def test_ring_block_fwd_bwd_compiles(one_chip):
             q, k, v, o, lse, do, True),
         x, x, x, x, s((2, 16, 512), jnp.float32), x)
     assert KERNEL in fwd and KERNEL in bwd
+
+
+# the sparse-attention cell's pool: 36,500 blocks of 16 positions, a
+# table of 3,200 columns (51,200 positions), 12 rows
+_SPARSE_N, _SPARSE_M, _SPARSE_B = 36500, 3200, 12
+
+
+@pytest.mark.parametrize("S", [1, 2048, 32],
+                         ids=["decode", "cell-chunk", "smallest-bucket"])
+def test_sparse_paged_attention_compiles_through_the_gate(
+        one_chip, monkeypatch, S):
+    """`sparse_paged_attention` at the sparse cell's shapes, as the model
+    calls it: 32 query heads over 4 kv heads of 128, 16 indexer heads
+    over one cached key padded to 128 lanes, top 2,048.  A decode step
+    is the indexer's decode kernel, XLA's top-k and the attention over
+    the picked rows alone; a chunk the indexer's prefill kernel and the
+    K/V prefill walk with each query's threshold.  Each kernel carries a
+    name of its own that no reader of another kernel matches."""
+    import re
+    from benchmark.metrics import indexer_roofline, sparse_prefill_roofline
+    from paddle_tpu.ops import pallas as plo
+    monkeypatch.setattr(plo, "_mode", lambda: "tpu")
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    bf16 = jnp.bfloat16
+    B = _SPARSE_B if S == 1 else 1
+    pool = s((_SPARSE_N, 16, 4, 128), bf16)
+    text = _compiled_text(
+        lambda q, k, v, ik, qi, w, t, p: plo.sparse_paged_attention_with_pallas(
+            q, k, v, ik, qi, w, t, p, topk=2048, scale=128 ** -0.5),
+        s((B, S, 32, 128), bf16), pool, pool, s((_SPARSE_N, 16, 128), bf16),
+        s((B, S, 16, 128), bf16), s((B, S, 16), jnp.float32),
+        s((B, _SPARSE_M), jnp.int32), s((B,), jnp.int32))
+    names = ({"indexer_decode_scores"} if S == 1
+             else {"indexer_prefill_scores", "sparse_topk_threshold",
+                   "sparse_prefill_attention"})
+    for name in names:
+        assert name in text
+    readers = {"indexer_decode_scores": indexer_roofline,
+               "indexer_prefill_scores": indexer_roofline,
+               "sparse_prefill_attention": sparse_prefill_roofline}
+    others = (r"paged_decode|paged_attention",
+              r"(?<!latent_)paged_decode_attention",
+              r"paged_window_decode_attention",
+              r"latent_paged_decode_attention", r"^mosaic:(?!paged)")
+    for name, reader in readers.items():
+        assert re.search(reader.PATTERN, name)
+        for mod in set(readers.values()) - {reader}:
+            assert not re.search(mod.PATTERN, name)
+        for pattern in others:
+            assert not re.search(pattern, name)
