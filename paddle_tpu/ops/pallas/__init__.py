@@ -348,7 +348,7 @@ def sparse_blocks_read(lens, table_cols, plane_shapes, rows, heads, dtype,
 
 
 def sparse_positions_read(lens, table_cols, plane_shapes, rows, heads, dtype,
-                          queries=1, real=1, topk=None):
+                          queries=1, real=1, topk=None, **_):
     """What the indexer scores and the attention reads, in positions, for
     rows whose last query sees `lens` positions, `real` real queries a
     row.  A decode step (`queries` 1), summed over its rows:
@@ -358,7 +358,8 @@ def sparse_positions_read(lens, table_cols, plane_shapes, rows, heads, dtype,
     XLA form gathers).  A prefill chunk, over (query, position) pairs:
     `scored_pairs`, `selected_pairs` (at most `topk` a query) and
     `attended_pairs` (what its attention computes over: every visible
-    pair in the kernel's walk, every table position in the XLA form)."""
+    pair in the kernel's walk, every table position in the XLA form).
+    The op's other arguments are its walk's (`sparse_walk_copies`)."""
     kernel = _sparse_gate(plane_shapes, rows, queries, heads,
                           dtype) is not None
     table = table_cols * plane_shapes["k"][1]
@@ -375,6 +376,19 @@ def sparse_positions_read(lens, table_cols, plane_shapes, rows, heads, dtype,
                 attended_pairs=scored if kernel else attended)
 
 
+def sparse_walk_copies(tables, pos, plane_shapes, rows, heads, dtype,
+                       queries=1, index_heads=1, **_):
+    """`ik_copies`: the DMAs the indexer's walk issues for a call sent
+    `tables` and `pos` (`sparse_attention.walk_copies`: one a run of
+    adjacent blocks in a chunk, in power-of-two pieces), where the
+    kernel serves; nothing where the XLA form gathers."""
+    if _sparse_gate(plane_shapes, rows, queries, heads, dtype) is None:
+        return {}
+    return {"ik_copies": _sa.walk_copies(
+        tables, pos, plane_shapes["ik"][1], queries=queries,
+        heads=index_heads)}
+
+
 override("sparse_paged_attention", sparse_paged_attention_with_pallas)
 
 
@@ -383,6 +397,8 @@ _BLOCKS_READ = {"paged_attention": paged_blocks_read,
 # the readers of ops whose planes differ: each takes them by name
 _PLANES_READ = {"sparse_paged_attention": sparse_blocks_read}
 _POSITIONS_READ = {"sparse_paged_attention": sparse_positions_read}
+# the ops whose walk copies runs of adjacent blocks, and the rule of each
+_WALK_COPIES = {"sparse_paged_attention": sparse_walk_copies}
 
 
 def pool_blocks_read(op, lens, table_cols, plane_shapes, rows, heads, dtype,
@@ -422,6 +438,21 @@ def pool_positions_read(op, lens, table_cols, plane_shapes, rows, heads,
         return {}
     return reader(lens, table_cols, plane_shapes, rows, heads, dtype,
                   queries=queries, real=real, **op_args)
+
+
+def pool_walk_copies(op, tables, pos, plane_shapes, rows, heads, dtype,
+                     queries=1, **op_args):
+    """The DMA counts of an op whose walk copies runs of adjacent pool
+    blocks (`_WALK_COPIES`, e.g. `sparse_walk_copies`) for one call of
+    `rows` rows, `queries` query tokens a row, sent `tables` and first
+    positions `pos` (host arrays), by the path that serves the shapes;
+    {} for every other op.  `op_args`: what the model says the op's
+    counts need (`cache_op_args`)."""
+    rule = _WALK_COPIES.get(op)
+    if rule is None:
+        return {}
+    return rule(tables, pos, plane_shapes, rows, heads, dtype,
+                queries=queries, **op_args)
 
 
 _xla_grouped_matmul = get("grouped_matmul").fn

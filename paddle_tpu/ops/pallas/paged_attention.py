@@ -396,12 +396,15 @@ class PrefillWalk:
         whole into buffer `slot` (of its last chunk only the blocks the
         tile sees: the rest of the buffer holds what it held).
         `copy_chunk(i, slot, start)`, where given, starts (or waits for)
-        a copy of the chunk's own beside its blocks'."""
+        a copy of the chunk's own beside its blocks', or copies the
+        blocks itself where `copy_block` is None."""
         chunk, n_blocks, n_chunks = self.chunk, self.n_blocks, self.n_chunks
 
         def copies(i, slot, start):
             if copy_chunk is not None:
                 copy_chunk(i, slot, start)
+            if copy_block is None:
+                return
             at = self.first + i * chunk
 
             def one(c, _):

@@ -12,8 +12,12 @@ indexer queries of a position: a program walks the blocks its queries
 see (`paged_attention.PrefillWalk`: a decode row is a tile of one
 position) in chunks of `index_chunk` blocks, scores a copied chunk by
 ONE product ``[(position, head), W] . [W, keys]``, and sums ``w . relu``
-over the heads, float32.  It writes [B, s, L] scores, -inf past what a
-query sees: a decode row's whole row lies in VMEM (named
+over the heads, float32.  A chunk's blocks that lie side by side in the
+pool go in one DMA: XLA plans the copies from the tables
+(`_walk_plan`), and the core's loop then turns once a copy, not once a
+block (a turn costs ~35-45 ns on a v5e whatever it issues, and a walk
+that turned once a block was bound by it).  It writes [B, s, L] scores,
+-inf past what a query sees: a decode row's whole row lies in VMEM (named
 ``indexer_decode_scores`` in a device trace), a prefill tile's chunks go
 out by DMA into scores that start at -inf (``indexer_prefill_scores``).
 
@@ -28,6 +32,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -73,13 +78,48 @@ def supports(q_shape, pool_shape, ik_shape, dtype):
     return s == 1 or s % 8 == 0
 
 
-def _indexer_kernel(tables_ref, pos_ref, q_ref, w_ref, ik_hbm, *refs, bs,
+_ID_BITS = 26           # a packed table entry: block id below, piece above
+
+
+def _piece_sizes(chunk):
+    """The static sizes, in blocks, of the walk's copies: the powers of
+    two up to `chunk`."""
+    return [1 << k for k in range(chunk.bit_length())]
+
+
+def _walk_plan(tables, chunk):
+    """The walk's copies, planned in XLA beside its tables: each entry
+    of `tables` [B, M] with, above its `_ID_BITS` of block id, 1 + log2
+    of the size of the copy that starts there, or 0 where none starts.
+    A copy starts at each maximal run of ascending ids inside an aligned
+    chunk of `chunk` columns, and again after each piece of it: a run of
+    r blocks is copied in the pieces of r's binary form, largest first
+    (`walk_copies` is the rule on the host)."""
+    B, M = tables.shape
+    col = lax.broadcasted_iota(jnp.int32, (B, M), 1)
+    after = jnp.concatenate(
+        [jnp.ones((B, 1), bool), tables[:, 1:] != tables[:, :-1] + 1], 1)
+    cut = (col % chunk == 0) | after
+    start = lax.cummax(jnp.where(cut, col, 0), axis=1)
+    nxt = jnp.concatenate([jnp.where(cut, col, M)[:, 1:],
+                           jnp.full((B, 1), M, jnp.int32)], 1)
+    end = lax.cummin(nxt, axis=1, reverse=True)
+    # at a piece's first column what is left of the run, `left`, is the
+    # run's length with its larger pieces taken off: r's low bits
+    left = end - col
+    bits = 32 - lax.clz(left)
+    first = ((end - start) & ((1 << bits) - 1)) == left
+    return tables | jnp.where(first, bits << _ID_BITS, 0)
+
+
+def _indexer_kernel(plan_ref, pos_ref, q_ref, w_ref, ik_hbm, *refs, bs,
                     chunk, tq, heads):
     if tq == 1:
-        o_ref, buf, sems = refs
+        o_ref, buf, sems, counts = refs
     else:
-        _, o_hbm, buf, sems, obuf, osem = refs      # the -inf start, aliased
-    walker = PrefillWalk(tables_ref, pos_ref, tq=tq, bs=bs, chunk=chunk,
+        # the -inf start, aliased
+        _, o_hbm, buf, sems, counts, obuf, osem = refs
+    walker = PrefillWalk(plan_ref, pos_ref, tq=tq, bs=bs, chunk=chunk,
                          window=None)
     keys = chunk * bs
     f32 = jnp.float32
@@ -89,15 +129,50 @@ def _indexer_kernel(tables_ref, pos_ref, q_ref, w_ref, ik_hbm, *refs, bs,
     seen = walker.p0 + lax.broadcasted_iota(jnp.int32, (tq, keys), 0)
     if tq == 1:
         o_ref[...] = jnp.full(o_ref.shape, _NEG_INF, f32)
+    sizes = _piece_sizes(chunk)
 
-    def copy_block(blk, c, slot, start):
-        dma = pltpu.make_async_copy(
-            ik_hbm.at[blk], buf.at[slot, pl.ds(pl.multiple_of(c * bs, bs), bs)],
-            sems.at[slot])
-        dma.start() if start else dma.wait()
+    def piece(size, src, dst, slot):
+        return pltpu.make_async_copy(ik_hbm.at[pl.ds(src, size)],
+                                     buf.at[slot, pl.ds(dst, size)],
+                                     sems.at[slot])
+
+    def copy_chunk(i, slot, start):
+        """The chunk's copies by the plan (`_walk_plan`), one a piece; a
+        piece that passes the blocks this program walks is cut to them,
+        in the pieces of what is left.  `counts[slot]` keeps how many of
+        each size, and the chunk's wait takes the same pieces."""
+        if not start:
+            for k, size in enumerate(sizes):
+                def wait(_, carry, size=size):
+                    piece(size, 0, 0, slot).wait()
+                    return carry
+                lax.fori_loop(0, counts[slot, k], wait, 0)
+            return
+        for k in range(len(sizes)):
+            counts[slot, k] = 0
+        first = walker.first + i * chunk
+        n = jnp.minimum(walker.n_blocks - i * chunk, chunk)
+
+        def copy(c):
+            entry = plan_ref[walker.b, first + c]
+            blk = entry & ((1 << _ID_BITS) - 1)
+            take = jnp.minimum(
+                lax.shift_left(1, lax.shift_right_logical(entry, _ID_BITS)
+                               - 1), n - c)
+            for k, size in reversed(list(enumerate(sizes))):
+                @pl.when(take & size != 0)
+                def _piece(k=k, size=size):
+                    off = take & -(2 * size)        # the larger pieces'
+                    piece(size, blk + off, c + off, slot).start()
+                    counts[slot, k] += 1
+
+            return c + take
+
+        lax.while_loop(lambda c: c < n, copy, 0)
 
     def reduce_chunk(i, slot):
-        s = lax.dot_general(q, buf[slot], (((1,), (1,)), ((), ())),
+        k = buf[slot].reshape(keys, buf.shape[-1])
+        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             precision=precision, preferred_element_type=f32)
         s = (jnp.maximum(s, 0.0) * w).reshape(tq, heads, keys).sum(axis=1)
         base = i * keys
@@ -114,7 +189,45 @@ def _indexer_kernel(tables_ref, pos_ref, q_ref, w_ref, ik_hbm, *refs, bs,
             dma.start()
             dma.wait()
 
-    walker.run(copy_block, reduce_chunk)
+    walker.run(None, reduce_chunk, copy_chunk)
+
+
+def walk_copies(tables, pos, block_size, queries=1, heads=1):
+    """The DMAs the indexer's walk issues for one call (host numbers):
+    `tables` [B, M] and `pos` [B] as the call is sent, `queries` query
+    positions a row (a decode row 1, a prefill chunk its bucket) and
+    `heads` indexer heads, which set the prefill tile.  The kernel's own
+    rule: each program walks its row's blocks up to the one that holds
+    its last query's position, in aligned chunks of `index_chunk`
+    blocks, and copies each maximal run of ascending ids inside a chunk
+    in power-of-two pieces, so a run of r blocks costs popcount(r)
+    DMAs."""
+    pos = np.asarray(pos, np.int64)
+    B = len(pos)
+    bs, chunk = int(block_size), index_chunk(block_size)
+    tq = 1 if queries == 1 else index_tile(queries, heads)
+    # blocks each program walks: [B, programs]
+    ends = pos[:, None] + tq * np.arange(1, queries // tq + 1)
+    n = np.minimum(-(-ends // bs), np.shape(tables)[1])
+    cols = int(n.max())
+    t = np.asarray(tables)[:, :cols]
+    # the runs, over the rows laid end to end: a row and a chunk start
+    # one; none starts past what the row's programs walk
+    cut = np.empty(t.shape, bool)
+    cut[:, 1:] = t[:, 1:] != t[:, :-1] + 1
+    cut[:, ::chunk] = True
+    for r, walked in enumerate(n.max(axis=1)):
+        cut[r, walked:] = False
+    starts = np.flatnonzero(cut)
+    before = np.zeros(len(starts) + 1, np.int64)
+    np.cumsum(np.bitwise_count(np.diff(starts, append=t.size)),
+              out=before[1:])
+    row = np.arange(B)[:, None] * cols
+    last = row + n - 1
+    k = np.searchsorted(starts, last, side="right") - 1
+    k0 = np.searchsorted(starts, row, side="right") - 1
+    return int((before[k] - before[k0]
+                + np.bitwise_count(last + 1 - starts[k])).sum())
 
 
 def indexer_scores(q_idx, w_idx, ik_pool, tables, pos, interpret=False):
@@ -145,9 +258,14 @@ def _indexer(q_idx, w_idx, ik_pool, tables, pos, *, interpret):
     in_specs = [pl.BlockSpec((1, rows, W), lambda b, t, *_: (b, t, 0)),
                 pl.BlockSpec((1, rows, 1), lambda b, t, *_: (b, t, 0)),
                 any_spec]
-    scratch = [pltpu.VMEM((2, chunk * bs, W), ik_pool.dtype),
-               pltpu.SemaphoreType.DMA((2,))]
-    args = [tables.astype(jnp.int32), pos.astype(jnp.int32), q, w, ik_pool]
+    if N >= 1 << _ID_BITS:
+        raise ValueError(f"a pool of {N} blocks: the walk's plan packs "
+                         f"block ids in {_ID_BITS} bits")
+    scratch = [pltpu.VMEM((2, chunk, bs, W), ik_pool.dtype),
+               pltpu.SemaphoreType.DMA((2,)),
+               pltpu.SMEM((2, len(_piece_sizes(chunk))), jnp.int32)]
+    args = [_walk_plan(tables.astype(jnp.int32), chunk),
+            pos.astype(jnp.int32), q, w, ik_pool]
     out_shape = jax.ShapeDtypeStruct((B, s, L), jnp.float32)
     if s == 1:
         out_spec, aliases, name = (

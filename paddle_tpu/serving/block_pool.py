@@ -9,7 +9,11 @@ ONCE and carved into fixed-size token blocks handed to requests through
 a host-side free list with reference counts.  Freed requests return
 their blocks immediately (refcount 0 -> back on the free list), so pool
 pressure is a pure function of live context tokens — the scheduler
-admits, evicts and preempts against `free_blocks`.
+admits, evicts and preempts against `free_blocks`.  The free list is a
+min-heap: `allocate` hands out the LOWEST free ids, so the blocks of one
+allocation, and of allocations that follow one another, lie side by side
+in the pool wherever the free ids do -- a walk that copies a run of
+adjacent blocks in one DMA (the sparse indexer's) meets runs.
 
 Blocks come in GROUPS, one per lifetime (`text.decode.LayerPlanes`): a
 group is the layers whose blocks live equally long, and has its own
@@ -43,6 +47,8 @@ the paged attention op runs on local heads only.  A plane without one
 """
 from __future__ import annotations
 
+import heapq
+
 import jax.numpy as jnp
 
 from ..distributed import mesh as mesh_mod
@@ -63,13 +69,14 @@ def band_blocks(window, n_tokens, block_size):
 
 class BlockGroup:
     """The blocks of the layers of one lifetime: how many, which are
-    free (LIFO), how often each is referenced."""
+    free (a min-heap: the lowest id first), how often each is
+    referenced."""
 
     def __init__(self, name, window, num_blocks):
         self.name, self.window = name, window
         self.num_blocks = int(num_blocks)
         self.layers = []        # the layers whose planes these blocks are
-        self.free = list(range(self.num_blocks - 1, -1, -1))
+        self.free = list(range(self.num_blocks))    # ascending: a heap
         self.refs = [0] * self.num_blocks
 
 
@@ -201,9 +208,10 @@ class BlockPool:
             else band_blocks(window, n_tokens, self.block_size)
 
     def allocate(self, n, group=0):
-        """n block ids of `group` at refcount 1, or None when it can't
-        serve them right now (the scheduler's preemption trigger).  The
-        `serving.pool_exhausted` chaos site simulates that exhaustion."""
+        """The n lowest free block ids of `group`, in ascending order,
+        at refcount 1, or None when it can't serve them right now (the
+        scheduler's preemption trigger).  The `serving.pool_exhausted`
+        chaos site simulates that exhaustion."""
         n, grp = int(n), self.groups[group]
         if n > grp.num_blocks:
             raise PoolExhausted(
@@ -217,7 +225,7 @@ class BlockPool:
                 _metrics.registry().counter(
                     "serving_pool_exhausted_total", **labels).inc()
             return None
-        out = [grp.free.pop() for _ in range(n)]
+        out = [heapq.heappop(grp.free) for _ in range(n)]
         for b in out:
             grp.refs[b] = 1
         return out
@@ -239,7 +247,7 @@ class BlockPool:
                 raise ValueError(f"double free of {grp.name} block {b}")
             grp.refs[b] = r
             if r == 0:
-                grp.free.append(b)
+                heapq.heappush(grp.free, b)
 
     # ------------------------------------------------------ slot allocator
     @property

@@ -49,7 +49,8 @@ from ..jit import functional_bridge as FB
 from ..observability import metrics as _metrics
 from ..observability import trace as _trace
 from ..observability.compile_tracker import building as _building
-from ..ops.pallas import pool_blocks_read, pool_positions_read
+from ..ops.pallas import (pool_blocks_read, pool_positions_read,
+                          pool_walk_copies)
 from ..resilience import chaos
 from ..tensor import Tensor
 from ..text.generation import BucketPolicy
@@ -135,6 +136,12 @@ class LLMEngine:
         # what it reads (nothing for the others)
         self._positions_read = functools.partial(
             pool_positions_read, model.cache_op,
+            plane_shapes=self.pool.plane_shapes(0), **gate,
+            **getattr(model, "cache_op_args", {}))
+        # ... and the DMAs its walk issues, where it copies runs of
+        # adjacent blocks (nothing for the others)
+        self._walk_copies = functools.partial(
+            pool_walk_copies, model.cache_op,
             plane_shapes=self.pool.plane_shapes(0), **gate,
             **getattr(model, "cache_op_args", {}))
 
@@ -362,7 +369,7 @@ class LLMEngine:
             blocks = self._block_counts(ready)
             if ready:
                 chained = sum(1 for r in ready if r.in_flight)
-                self._flight = self._dispatch(ready, root.sid)
+                self._flight = self._dispatch(ready, root.sid, root.counts)
                 freed += sum(sched.trim(r) for r in ready)
             self._land(behind, root.sid, landed)
             if in_place:
@@ -682,6 +689,8 @@ class LLMEngine:
             limit = np.asarray([req.ctx + n], np.int32)
             slots = () if req.state_slot is None \
                 else (np.asarray([req.state_slot], np.int32),)
+            span.counts.update(self._walk_copies(tables[0], pos, rows=1,
+                                                 queries=bucket))
             self.pool.planes, *load = self._run_program(
                 ("prefill", bucket), self._build_prefill,
                 self._p_arrays, self._b_arrays, self.pool.planes,
@@ -726,13 +735,14 @@ class LLMEngine:
         return out
 
     # -------------------------------------------------------------- decode
-    def _dispatch(self, ready, parent=None):
+    def _dispatch(self, ready, parent=None, counts=None):
         """Build and dispatch one decode program over `ready`, and queue
         the copies of what the host reads of it behind it.  Returns the
         flight; nothing waits here.  A row with a pick in flight takes
         its token from that pick on the device (`src` names its row in
         the program before); the host sends the token of every other
-        row."""
+        row.  `counts` (the step root's) takes the DMAs of a walk that
+        copies runs of adjacent blocks, counted from the tables sent."""
         R = self.max_running
         with _trace.traced("serving.decode.prepare", parent=parent,
                            cat="serving"):
@@ -757,6 +767,8 @@ class LLMEngine:
                 spare = sorted(set(range(self.pool.slots)) - set(taken))
                 slots = (np.asarray(taken + spare[:R - len(taken)],
                                     np.int32),)
+            if counts is not None:
+                counts.update(self._walk_copies(tables[0], pos))
         # the logits are copied only for a row that draws its token on
         # the host; a greedy step leaves them on the device
         sampled = any(r.do_sample for r in ready)

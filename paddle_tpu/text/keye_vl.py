@@ -285,7 +285,8 @@ class KeyeVL2ForCausalLM(nn.Layer):
     @property
     def cache_op_args(self):
         """What the op's counts need beyond the pool's shapes."""
-        return {"topk": self.cfg.index_topk}
+        return {"topk": self.cfg.index_topk,
+                "index_heads": self.cfg.index_n_heads}
 
     def cache_planes(self):
         """`k` and `v` per token in every layer, and the indexer's key."""

@@ -81,7 +81,7 @@ def test_the_layer_holds_what_the_configuration_says(model):
     assert attn.indexer.weights_proj.weight.shape == [128, 16]
     assert model.model.layers[0].mlp.w_gate.shape == [8, 128, 64]
     assert model.cache_op == "sparse_paged_attention"
-    assert model.cache_op_args == {"topk": TOPK}
+    assert model.cache_op_args == {"topk": TOPK, "index_heads": 16}
     assert [dict(p) for p in model.cache_planes()] == [
         {"k": (2, 128), "v": (2, 128), "ik": (128,)}] * 2
 
@@ -221,6 +221,161 @@ def test_the_indexer_kernel_scores_what_the_xla_form_scores(monkeypatch):
     assert got.shape == (2, 32, sa.scored_len(10, 16))
     np.testing.assert_allclose(got[..., :160], want, atol=1e-4)
     assert np.isneginf(got[..., 160:]).all()
+
+
+def _run_tables(n_blocks, cols=70):
+    """Two tables over a pool of `n_blocks` blocks whose ids run in every
+    way the indexer's walk meets (chunks of 32 blocks): row 0 one
+    ascending run that ends at the pool's last block; row 1 a descending
+    stretch, then runs of every power-of-two length up to a chunk and one
+    of 2, which cross the chunk boundaries at 32 and 64."""
+    row = list(range(40, 35, -1))
+    start = 50
+    for length in (1, 2, 4, 8, 16, 32, 2):
+        row += list(range(start, start + length))
+        start += length + 3
+    assert len(row) == cols
+    return np.asarray([np.arange(n_blocks - cols, n_blocks), row], np.int32)
+
+
+@pytest.mark.parametrize("s", [1, 16, 32], ids=["decode", "chunk16",
+                                                "chunk32"])
+def test_the_indexer_walk_copies_runs_as_it_copies_blocks(s):
+    """The indexer kernel (interpret mode) over tables of runs gives, to
+    the bit, what it gives over the same keys laid out so that no two
+    neighbours of a table lie side by side in the pool (one copy a
+    block), and the XLA form's scores; both rows end in a partial
+    chunk."""
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    N, bs, h, M = 160, 16, 4, 70
+    rng = np.random.default_rng(s)
+    f = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
+    ik, qi, wi = f(N, bs, 128), f(2, s, h, 128), f(2, s, h)
+    tables = _run_tables(N, M)
+    pos = np.asarray([M * bs - s, 700], np.int32)
+    # block x of the pool lies at 7x mod N in the other: a run of ids is
+    # no run there (the tables hold no jump of 23, 7's inverse mod 160)
+    at = 7 * np.arange(N) % N
+    apart = at[tables]
+    assert (np.diff(apart, axis=1) != 1).all()
+    assert sa.walk_copies(apart, pos, bs, s, h) > 2 * sa.walk_copies(
+        tables, pos, bs, s, h)
+    got = np.asarray(sa.indexer_scores(qi, wi, ik, jnp.asarray(tables),
+                                       jnp.asarray(pos), interpret=True))
+    moved = jnp.zeros_like(ik).at[at].set(ik)
+    per_block = np.asarray(sa.indexer_scores(
+        qi, wi, moved, jnp.asarray(apart), jnp.asarray(pos),
+        interpret=True))
+    np.testing.assert_array_equal(got, per_block)
+    keys = nn_kernels.paged_gather_k(ik, jnp.asarray(tables))
+    want = np.asarray(jnp.where(
+        nn_kernels.paged_visible(s, keys.shape[1], jnp.asarray(pos)),
+        nn_kernels.indexer_scores(qi, wi, keys), -jnp.inf))
+    np.testing.assert_allclose(got[..., :M * bs], want, atol=1e-4)
+    assert np.isneginf(got[..., M * bs:]).all()
+
+
+def _copies_one_by_one(tables, pos, bs, queries, heads):
+    """The walk's DMAs counted a program, a chunk and a run at a time."""
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    chunk = sa.index_chunk(bs)
+    tq = 1 if queries == 1 else sa.index_tile(queries, heads)
+    total = 0
+    for row, p in zip(tables, pos):
+        for t in range(queries // tq):
+            n = min(-(-(p + t * tq + tq) // bs), len(row))
+            for a in range(0, n, chunk):
+                blocks = list(row[a:min(a + chunk, n)])
+                i = 0
+                while i < len(blocks):
+                    j = i
+                    while j + 1 < len(blocks) and \
+                            blocks[j + 1] == blocks[j] + 1:
+                        j += 1
+                    total += bin(j - i + 1).count("1")
+                    i = j + 1
+    return total
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_hosts_copy_count_is_the_walks_rule(seed):
+    """`walk_copies` against the walk's DMAs counted one by one, over
+    random tables of runs, descending stretches and repeated ids, decode
+    rows and chunks of one and of several programs."""
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    rng = np.random.default_rng(seed)
+    for _ in range(25):
+        B, M = int(rng.integers(1, 5)), int(rng.choice([1, 37, 70, 139]))
+        rows = []
+        for _ in range(B):
+            row = []
+            while len(row) < M:
+                length = int(rng.integers(1, 45))
+                start = int(rng.integers(length, 500))
+                step = 1 if rng.random() < 0.7 else -1
+                row += [start + step * i for i in range(length)]
+            rows.append(row[:M])
+        tables = np.asarray(rows, np.int32)
+        tables[:, int(rng.integers(0, M)):] *= rng.random() < 0.3
+        bs = int(rng.choice([8, 16, 32]))
+        queries = int(rng.choice([1, 16, 64]))
+        heads = int(rng.choice([4, 16, 64]))
+        pos = rng.integers(0, M * bs, B)
+        want = _copies_one_by_one(tables, pos, bs, queries, heads)
+        assert sa.walk_copies(tables, pos, bs, queries, heads) == want
+        if queries == 1:
+            assert _copies_by_the_plan(tables, pos, bs) == want
+
+
+def _copies_by_the_plan(tables, pos, bs):
+    """A decode call's DMAs as the kernel's loop issues them from the
+    plan XLA packs into its tables (`sparse_attention._walk_plan`)."""
+    from paddle_tpu.ops.pallas import sparse_attention as sa
+    chunk = sa.index_chunk(bs)
+    plan = np.asarray(jax.jit(sa._walk_plan, static_argnums=1)(
+        jnp.asarray(tables), chunk))
+    total = 0
+    for row, p in zip(plan, pos):
+        n_blocks = min(-(-(p + 1) // bs), len(row))
+        for first in range(0, n_blocks, chunk):
+            n, c = min(n_blocks - first, chunk), 0
+            while c < n:
+                size = 1 << ((row[first + c] >> sa._ID_BITS) - 1)
+                assert row[first + c] >> sa._ID_BITS
+                take = min(size, n - c)
+                total += bin(take).count("1")
+                c += take
+    return total
+
+
+def test_the_engine_counts_the_indexer_walks_copies(model, prompts,
+                                                    monkeypatch):
+    """Where the kernels serve (their gate, patched here: the programs
+    still run the XLA form on the CPU), every decode step's root and
+    every chunk's span carry `ik_copies`, by the walk's rule over the
+    tables and positions sent; a fresh pool's tables are runs."""
+    from paddle_tpu.observability import trace
+    sent = []
+    rule = pallas_ops._WALK_COPIES["sparse_paged_attention"]
+
+    def record(tables, pos, *args, **kw):
+        out = rule(tables, pos, *args, **kw)
+        sent.append(out["ik_copies"])
+        return out
+
+    monkeypatch.setattr(pallas_ops, "_sparse_gate", lambda *a: "tpu")
+    monkeypatch.setitem(pallas_ops._WALK_COPIES, "sparse_paged_attention",
+                        record)
+    trace.clear()
+    _served(model, prompts[:2], 3)
+    spans = trace.spans()
+    steps = [c for name, *_, c, _, _ in spans if name == "serving.step"
+             and c.get("decode_rows")]
+    chunks = [c for name, *_, c, _, _ in spans if name == "serving.prefill"]
+    assert steps and chunks
+    assert sorted(c["ik_copies"] for c in steps + chunks) == sorted(sent)
+    for c in steps:
+        assert 0 < c["ik_copies"] < c["kv_blocks_walked"]
 
 
 @pytest.mark.parametrize("rows,seen", [(16, [300, 300]), (8, [9]),

@@ -630,6 +630,48 @@ def test_block_pool_blocks_for():
     assert [pool.blocks_for(n) for n in (1, 16, 17, 32)] == [1, 1, 2, 2]
 
 
+def test_a_fresh_pool_hands_out_its_first_ids_in_order():
+    pool = BlockPool(1, 8, 4, {"k": (2, 8)})
+    assert pool.allocate(5) == [0, 1, 2, 3, 4]
+    assert pool.allocate(3) == [5, 6, 7]
+
+
+@pytest.mark.parametrize("seed", range(3))
+def test_the_pool_hands_out_its_lowest_free_ids_first(seed):
+    """Over cycles of allocations and frees each allocation is the
+    lowest free ids, ascending, and no id is out twice."""
+    rng = np.random.default_rng(seed)
+    pool = BlockPool(1, 48, 4, {"k": (2, 8)})
+    held = []
+    for _ in range(200):
+        if held and rng.random() < 0.45:
+            pool.free(held.pop(int(rng.integers(len(held)))))
+            continue
+        n = int(rng.integers(1, 9))
+        out = {b for table in held for b in table}
+        free = [b for b in range(48) if b not in out]
+        got = pool.allocate(n)
+        if n > len(free):
+            assert got is None
+            continue
+        assert got == free[:n]
+        held.append(got)
+    for table in held:
+        pool.free(table)
+    assert pool.check_leaks() == ([], [])
+    assert pool.allocate(48) == list(range(48))
+
+
+def test_a_shared_block_comes_back_only_at_refcount_zero():
+    pool = BlockPool(1, 8, 4, {"k": (2, 8)})
+    a = pool.allocate(3)                    # 0, 1, 2
+    pool.ref([1])                           # another table shares 1
+    pool.free(a)
+    assert pool.allocate(3) == [0, 2, 3]    # 1 is still out
+    pool.free([1])
+    assert pool.allocate(1) == [1]
+
+
 def _tiny_latent():
     from paddle_tpu.text.deepseek import (DeepseekV3Config,
                                           DeepseekV3ForCausalLM)

@@ -110,6 +110,29 @@ def test_a_model_of_one_kind_is_one_group():
         == [("window", 6, 5)]
 
 
+def test_a_window_group_trimmed_blocks_come_back_lowest_first():
+    """`Scheduler.trim` hands a window group's blocks behind the band
+    back to that group alone, and its next allocation takes them
+    first."""
+    planes = [LayerPlanes({"k": (2, 8)}),
+              LayerPlanes({"k": (2, 8)}, window=WINDOW)]
+    pool = BlockPool(2, 20, BS, planes, window_blocks={WINDOW: 8})
+    sched = Scheduler(pool, max_running=1, run_tokens=CHUNK)
+    req = Request(list(range(40)), max_new_tokens=4)
+    sched.submit(req)
+    assert sched.admit() == [req]
+    assert sched.reserve(req, 16)
+    assert req.block_tables[1] == [0, 1, 2, 3]
+    req.ctx = 16
+    assert sched.trim(req) == (16 - WINDOW) // BS == 2
+    assert pool.used_in(1) == 2 and pool.free_blocks == 20 - 11
+    assert sched.reserve(req, 24)
+    assert req.block_tables[1][2:] == [2, 3, 0, 1]
+    sched.trim(req)
+    sched.finish(req, "length")
+    assert pool.check_leaks() == ([], [])
+
+
 def test_ten_windows_hold_a_band_of_window_blocks_and_a_full_table(model):
     eng = _engine(model)
     bound = eng.pool.band_blocks(1, CHUNK)
@@ -141,9 +164,9 @@ def test_ten_windows_hold_a_band_of_window_blocks_and_a_full_table(model):
 
 
 def test_freed_ids_serve_another_request_while_the_first_decodes(model):
-    """The free list is last in, first out: the second request's chunks
-    run on the blocks the first has just handed back, while the first
-    still decodes; neither's tokens change."""
+    """The free list hands out its lowest ids first: the second
+    request's chunks run on the blocks the first has handed back, while
+    the first still decodes; neither's tokens change."""
     eng = _engine(model, max_running=2)
     first, second = _prompts([50, 45], seed=3)
     a = eng.add_request(first, max_new_tokens=30)
